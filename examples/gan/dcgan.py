@@ -87,7 +87,7 @@ def train(epochs=1, batch=32, steps_per_epoch=25, code_dim=64, lr=2e-4,
     log = log or logging.getLogger("dcgan")
     rs = np.random.RandomState(seed + 1)
     mx.random.seed(seed)   # deterministic init: same seed => same G/D start
-    ctx = ctx or mx.context.current_context()
+    ctx = ctx or mx.tpu(0)
 
     mod_g = mx.Module(make_generator(code_dim=code_dim),
                       data_names=("code",), label_names=None, context=ctx)
@@ -186,6 +186,7 @@ def main():
     ap.add_argument("--out", type=str, default="/tmp/dcgan_samples.npy")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
     mod_g, _, hist = train(epochs=args.epochs, batch=args.batch,
                            steps_per_epoch=args.steps)
     imgs = sample(mod_g, 16)

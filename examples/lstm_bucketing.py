@@ -68,6 +68,7 @@ def main():
     ap.add_argument("--buckets", default="10,20,30,40")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
 
     invalid_label = 0
     if args.train_data and os.path.exists(args.train_data):
@@ -103,7 +104,8 @@ def main():
         return pred, ("data",), ("softmax_label",)
 
     mod = mx.module.BucketingModule(sym_gen,
-                                    default_bucket_key=train.default_bucket_key)
+                                    default_bucket_key=train.default_bucket_key,
+                                    context=mx.tpu(0))
     mod.fit(train, num_epoch=args.num_epochs,
             eval_metric=mx.metric.Perplexity(invalid_label),
             optimizer="sgd",

@@ -72,6 +72,7 @@ def main():
     ap.add_argument("--lr", type=float, default=0.2)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
 
     import jax
     n_dev = max(1, len(jax.devices()))
@@ -83,7 +84,7 @@ def main():
                       args.num_hidden, args.num_embed, args.vocab_size,
                       lambda i: "layer%d" % i)
 
-    ex = net.simple_bind(mx.cpu(), grad_req="write", group2ctx=group2ctx,
+    ex = net.simple_bind(mx.tpu(0), grad_req="write", group2ctx=group2ctx,
                          data=(args.batch_size, args.seq_len),
                          softmax_label=(args.batch_size, args.seq_len))
     init = mx.init.Xavier(magnitude=2.0)

@@ -87,7 +87,7 @@ def transfer(steps=60, lr=0.05, seed=0, log=None):
 
     # 1. extract targets with a forward-only binding of the feature net
     feats = feature_net()
-    fex = feats.simple_bind(mx.cpu(), grad_req="null", data=shape)
+    fex = feats.simple_bind(mx.tpu(0), grad_req="null", data=shape)
     init = mx.initializer.Xavier(magnitude=2.0)
     for name, arr in fex.arg_dict.items():
         if name != "data":
@@ -115,7 +115,7 @@ def transfer(steps=60, lr=0.05, seed=0, log=None):
     net = style_loss_net()
     reqs = {n: "write" if n == "data" else "null"
             for n in net.list_arguments()}
-    ex = net.simple_bind(mx.cpu(), grad_req=reqs, data=shape,
+    ex = net.simple_bind(mx.tpu(0), grad_req=reqs, data=shape,
                          **{k: v.shape for k, v in targets.items()})
     for n, v in weight_values.items():
         ex.arg_dict[n][:] = v
@@ -147,6 +147,7 @@ def main():
     ap.add_argument("--out", type=str, default="/tmp/neural_style.npy")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
     img, hist = transfer(steps=args.steps)
     np.save(args.out, img)
     logging.info("loss %0.4f -> %0.4f; stylised image -> %s",

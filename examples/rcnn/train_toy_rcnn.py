@@ -111,7 +111,7 @@ def main():
                             "rpn_heat": heat},
                            {"softmax_label": y}, batch_size=batch)
     mod = mx.Module(net, data_names=("data", "im_info", "rpn_heat"),
-                    label_names=("softmax_label",))
+                    label_names=("softmax_label",), context=mx.tpu(0))
     # the Group emits (cls_prob, rpn_loss); score on the classifier head
     def head_acc(label, pred):
         return float((pred.argmax(axis=1) == label).mean())

@@ -86,11 +86,13 @@ def main():
     ap.add_argument("--num-batches", type=int, default=10)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
 
     net = ssd.get_symbol_train(num_classes=args.num_classes)
     train = SyntheticDetIter(args.batch_size, args.num_classes,
                              args.num_batches)
-    mod = mx.Module(net, data_names=("data",), label_names=("label",))
+    mod = mx.Module(net, data_names=("data",), label_names=("label",),
+                    context=mx.tpu(0))
 
     class LocL1(mx.metric.EvalMetric):
         """Mean smooth-L1 localisation loss (parity: example/ssd MultiBoxMetric)."""
@@ -111,7 +113,7 @@ def main():
                                                         5)])
     logging.info("running detection symbol on one batch...")
     det = ssd.get_symbol(num_classes=args.num_classes)
-    ex = det.simple_bind(mx.cpu(), data=(args.batch_size, 3, 64, 64))
+    ex = det.simple_bind(mx.tpu(0), data=(args.batch_size, 3, 64, 64))
     arg_params, aux_params = mod.get_params()
     ex.copy_params_from(arg_params, aux_params, allow_extra_params=True)
     d, _ = synthetic_detection_batch(np.random.RandomState(1),

@@ -94,6 +94,7 @@ def main():
     ap.add_argument("--kv-store", default="local")
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
 
     net = get_symbol(args)
     if args.benchmark:
@@ -107,7 +108,7 @@ def main():
         data_shape=shape, batch_size=args.batch_size, shuffle=True,
         rand_crop=True, rand_mirror=True, resize=max(shape[1:]) + 32,
         mean_r=123.68, mean_g=116.78, mean_b=103.94, preprocess_threads=8)
-    mod = mx.Module(net)
+    mod = mx.Module(net, context=mx.tpu(0))
     mod.fit(train, num_epoch=args.num_epochs, optimizer=args.optimizer,
             optimizer_params={"learning_rate": args.lr, "momentum": 0.9},
             kvstore=args.kv_store,

@@ -66,6 +66,7 @@ def bigram_corpus(vocab, n_tokens, seed=0):
 
 def main():
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
     args = parse_args()
     T, B = args.seq_len, args.batch_size
 

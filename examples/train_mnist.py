@@ -63,18 +63,20 @@ def main():
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--num-epochs", type=int, default=3)
     ap.add_argument("--lr", type=float, default=0.1)
-    ap.add_argument("--gpus", default=None,
-                    help="e.g. 0,1 — maps to TPU cores/virtual devices")
+    ap.add_argument("--gpus", default="0",
+                    help="chips to train on, e.g. 0,1 (mx.gpu(i) is an "
+                         "alias of mx.tpu(i); virtual host devices under "
+                         "JAX_PLATFORMS=cpu)")
     ap.add_argument("--kv-store", default="local")
     ap.add_argument("--load-epoch", type=int, default=None)
     ap.add_argument("--model-prefix", default=None)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    mx.base.enable_compile_cache()
 
     net = (models.mlp if args.network == "mlp" else models.lenet) \
         .get_symbol(num_classes=10)
-    devs = [mx.gpu(int(i)) for i in args.gpus.split(",")] \
-        if args.gpus else [mx.cpu()]
+    devs = [mx.gpu(int(i)) for i in args.gpus.split(",")]
     train, val = get_iters(args)
 
     mod = mx.Module(net, context=devs)

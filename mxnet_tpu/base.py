@@ -12,7 +12,7 @@ import threading
 
 __all__ = ["MXNetError", "string_types", "numeric_types", "get_env", "check",
            "Registry", "classproperty", "TRACE_ENV_DEFAULTS", "trace_env_key",
-           "atomic_write"]
+           "atomic_write", "COMPILE_CACHE_DIR", "enable_compile_cache"]
 
 string_types = (str,)
 numeric_types = (float, int)
@@ -62,6 +62,32 @@ TRACE_ENV_DEFAULTS = (
 def trace_env_key():
     """Snapshot of the trace-affecting env flags, for jit cache keys."""
     return tuple(get_env(n, d) for n, d in TRACE_ENV_DEFAULTS)
+
+
+# The persistent XLA compile cache's home when nobody places it from
+# outside: one fixed directory at the root of the checkout (listed in
+# .gitignore).  Fixed, because a path built from tempfile, a pid or the
+# clock is a cache that never hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  Entry points that compile (chip_smoke.py,
+    bench.py, the examples, the measuring tools) call this once before
+    their first program; the library never does so on import.
+
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` where that is set — jax
+    reads it itself and no code names another — and ``COMPILE_CACHE_DIR``
+    otherwise.  Every program is kept, not only those over jax's one-second
+    default: a machine that starts cold on every run otherwise recompiles
+    the many small programs (initializers, copies, metrics) each time."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 class atomic_write(object):

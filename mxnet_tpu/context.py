@@ -1,11 +1,21 @@
 """Device context (parity: reference python/mxnet/context.py, include/mxnet/base.h:103-130).
 
-TPU-first design: a Context names a JAX device.  ``mx.tpu()`` is first-class; ``cpu``
-maps to the host platform.  ``gpu`` is accepted as an alias for the accelerator
-platform so that reference example scripts run unchanged on TPU.  Under the test
-harness (JAX_PLATFORMS=cpu with xla_force_host_platform_device_count=N) every
-``cpu(i)``/``tpu(i)`` resolves to one of the N virtual host devices, which is how
-multi-device semantics are tested without hardware.
+A Context names a JAX device, and what it names does not depend on what
+else is running:
+
+- ``mx.cpu(i)`` / ``mx.cpu_pinned(i)`` is the host: device ``i`` of JAX's
+  ``cpu`` backend.  Beside a chip that is the real host CPU (reference
+  parity: data loading, f32 references), so the ``cpu`` backend must be
+  initialised there — ``JAX_PLATFORMS=tpu,cpu``, not ``tpu`` alone.
+- ``mx.tpu(i)`` is local device ``i`` of the default backend, and that
+  backend must be a TPU.  ``mx.gpu(i)`` is an alias so reference example
+  scripts run unchanged.  The one exception is the test harness: with
+  ``JAX_PLATFORMS=cpu`` set explicitly (and
+  ``--xla_force_host_platform_device_count=N``) the N virtual host devices
+  stand in for chips, which is how multi-device semantics are tested
+  without hardware.  Anywhere else a default backend that is not a TPU —
+  no chip, or a chip held by another process — is an ``MXNetError``, never
+  a silent move to the host.
 """
 from __future__ import annotations
 
@@ -64,34 +74,71 @@ class Context(object):
 
     # -- JAX mapping ------------------------------------------------------
     def jax_device(self):
-        """Resolve this context to a concrete jax.Device."""
-        import jax
+        """Resolve this context to a concrete jax.Device (see the module
+        docstring for what each device type means)."""
+        if self.device_type in ("cpu", "cpu_pinned"):
+            devs = _host_devices()
+        else:
+            devs = _accelerator_devices(self)
+        if self.device_id >= len(devs):
+            raise MXNetError("no device for context %r: the %s backend has "
+                             "%d local device(s)"
+                             % (self, devs[0].platform, len(devs)))
+        return devs[self.device_id]
 
-        plat_order = {
-            "cpu": ("cpu",),
-            "cpu_pinned": ("cpu",),
-            # gpu/tpu both mean "the accelerator platform"; fall back to host
-            # so reference scripts written for gpu run under the CPU test harness.
-            "gpu": (None, "cpu"),
-            "tpu": (None, "cpu"),
-        }[self.device_type]
-        for plat in plat_order:
-            try:
-                # local_devices, not devices: under multi-process distributed
-                # training each process may only place data on its own
-                # addressable devices (global devices are reachable solely
-                # through collectives over the mesh).
-                devs = (jax.local_devices(backend=plat) if plat
-                        else jax.local_devices())
-                if plat is None and devs and devs[0].platform == "cpu" \
-                        and self.device_type in ("gpu", "tpu"):
-                    # default backend is host: treat virtual host devices as chips
-                    pass
-                if self.device_id < len(devs):
-                    return devs[self.device_id]
-            except RuntimeError:
-                continue
-        raise MXNetError("no device for context %r" % self)
+
+# local_devices, not devices: under multi-process distributed training each
+# process may only place data on its own addressable devices (global devices
+# are reachable solely through collectives over the mesh).
+def _host_devices():
+    import jax
+    try:
+        return jax.local_devices(backend="cpu")
+    except RuntimeError as e:
+        raise MXNetError(
+            "mx.cpu() needs JAX's host backend beside the accelerator, and "
+            "JAX_PLATFORMS=%r leaves it out: use JAX_PLATFORMS=tpu,cpu on a "
+            "machine with a chip (%s)"
+            % (jax.config.jax_platforms, e)) from e
+
+
+def cpu_harness():
+    """True when JAX was explicitly held to the host (``JAX_PLATFORMS=cpu``
+    or the equivalent ``jax.config`` update): the test harness, where
+    virtual host devices stand in for chips."""
+    import jax
+    return (jax.config.jax_platforms or "").strip().lower() == "cpu"
+
+
+def _accelerator_devices(ctx):
+    import jax
+    devs = jax.local_devices()
+    if devs[0].platform != "tpu" and not cpu_harness():
+        raise MXNetError(
+            "context %r needs a TPU, but the default JAX backend is %r "
+            "(JAX_PLATFORMS=%r): no chip is visible to this process, or "
+            "another process holds it.  Only an explicit JAX_PLATFORMS=cpu "
+            "(the test harness) maps %s contexts onto host devices."
+            % (ctx, devs[0].platform, jax.config.jax_platforms,
+               ctx.device_type))
+    return devs
+
+
+def announce_placement(who, contexts, logger):
+    """Say where an entry point computes: one INFO line naming the resolved
+    devices, and a WARNING when they are the host while the process has a
+    TPU — the reference's default context is ``cpu()``, and beside a chip
+    that trains and serves on the host unless ``mx.tpu(i)`` is passed."""
+    import jax
+    devs = [c.jax_device() for c in contexts]
+    logger.info("%s: computing on %s = %s (%s)", who,
+                ", ".join(str(c) for c in contexts),
+                ", ".join(str(d) for d in devs), devs[0].device_kind)
+    if devs[0].platform == "cpu" and jax.default_backend() == "tpu":
+        logger.warning(
+            "%s: context %s is the host CPU, but this process has a TPU "
+            "(%s) — pass context=mx.tpu(0) (dev_type='tpu') to compute on "
+            "the chip", who, contexts[0], jax.devices()[0].device_kind)
 
 
 def cpu(device_id=0):
@@ -100,7 +147,7 @@ def cpu(device_id=0):
 
 
 def gpu(device_id=0):
-    """Accelerator alias (parity: mx.gpu); resolves to the TPU/accelerator platform."""
+    """Accelerator alias (parity: mx.gpu); resolves exactly like ``mx.tpu``."""
     return Context("gpu", device_id)
 
 

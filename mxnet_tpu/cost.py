@@ -3,16 +3,20 @@
 The cost ledger (:mod:`mxnet_tpu.sanitize`) records what each compiled
 program *costs* — model FLOPs, bytes accessed — but an efficiency claim
 needs a denominator: the hardware's peak FLOP rate and memory bandwidth.
-This module resolves that pair, in order of precedence:
+This module resolves that pair from what the process runs on:
 
-1. ``MXNET_PEAK_FLOPS`` / ``MXNET_PEAK_BW`` — explicit per-chip peaks
-   (FLOP/s and bytes/s; SI suffixes K/M/G/T/P accepted, e.g. ``275T``
-   and ``1228G``).  Either alone is honoured; MFU needs only FLOPS.
-2. On a real TPU backend, the device-kind table below (per-chip dense
-   peak FLOP/s and HBM bandwidth, from published chip specs).
+- On a TPU backend, the device-kind table below (per-chip dense peak
+  FLOP/s and HBM bandwidth, from published chip specs), and nothing else:
+  a ``device_kind`` the table does not know is an ``MXNetError``, and
+  ``MXNET_PEAK_FLOPS`` / ``MXNET_PEAK_BW`` cannot assert other peaks for a
+  chip (a utilization figure against a made-up peak is worse than none).
+- Off a TPU (the CPU harness), ``MXNET_PEAK_FLOPS`` / ``MXNET_PEAK_BW`` —
+  explicit peaks (FLOP/s and bytes/s; SI suffixes K/M/G/T/P accepted,
+  e.g. ``275T`` and ``1228G``) that arm the accounting so its arithmetic
+  can be tested.  Either alone is honoured; MFU needs only FLOPS.  With
+  neither set every consumer degrades to None — the strict no-op
+  contract: no gauges, no roofline verdicts, no sentinel MFU watch.
 
-With neither available every consumer degrades to None — the strict
-no-op contract: no gauges, no roofline verdicts, no sentinel MFU watch.
 Nothing here imports or initializes jax at module import; the device
 probe runs only when a caller (the fused fit, diagnostics) asks after
 the backend already exists.
@@ -26,7 +30,7 @@ Definitions (docs/observability.md "Cost attribution & MFU"):
 """
 from __future__ import annotations
 
-from .base import get_env
+from .base import MXNetError, get_env
 
 __all__ = ["resolve_peaks", "enabled", "mfu", "ridge", "verdict",
            "DEVICE_PEAKS"]
@@ -67,36 +71,34 @@ def _parse_rate(raw):
 
 
 def _device_peaks():
-    """(peak_flops, peak_bw) from the TPU device-kind table; (None,
-    None) off-TPU or when jax is not importable/initialized yet."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return (None, None)
-        kind = str(getattr(dev, "device_kind", "")).lower()
-    except Exception:
-        return (None, None)
+    """(peak_flops, peak_bw) from the TPU device-kind table, or None off
+    a TPU.  A TPU the table does not list is an error, not a null."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    kind = str(dev.device_kind).lower()
     for key, flops, bw in DEVICE_PEAKS:
         if key in kind:
             return (flops, bw)
-    return (None, None)
+    raise MXNetError(
+        "cost: no roofline peaks for TPU device_kind %r — add its "
+        "published per-chip peaks to cost.DEVICE_PEAKS (known: %s)"
+        % (dev.device_kind, ", ".join(k for k, _, _ in DEVICE_PEAKS)))
 
 
 def resolve_peaks(refresh=False):
-    """The active ``(peak_flops, peak_bw)`` pair, each possibly None.
-    Env vars win; the TPU table fills whichever the env left unset.
+    """The active ``(peak_flops, peak_bw)`` pair.  The device table on a
+    TPU; the ``MXNET_PEAK_*`` variables (each possibly None) elsewhere.
     Cached after the first call (``refresh=True`` re-reads — tests)."""
     global _cache
     if _cache is not None and not refresh:
         return _cache
-    flops = _parse_rate(get_env("MXNET_PEAK_FLOPS"))
-    bw = _parse_rate(get_env("MXNET_PEAK_BW"))
-    if flops is None or bw is None:
-        dflops, dbw = _device_peaks()
-        flops = flops if flops is not None else dflops
-        bw = bw if bw is not None else dbw
-    _cache = (flops, bw)
+    peaks = _device_peaks()
+    if peaks is None:
+        peaks = (_parse_rate(get_env("MXNET_PEAK_FLOPS")),
+                 _parse_rate(get_env("MXNET_PEAK_BW")))
+    _cache = peaks
     return _cache
 
 

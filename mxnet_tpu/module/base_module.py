@@ -146,11 +146,12 @@ class BaseModule(object):
                 reset=True, always_output_list=False):
         """Collect forward outputs over a data iterator, de-padded
         (parity surface: base_module.predict)."""
-        per_batch = [outs for outs, _, _
+        # iter_predict yields views of the executor's output buffers, which
+        # the NEXT batch's forward overwrites: own each batch's rows as it
+        # is yielded, not after the loop
+        per_batch = [[o.copy() for o in outs] for outs, _, _
                      in self.iter_predict(eval_data, num_batch=num_batch,
                                           reset=reset)]
-        # iter_predict yields views; own the buffers before batches merge
-        per_batch = [[o.copy() for o in outs] for outs in per_batch]
         if not per_batch or not merge_batches:
             return per_batch
         widths = {len(outs) for outs in per_batch}

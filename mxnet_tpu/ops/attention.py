@@ -25,13 +25,6 @@ def _attn_infer(attrs, in_shapes):
     return list(in_shapes), [q], None
 
 
-def _on_tpu():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 @register("dot_product_attention", arg_names=("query", "key", "value"),
           attr_types={"causal": parse_bool, "scale": parse_float,
                       "impl": str},
@@ -56,7 +49,8 @@ def _dot_product_attention(query, key, value, causal=False, scale=None,
         return ring.ring_attention(query, key, value, mesh, axis=axis,
                                    causal=causal, scale=scale)
     use_flash = impl == "flash" or (
-        impl == "auto" and _on_tpu() and query.shape[2] >= 512
+        impl == "auto" and jax.default_backend() == "tpu"
+        and query.shape[2] >= 512
         and pallas_kernels.flash_available(query.shape, key.shape,
                                            value.shape))
     if use_flash:

@@ -11,7 +11,8 @@ O(T) memory in both directions).
 
 Used by ``dot_product_attention`` (ops/attention.py) on TPU for long
 sequences; everything is shape-guarded so XLA's fused attention remains the
-fallback.  Tested in Pallas interpret mode on the CPU harness.
+fallback.  Tested in Pallas interpret mode on the CPU harness and compiled on
+the chip by ``tools/tpu_numerics_check.py``.
 """
 from __future__ import annotations
 
@@ -20,25 +21,40 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 __all__ = ["flash_attention", "flash_available"]
 
 _NEG_INF = -1e30
 
 
+# VMEM budgets of the shape guard, in bytes at the f32 upper bound.  What
+# they admit was compiled, forward and both backward kernels, on a v5e
+# (libtpu 0.0.34): the corners T*D = 2**20 at D = 64, 128 and 256 pass in
+# f32 and bf16; T=32768, D=32 in f32 is what the compiler refuses (128 MB
+# by the dK/dV estimate below), and it is no longer admitted.
+_KV_BUDGET = 8 * 1024 * 1024
+_DKV_BUDGET = 64 * 1024 * 1024
+
+
 def flash_available(q_shape, k_shape=None, v_shape=None, block_q=128,
                     block_k=128):
     """Shape guard: self-attention only (q/k/v shapes equal), T divisible
-    into blocks, D lane-friendly, and one head's K+V must fit VMEM (the
-    kernel keeps a (T, D) K and V slice resident while Q is tiled)."""
-    if pl is None or len(q_shape) != 4:
+    into blocks, D lane-friendly, and each kernel's whole-T residents must
+    fit VMEM: one head's K+V in the forward and dQ kernels, and in the
+    dK/dV kernel q, dO and the (T, 1) lse/delta columns — double-buffered,
+    with the last dimension padded to the 128 lanes of a VMEM tile, so a
+    narrow head costs as much as D=128 and each column as much as a
+    (T, 128) block."""
+    if len(q_shape) != 4:
         return False
     for other in (k_shape, v_shape):
         if other is not None and tuple(other) != tuple(q_shape):
             return False  # cross-attention -> XLA path
     t, d = q_shape[2], q_shape[3]
-    # 2 * t * d * 4B (f32 upper bound) must leave VMEM room for q/o/acc
-    if 2 * t * d * 4 > 8 * 1024 * 1024:
+    if 2 * t * d * 4 > _KV_BUDGET:
+        return False
+    if 2 * 2 * t * (max(d, 128) + 128) * 4 > _DKV_BUDGET:
         return False
     return t % block_q == 0 and t % block_k == 0 and t >= block_q and \
         d % 8 == 0 and d <= 256
@@ -82,12 +98,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # log-sum-exp residual for the blocked backward
     lse_ref[0] = m + jnp.log(l)
-
-
-try:  # pallas import kept lazy-safe for exotic builds
-    from jax.experimental import pallas as pl
-except Exception:  # pragma: no cover
-    pl = None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -267,8 +277,8 @@ def _flash_bwd_xla(causal, scale, block_q, block_k, interpret, res, g):
     time against the saved log-sum-exp, so the (T, T) matrix never
     materialises in the backward either — O(T·block) live memory, matmuls
     on the MXU.  Kept as the reference implementation the Pallas kernels
-    are tested against (the forward itself requires pallas, so this is not
-    a runtime fallback — flash_available gates on pl)."""
+    are tested against (the forward itself is a Pallas kernel, so this is
+    not a runtime fallback)."""
     q, k, v, out, lse = res
     b, h, t, d = q.shape
     sc = scale if scale is not None else 1.0 / math.sqrt(d)

@@ -138,7 +138,7 @@ def _coordination_connect(coord, nproc, pid):
     (a zombie generation's destructor must not run a blocking handshake
     with a dead world)."""
     from jax._src import distributed as _jdist
-    from jax._src.lib import xla_extension as _xe
+    from jax._src.lib import _jax as _xe
     state = _jdist.global_state
     if state.client is not None:
         # same message class as jax.distributed.initialize — _connect

@@ -62,7 +62,6 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n = mesh.shape[axis]
     d = q.shape[-1]
@@ -120,6 +119,6 @@ def ring_attention(q, k, v, mesh, axis="sp", causal=False, scale=None):
         return (o / jnp.maximum(l, 1e-30)).astype(qb.dtype)
 
     spec = P(None, None, axis, None)
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

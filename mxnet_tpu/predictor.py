@@ -76,6 +76,10 @@ class Predictor(object):
                 [internals._outputs[i] for i in picked])
         self.symbol = symbol
         ctx = Context(dev_type, dev_id)
+        if copy_params:   # a serving rung shares ServedModel's announcement
+            import logging
+            from .context import announce_placement
+            announce_placement("Predictor", [ctx], logging)
         arg_params, aux_params = _load_params(param_blob)
 
         input_shapes = {k: tuple(int(x) for x in v)

@@ -35,16 +35,11 @@ class Rtc(object):
         self._interpret = interpret
         self._compiled = {}
 
-    def _interp(self):
-        if self._interpret is not None:
-            return self._interpret
-        import jax
-        return jax.default_backend() != "tpu"
-
-    def _get(self, out_shapes, out_dtypes):
+    def _get(self, out_shapes, out_dtypes, interpret):
         import jax
         from jax.experimental import pallas as pl
-        key = (tuple(out_shapes), tuple(str(d) for d in out_dtypes))
+        key = (tuple(out_shapes), tuple(str(d) for d in out_dtypes),
+               interpret)
         fn = self._compiled.get(key)
         if fn is None:
             shapes = [jax.ShapeDtypeStruct(s, d)
@@ -55,7 +50,7 @@ class Rtc(object):
             call = pl.pallas_call(
                 self.kernel,
                 out_shape=shapes if len(shapes) > 1 else shapes[0],
-                interpret=self._interp(), **kwargs)
+                interpret=interpret, **kwargs)
             fn = jax.jit(call)
             self._compiled[key] = fn
         return fn
@@ -73,7 +68,16 @@ class Rtc(object):
             raise MXNetError("%s expects %d outputs, got %d"
                              % (self.name, len(self.output_names),
                                 len(outs)))
-        fn = self._get([o.shape for o in outs], [o.dtype for o in outs])
+        # the kernel runs where its inputs live.  Mosaic compiles for the
+        # TPU only, so inputs on a host device (mx.cpu(), or any context
+        # under the JAX_PLATFORMS=cpu harness) go through the Pallas
+        # interpreter; inputs on a chip are always compiled.
+        interpret = self._interpret
+        if interpret is None:
+            interpret = (list(ins) or list(outs))[0].context \
+                .jax_device().platform == "cpu"
+        fn = self._get([o.shape for o in outs], [o.dtype for o in outs],
+                       bool(interpret))
         res = fn(*[i.value for i in ins])
         if not isinstance(res, (tuple, list)):
             res = (res,)

@@ -404,8 +404,10 @@ def _raw_compile(fun_name, shapes):
 def _make_log_handler():
     import logging
     import re
-    pat = re.compile(
-        r"^Compiling (\S+) with global shapes and types (\[.*?\])\.")
+    # jax 0.9: "Compiling jit(<fun>) with global shapes and types
+    # (<avals>,). Argument mapping: ..."
+    pat = re.compile(r"^Compiling jit\((\S+)\) with global shapes and "
+                     r"types (\(.*?\))\. Argument mapping:")
 
     class _CompileLogHandler(logging.Handler):
         def emit(self, record):
@@ -418,7 +420,7 @@ def _make_log_handler():
             # recompile-loop signal; names a registered cache declared
             # (via jit_names=) are that cache's own misses, watched by
             # its handle with its own warmup budget
-            if m and m.group(2) != "[]" \
+            if m and m.group(2) != "()" \
                     and m.group(1) not in _REGISTERED_JIT_NAMES:
                 _raw_compile(m.group(1), m.group(2))
 
@@ -925,21 +927,39 @@ def cost_note(name, analysis, compile_s=None):
 
 def program_capture(name, fn, args=(), kwargs=None, cache=None):
     """The unified capture-at-compile hook: one timed
-    ``fn.lower(*args).compile()`` (the executable is shared with the jit
-    cache, so arming pays each compile once), then whatever ledgers are
-    armed — ``memory_analysis()`` when ``_hbm_on``, ``cost_analysis()``
-    when ``_cost_on`` — plus compile-seconds accounting against
-    ``cache`` (a register_cache handle) and a ``compile.seconds``
-    telemetry span.  Best-effort like :func:`hbm_capture`: any failure
-    degrades to a silent None.  Returns ``{"hbm": row|None,
-    "cost": row|None}``."""
+    ``fn.lower(*args).compile()``, then whatever ledgers are armed —
+    ``memory_analysis()`` when ``_hbm_on``, ``cost_analysis()`` when
+    ``_cost_on`` — plus compile-seconds accounting against ``cache`` (a
+    register_cache handle) and a ``compile.seconds`` telemetry span.
+
+    Arming pays each compile once: jax caches the lowering per argument
+    signature and keeps the compiled executable on it, so the dispatch of
+    ``fn`` with these same arguments reuses what was compiled here
+    (test_cost.py counts the backend compiles; on the chip,
+    ``chip_smoke.py`` does).
+
+    Attribution must never add a failure mode to the program it measures,
+    so a capture that cannot run returns None — but not silently: tracer
+    arguments (an executor grad jit first invoked under ``jax.vjp``) are
+    the one expected case and skip quietly; any other lowering or compile
+    error is logged as a warning naming the program, because the ledger
+    row, and every MFU figure that needs it, will be missing.  Returns
+    ``{"hbm": row|None, "cost": row|None}``."""
     if not (_hbm_on or _cost_on):
+        return None
+    import jax
+    if any(isinstance(leaf, jax.core.Tracer)
+           for leaf in jax.tree_util.tree_leaves((args, kwargs))):
         return None
     wall = time.time()
     t0 = time.perf_counter()
     try:
         compiled = fn.lower(*args, **(kwargs or {})).compile()
-    except Exception:
+    except Exception as e:
+        import logging
+        logging.getLogger(__name__).warning(
+            "mxsan: no HBM/cost row for program '%s' — lowering it for "
+            "attribution failed: %s: %s", name, type(e).__name__, e)
         return None
     dur = time.perf_counter() - t0
     if cache is not None:

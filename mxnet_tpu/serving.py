@@ -171,6 +171,9 @@ class ServedModel(object):
         # rung, and rung creation never re-parses the blob
         arg_p, aux_p = _load_params(param_blob)
         ctx = Context(dev_type, dev_id)
+        import logging
+        from .context import announce_placement
+        announce_placement("ServedModel %r" % self.name, [ctx], logging)
         self._param_blob = {}
         for prefix, group in (("arg:", arg_p), ("aux:", aux_p)):
             for k, v in group.items():

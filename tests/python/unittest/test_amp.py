@@ -309,10 +309,21 @@ def test_explicit_fit_policy_kwarg():
     pol = Policy("float32", loss_scale=8.0)
     m, p1, _ = _fit(policy=pol)
     assert m._fused_ts_cache[1].policy is pol
-    # power-of-two f32 policy == plain f32 run, end to end through fit
+    # power-of-two f32 policy == plain f32 run, end to end through fit —
+    # to f32 round-off, not bitwise.  Scaling by 8 and back is exact per
+    # element, but the two fits are two different XLA programs (the policy
+    # step adds the finite check, the lax.cond and the scale multiplies),
+    # and XLA is free to fuse them differently and so to sum the batch
+    # reductions in another order; those last-bit differences compound
+    # over the 12 momentum updates (7e-9 absolute under jax 0.9's XLA:CPU).
+    # Bitwise equality across separately compiled programs is not a
+    # contract this repo can hold; agreement at 1e-6 of the tensor's scale
+    # is what the test means.
     m0, p0, _ = _fit()
     for k in p0:
-        np.testing.assert_array_equal(p0[k], p1[k], err_msg=k)
+        np.testing.assert_allclose(p1[k], p0[k], rtol=1e-6,
+                                   atol=1e-6 * np.abs(p0[k]).max(),
+                                   err_msg=k)
 
 
 # ------------------------------------------------------------- telemetry
@@ -456,15 +467,21 @@ def test_measure_data_wait_respects_prefetch_off(monkeypatch):
 # ------------------------------------------------------- run_compare gate
 def test_bench_record_gates_with_run_compare(tmp_path):
     """A new-format BENCH record (amp + data_wait_share stamped) compares
-    against the committed BENCH_r05.json through run_compare --check: a
-    faster run passes, a >5% slower one exits 2 (the mechanical gate)."""
+    against an old-format baseline (the driver-wrapper shape: no amp, no
+    telemetry block) through run_compare --check: a faster run passes, a
+    >5% slower one exits 2 (the mechanical gate)."""
     import json
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__),
                                     "..", "..", ".."))
     from tools import run_compare
-    repo = os.path.join(os.path.dirname(__file__), "..", "..", "..")
-    r05 = os.path.join(repo, "BENCH_r05.json")
+    base = tmp_path / "BENCH_base.json"
+    base.write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 0, "tail": "",
+        "parsed": {"metric": "resnet50_train_img_per_sec_b32",
+                   "value": 2950.0, "unit": "img/s",
+                   "vs_baseline": 16.251}}))
+    base = str(base)
 
     def rec(value):
         return {"metric": "resnet50_train_img_per_sec_b32", "value": value,
@@ -480,8 +497,8 @@ def test_bench_record_gates_with_run_compare(tmp_path):
     slow = tmp_path / "BENCH_new_slow.json"
     fast.write_text(json.dumps(rec(3100.0)))
     slow.write_text(json.dumps(rec(2500.0)))
-    assert run_compare.main([r05, str(fast), "--check"]) == 0
-    assert run_compare.main([r05, str(slow), "--check"]) == 2
+    assert run_compare.main([base, str(fast), "--check"]) == 0
+    assert run_compare.main([base, str(slow), "--check"]) == 2
 
 
 # ----------------------------------------------------------- mesh / ZeRO-1

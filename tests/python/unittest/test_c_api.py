@@ -20,12 +20,13 @@ EXAMPLE = os.path.join(BUILD, "mlp_predict")
 
 @pytest.fixture(scope="module")
 def libmx():
-    if not os.path.exists(LIB):
-        subprocess.run(["cmake", "-S", REPO, "-B", BUILD, "-G", "Ninja",
-                        "-DCMAKE_BUILD_TYPE=Release"], check=True,
-                       capture_output=True)
-        subprocess.run(["ninja", "-C", BUILD], check=True,
-                       capture_output=True)
+    # always configure + build: build/ is ignored by git, so a library found
+    # there may come from another tree; ninja is incremental, an up-to-date
+    # build costs a fraction of a second
+    subprocess.run(["cmake", "-S", REPO, "-B", BUILD, "-G", "Ninja",
+                    "-DCMAKE_BUILD_TYPE=Release"], check=True,
+                   capture_output=True)
+    subprocess.run(["ninja", "-C", BUILD], check=True, capture_output=True)
     lib = ctypes.CDLL(LIB)
     lib.MXGetLastError.restype = ctypes.c_char_p
     assert lib.MXTPULibInit() == 0, "library init failed"

@@ -60,6 +60,26 @@ def test_parse_rate_grammar():
         assert cost._parse_rate(junk) is None
 
 
+def test_resolve_peaks_on_a_tpu_come_from_the_table_only(monkeypatch):
+    """On a TPU the device-kind table decides: the kind jax reports for a
+    v5e resolves, MXNET_PEAK_* cannot assert other peaks for a chip, and a
+    kind the table does not know is an error, not a null MFU."""
+    import jax
+    from mxnet_tpu.base import MXNetError
+
+    class Dev(object):
+        platform = "tpu"
+
+        def __init__(self, kind):
+            self.device_kind = kind
+    monkeypatch.setenv("MXNET_PEAK_FLOPS", "1T")
+    monkeypatch.setattr(jax, "devices", lambda: [Dev("TPU v5 lite")])
+    assert cost.resolve_peaks(refresh=True) == (197e12, 819e9)
+    monkeypatch.setattr(jax, "devices", lambda: [Dev("TPU v9 imaginary")])
+    with pytest.raises(MXNetError, match="TPU v9 imaginary"):
+        cost.resolve_peaks(refresh=True)
+
+
 def test_resolve_peaks_env_precedence(monkeypatch):
     # unset + CPU backend: strict no-op — nothing resolves
     monkeypatch.delenv("MXNET_PEAK_FLOPS", raising=False)
@@ -69,7 +89,8 @@ def test_resolve_peaks_env_precedence(monkeypatch):
     assert cost.mfu(1e9, 0.1) is None
     assert cost.ridge() is None
     assert cost.verdict(10.0) is None
-    # env wins; either alone is honoured (MFU needs only FLOPS)
+    # off a TPU the env arms it; either alone is honoured (MFU needs only
+    # FLOPS)
     monkeypatch.setenv("MXNET_PEAK_FLOPS", "100G")
     assert cost.resolve_peaks(refresh=True) == (pytest.approx(100e9), None)
     assert cost.enabled()
@@ -126,7 +147,34 @@ def test_cost_capture_matches_cost_analysis():
     assert san.cost_ledger() == {}          # disarm clears
 
 
-def test_cost_capture_disarmed_and_degraded():
+def test_capture_then_dispatch_compiles_once():
+    """Arming attribution pays each compile once: the dispatch that follows
+    a capture with the same arguments reuses the executable the capture
+    compiled (donation included — the TrainStep case)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import monitoring
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+    x = jnp.ones((32, 32), jnp.float32)
+    y = jnp.ones((32, 32), jnp.float32)
+    fn = jax.jit(lambda a, b: (a @ b, a + 1), donate_argnums=(0,))
+    san.cost_arm()
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        assert san.program_capture("once", fn, (x, y))["cost"] is not None
+        assert len(compiles) == 1
+        jax.block_until_ready(fn(x, y))
+        assert len(compiles) == 1, "the dispatch compiled the program again"
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+        san.cost_disarm()
+
+
+def test_cost_capture_disarmed_and_degraded(caplog):
     import jax
     import jax.numpy as jnp
     fn = jax.jit(lambda x: x + 1)
@@ -135,8 +183,11 @@ def test_cost_capture_disarmed_and_degraded():
     assert san.cost_ledger() == {}
     san.cost_arm()
     try:
-        # a non-lowerable callable degrades to silent None, never an error
-        assert san.program_capture("bad", lambda x: x, (x,)) is None
+        # a non-lowerable callable degrades to None, never an error — and
+        # says so, because the ledger row will be missing
+        with caplog.at_level("WARNING", logger="mxnet_tpu.sanitize"):
+            assert san.program_capture("bad", lambda x: x, (x,)) is None
+        assert "no HBM/cost row for program 'bad'" in caplog.text
         assert "bad" not in san.cost_ledger()
         assert san.program_wrap("w", lambda: 0)() == 0    # wrapper still calls
         # junk analysis objects degrade too

@@ -100,6 +100,12 @@ def test_available_guard():
     big = (1, 224, 224, 512)
     assert not norm_conv_available(big, (3, 3, 512, 512), (1, 1), (1, 1))
     assert NC_VMEM_BUDGET <= 16 * 1024 * 1024
+    # the budget follows what compiled on the chip: ResNet-50's largest
+    # site is admitted, the smallest estimate Mosaic refused is not
+    assert norm_conv_available((8, 56, 56, 256), (1, 1, 256, 512),
+                               (2, 2), (0, 0))
+    assert not norm_conv_available((8, 112, 112, 64), (3, 3, 64, 128),
+                                   (2, 2), (1, 1))
 
 
 def _train_step(env, num_layers, image, batch=4, nclass=10, seed=0):

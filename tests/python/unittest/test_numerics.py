@@ -452,14 +452,17 @@ def test_numerics_report_help_and_curated_errors(tmp_path):
         nr.load_numerics(str(junk))
 
 
-def test_tpu_numerics_check_skips_off_tpu():
+def test_tpu_numerics_check_fails_off_tpu():
+    """The on-chip kernel check checks nothing without a chip, and says so
+    with a non-zero exit — never a SKIP that reads as a pass."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, str(ROOT / "tools" / "tpu_numerics_check.py")],
         capture_output=True, text=True, env=env, timeout=120)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "SKIP: no TPU backend" in proc.stdout
+    assert proc.returncode != 0, proc.stdout + proc.stderr
+    assert "nothing was checked" in proc.stderr
+    assert "PASS" not in proc.stdout
 
 
 def test_multichip_num_record_gates_itself():

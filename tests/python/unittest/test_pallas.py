@@ -94,6 +94,11 @@ def test_flash_available_guard():
     assert not flash_available((2, 2, 100, 64))    # T not block-divisible
     assert not flash_available((2, 2, 1024, 300))  # D too large
     assert not flash_available((2, 1024, 64))      # wrong rank
+    # the corners the chip run compiled stay admitted; T=32768, D=32 —
+    # which Mosaic refuses in f32 (dK/dV residents) — does not
+    for t, d in ((16384, 64), (8192, 128), (4096, 256)):
+        assert flash_available((1, 1, t, d))
+    assert not flash_available((1, 1, 32768, 32))
 
 
 def test_attention_op_impl_attr():

@@ -42,6 +42,16 @@ def test_predictor_matches_module(tmp_path):
     # trained model should classify the separable blobs correctly
     assert (out.argmax(axis=1) == y[:batch]).mean() > 0.8
 
+    # over several batches predict() must own each batch's rows before the
+    # next forward overwrites the executor's output buffers
+    it = mx.io.NDArrayIter(x[:3 * batch], y[:3 * batch].astype(np.float32),
+                           batch_size=batch)
+    many = mod.predict(it).asnumpy()
+    for i in range(3):
+        pred.forward(data=x[i * batch:(i + 1) * batch])
+        np.testing.assert_allclose(many[i * batch:(i + 1) * batch],
+                                   pred.get_output(0), rtol=1e-5, atol=1e-6)
+
 
 def test_predictor_from_blob_bytes(tmp_path):
     prefix, _, x, _ = _checkpoint(tmp_path)

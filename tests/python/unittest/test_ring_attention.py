@@ -51,7 +51,9 @@ def test_ring_gradients_match():
     def loss_ref(q, k, v):
         return (attention_reference(q, k, v, causal=True) ** 2).sum()
 
-    g_ring = jax.grad(loss_ring, argnums=(0, 1, 2))(qd, kd, vd)
+    # under jit, as every training step differentiates it (an eager
+    # shard_map dispatches the ring's loop op by op: ~50 s for this test)
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(qd, kd, vd)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
     for gr, gf in zip(g_ring, g_ref):

@@ -129,19 +129,13 @@ def test_reduce_scatter_hlo_supported_on_cpu():
     import re
     mesh = make_mesh({"dp": 8})
     from jax.sharding import PartitionSpec as P, NamedSharding
-    # jax >= 0.6 promotes shard_map to jax.shard_map; this jax still ships
-    # it under jax.experimental (jax.shard_map raises AttributeError here)
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     @jax.jit
     def f(x):
         def body(xl):
             return jax.lax.psum_scatter(xl, "dp", scatter_dimension=0,
                                         tiled=True)
-        return shard_map(body, mesh=mesh, in_specs=P("dp"),
-                         out_specs=P("dp"))(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                             out_specs=P("dp"))(x)
 
     x = jax.device_put(np.ones((64, 4), np.float32),
                        NamedSharding(mesh, P("dp")))

@@ -186,6 +186,10 @@ def main():
                     help="stage raw uint8 batches, normalise on device "
                          "(orthogonal to the record format)")
     args = ap.parse_args()
+    import bench
+    from mxnet_tpu.base import enable_compile_cache
+    enable_compile_cache()
+    print(json.dumps({"device": bench.device_stamp()}), flush=True)
     with tempfile.TemporaryDirectory() as td:
         rec = os.path.join(td, "data.rec")
         t0 = time.perf_counter()

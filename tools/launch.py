@@ -14,7 +14,11 @@ MXTPU_* env contract (see mxnet_tpu/parallel/dist.py):
 Launch modes:
 - ``local`` (default): N processes on this host — the mode the reference's
   nightly dist tests use; on a TPU pod each host runs one process and an
-  external scheduler (GKE/SLURM/ray) plays this role instead.
+  external scheduler (GKE/SLURM/ray) plays this role instead.  A chip
+  belongs to one process at a time, so on a host with TPU chips each of the
+  N ranks is given ONE chip of its own (``local_chip_env``); a world the
+  chips cannot seat is refused with a message instead of left to hang.
+  The launcher itself never imports jax, so it never holds a chip.
 - ``ssh``: one process per host listed in --hostfile, sharing the same env
   contract (requires passwordless ssh; mirrors the reference's ssh tracker).
 """
@@ -57,6 +61,58 @@ def observability_env():
     return {k: os.environ[k] for k in OBSERVABILITY_ENV if k in os.environ}
 
 
+# libtpu's process grid for n one-chip ranks on one host (x,y,z)
+_TPU_PROCESS_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1", 8: "2,4,1"}
+_TPU_PROCESS_PORT = 8476
+
+
+def _cpu_world():
+    """An explicit JAX_PLATFORMS=cpu: the children never touch a chip."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def local_tpu_chips():
+    """How many TPU chips this host exposes, read from the device nodes
+    (``/dev/accelN`` up to v4, ``/dev/vfio/N`` from v5e) — never through
+    jax, which would claim them.  0 off a TPU host."""
+    import glob
+    return len(glob.glob("/dev/accel[0-9]*")
+               or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def local_chip_env(rank, n):
+    """The env that seats local rank ``rank`` of ``n`` on a chip of its own.
+
+    Without it every child inherits the same environment and claims every
+    chip: the first wins and the rest fail at backend start-up or wait for
+    a chip that never frees.  Empty off a TPU host, for a single rank (it
+    may drive all chips), and under an explicit ``JAX_PLATFORMS=cpu`` (the
+    CPU test harness).  Raises SystemExit when the chips cannot seat the
+    world."""
+    if n == 1 or _cpu_world():
+        return {}
+    chips = local_tpu_chips()
+    if not chips:
+        return {}
+    if n > chips or n not in _TPU_PROCESS_BOUNDS:
+        raise SystemExit(
+            "launch.py: cannot seat %d local rank(s) on this host's %d TPU "
+            "chip(s): each rank needs a chip of its own (a chip belongs to "
+            "one process at a time) and the ranks must form a %s grid.  Run "
+            "one process — it drives every chip through a mesh — or set "
+            "JAX_PLATFORMS=cpu for a CPU world."
+            % (n, chips, "/".join(sorted(_TPU_PROCESS_BOUNDS.values()))))
+    return {
+        "TPU_VISIBLE_CHIPS": str(rank),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_BOUNDS[n],
+        "TPU_PROCESS_ADDRESSES": ",".join(
+            "localhost:%d" % (_TPU_PROCESS_PORT + r) for r in range(n)),
+        "TPU_PROCESS_PORT": str(_TPU_PROCESS_PORT + rank),
+        "CLOUD_TPU_TASK_ID": str(rank),
+    }
+
+
 def launch_local(n, command, env_extra=None, max_restarts=0):
     """Run n copies of `command` locally with the MXTPU_* env contract.
 
@@ -72,6 +128,7 @@ def launch_local(n, command, env_extra=None, max_restarts=0):
         procs = []
         for rank in range(n):
             env = dict(os.environ)
+            env.update(local_chip_env(rank, n))
             env.update(env_extra or {})
             env["MXTPU_COORDINATOR"] = "localhost:%d" % port
             env["MXTPU_NUM_PROCESSES"] = str(n)
@@ -158,6 +215,14 @@ def launch_elastic(n, command, wmin, wmax, env_extra=None, max_restarts=0,
     if not 1 <= wmin <= n <= wmax:
         raise ValueError("--elastic bounds must satisfy 1 <= min <= n <= "
                          "max; got min=%d n=%d max=%d" % (wmin, n, wmax))
+    if wmax > 1 and local_tpu_chips() and not _cpu_world():
+        # a live resize re-ranks processes and changes the world size;
+        # libtpu's process grid is fixed when a process claims its chip
+        raise SystemExit(
+            "launch.py: --elastic worlds of more than one local rank are "
+            "not supported on a TPU host: each rank owns one chip through "
+            "a process grid that cannot be resized live.  Use "
+            "--max-restarts (whole-world respawn), or JAX_PLATFORMS=cpu.")
     plan_dir = tempfile.mkdtemp(prefix="mxtpu-elastic-")
     plan_path = os.path.join(plan_dir, "world_plan.json")
     gen = 1

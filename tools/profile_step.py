@@ -137,6 +137,11 @@ def main():
     args = ap.parse_args()
 
     if not args.parse_only:
+        sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+        import bench
+        from mxnet_tpu.base import enable_compile_cache
+        enable_compile_cache()
+        print("device:", bench.device_stamp(), file=sys.stderr)
         ts, params, state, aux, batch_dev = build_step(
             args.batch, model=args.model)
         capture(ts, params, state, aux, batch_dev, args.steps, args.out)

@@ -24,7 +24,7 @@ their curves by step, and answers "did run B get worse than run A":
 
 Usage:
     python tools/run_compare.py good.jsonl bad.jsonl
-    python tools/run_compare.py BENCH_r04.json BENCH_r05.json --check
+    python tools/run_compare.py bench_parent.json bench_change.json --check
     python tools/run_compare.py a.jsonl b.jsonl --json --threshold 0.02
     python tools/run_compare.py a.jsonl b.jsonl --metric train_accuracy
 
