@@ -1149,18 +1149,14 @@ class Executor(object):
         from . import telemetry as _tel
         mode = "train" if is_train else "test"
         with _profiler.Scope("executor.forward[%s]" % mode, "symbolic"), \
-                _san.hot_region("executor.forward"):
-            if not _tel._enabled:
-                return self._forward_impl(is_train, **kwargs)
-            # jit="miss" on the span marks the call that paid trace+compile;
-            # steady-state calls run the cached computation (jit="hit")
+                _san.hot_region("executor.forward"), \
+                _tel.span("executor.forward", cat="executor",
+                          mode=mode) as sp:
+            # jit="miss" on the registry's event marks the call that paid
+            # trace+compile; steady-state calls run the cached computation
             self._jit_last = "hit"
-            # mirror=False: the profiler Scope above already records this
-            # region — don't double-count it in the chrome trace
-            with _tel.span("executor.forward", cat="executor",
-                           mirror=False, mode=mode) as sp:
-                out = self._forward_impl(is_train, **kwargs)
-                sp.tags["jit"] = self._jit_last
+            out = self._forward_impl(is_train, **kwargs)
+            sp.tags["jit"] = self._jit_last
             return out
 
     def _forward_impl(self, is_train=False, **kwargs):
@@ -1232,12 +1228,9 @@ class Executor(object):
         from . import profiler as _profiler
         from . import telemetry as _tel
         with _profiler.Scope("executor.backward", "symbolic"), \
-                _san.hot_region("executor.backward"):
-            if not _tel._enabled:
-                return self._backward_impl(out_grads)
-            with _tel.span("executor.backward", cat="executor",
-                           mirror=False):
-                return self._backward_impl(out_grads)
+                _san.hot_region("executor.backward"), \
+                _tel.span("executor.backward", cat="executor"):
+            return self._backward_impl(out_grads)
 
     def _backward_impl(self, out_grads=None):
         gnames = self._grad_arg_names()

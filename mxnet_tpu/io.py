@@ -514,7 +514,6 @@ class PrefetchingIter(DataIter):
             # only real batches count — the end-of-epoch sentinel fetch
             # measures producer teardown, not input wait
             _tel.record_span("io.queue_wait", wall, wait, cat="io")
-            _tel.counter("io_prefetch_batches")
         for p in parts:
             if isinstance(p, self._Raised):
                 self._exhausted = True
@@ -620,14 +619,19 @@ class DevicePrefetchIter(object):
     def _producer(self):
         while True:
             try:
-                item = self._stage(next(self._source))
+                with _tel.span("input.source_next", cat="io"):
+                    item = next(self._source)
+                with _tel.span("input.stage", cat="io"):
+                    item = self._stage(item)
             except StopIteration:
                 self._queue.put(self._STOP)
                 return
             except Exception as exc:   # forward, don't vanish
                 self._queue.put(self._Raised(exc))
                 return
-            self._queue.put(item)
+            # blocked here = the consumer is the slower side (queue full)
+            with _tel.span("input.put", cat="io"):
+                self._queue.put(item)
             if not self._alive:
                 return
 
@@ -644,8 +648,6 @@ class DevicePrefetchIter(object):
         if isinstance(item, self._Raised):
             self._exhausted = True
             raise item.exc
-        if _tel._enabled:
-            _tel.counter("io_device_prefetch_batches")
         return item
 
     next = __next__

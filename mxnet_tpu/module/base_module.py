@@ -30,7 +30,7 @@ def _lr_point(module, default_step):
     are functions of and the one the scheduler's decay-boundary pins use
     (lr_scheduler._record_decay) — so a checkpoint-resumed run
     (begin_num_update > 0) keeps one consistent lr axis instead of
-    folding back to 0.  Under the fused fit path (MXNET_TELEMETRY_FUSED=1)
+    folding back to 0.  On the fused fit path
     the live counter is the TrainStep's, not the optimizer's (which only
     syncs back at epoch end) — read it from the active fused trainer.
     Schedulers are pure functions of ``num_update``, so querying here is
@@ -298,217 +298,208 @@ class BaseModule(object):
                 data_iter = fast.prefetch(data_iter)
             try:
                 while True:
-                    # zero-overhead contract: with telemetry disabled this loop
-                    # body is byte-for-byte the untimed original — no span
-                    # objects, no tag dicts, no extra clock reads
+                    # ONE loop body, recording or not: every span is the
+                    # profiler's annotation (telemetry.span; an atomic check
+                    # while nobody traces), and recording changes neither the
+                    # path nor the dispatch.  Under `telem` sit only what the
+                    # registry alone consumes: counters, scalars, the
+                    # whole-batch `step` event, the sentinel and MFU folds.
+                    # `batch` is the iteration (the parent whose self time a
+                    # trace gives); the rest, cat "step", are its phases,
+                    # which the step-anatomy tools add up against `step`.
                     telem = _tel._enabled
+                    tags = {"epoch": epoch, "nbatch": nbatch}
                     if telem:
                         # live sentinel (sentinel.py): arming it armed at
-                        # least the flight recorder, so its anatomy feed
-                        # always rides the timed path below
+                        # least the flight recorder, so `telem` is on
                         sent = _sen._on and _sen._detect
-                        # the iterator fetch is timed separately so the
-                        # breakdown distinguishes input starvation from compute
                         step_wall = time.time()
                         step_t0 = time.perf_counter()
-                        with _tel.span("data_wait", cat="step", epoch=epoch,
-                                       nbatch=nbatch) as dsp:
+                    with _tel.span("batch", cat="fit", **tags) as bsp:
+                        # the iterator fetch is timed separately so the
+                        # breakdown distinguishes input starvation from compute
+                        with _tel.span("data_wait", cat="step", **tags) as dsp:
                             try:
                                 data_batch = next(data_iter)
                             except StopIteration:
                                 dsp.cancel()
+                                bsp.cancel()
                                 break
-                        if sent:
+                        if telem and sent:
                             # the sentinel's whole added cost on the hot
                             # path: two perf_counter reads per step
                             c0 = time.perf_counter()
                             dw_s = c0 - step_t0
-                    else:
-                        try:
-                            data_batch = next(data_iter)
-                        except StopIteration:
-                            break
-                    if monitor is not None:
-                        monitor.tic()
+                        if monitor is not None:
+                            monitor.tic()
+                            if fast is not None:
+                                # bridge: an armed tic() force-samples the
+                                # step's on-device stats for this batch
+                                fast.monitor_tic(monitor)
                         if fast is not None:
-                            # bridge: an armed tic() force-samples the
-                            # step's on-device stats for this batch
-                            fast.monitor_tic(monitor)
-                    if fast is not None:
-                        if telem:
-                            with _tel.span("fused_step", cat="step", epoch=epoch,
-                                           nbatch=nbatch):
+                            with _tel.span("fused_step", cat="step", **tags):
                                 outputs, dev_labels = fast.step(data_batch)
-                            with _tel.span("metric", cat="step", epoch=epoch,
-                                           nbatch=nbatch):
-                                eval_metric.update(dev_labels or data_batch.label,
-                                                   outputs)
+                            with _tel.span("metric", cat="step", **tags):
+                                eval_metric.update(
+                                    dev_labels or data_batch.label, outputs)
                         else:
-                            outputs, dev_labels = fast.step(data_batch)
-                            eval_metric.update(dev_labels or data_batch.label,
-                                               outputs)
-                    elif telem:
-                        if type(self).forward_backward is not \
-                                BaseModule.forward_backward:
-                            # a subclass hooked the public forward_backward
-                            # extension point — keep the override on the timed
-                            # path as ONE span (it can't be split from outside)
-                            with _tel.span("forward_backward", cat="step",
-                                           epoch=epoch, nbatch=nbatch):
-                                self.forward_backward(data_batch)
-                        else:
-                            with _tel.span("forward", cat="step", epoch=epoch,
-                                           nbatch=nbatch):
-                                self.forward(data_batch, is_train=True)
-                            with _tel.span("backward", cat="step", epoch=epoch,
-                                           nbatch=nbatch):
-                                self.backward()
-                        if check_mode is not None:
-                            # non-finite sentinel BEFORE update(): `raise`
-                            # halts with the weights still clean, naming this
-                            # batch
-                            try:
-                                _diag.check_fit_step(self, epoch, nbatch,
-                                                     check_mode)
-                            except _diag.NonFiniteError:
-                                if monitor is not None:
-                                    # surface the armed batch's per-tensor
-                                    # rows (Monitor names the first bad
-                                    # tensor) before the halt discards them;
-                                    # the monitor's own raise must not
-                                    # displace the batch-context error
-                                    try:
-                                        monitor.toc_print()
-                                    except _diag.NonFiniteError:
-                                        pass
-                                raise
-                        with _tel.span("update", cat="step", epoch=epoch,
-                                       nbatch=nbatch):
-                            self.update()
-                        with _tel.span("metric", cat="step", epoch=epoch,
-                                       nbatch=nbatch):
-                            self.update_metric(eval_metric, data_batch.label)
-                    else:
-                        self.forward_backward(data_batch)
-                        if check_mode is not None:
-                            try:
-                                _diag.check_fit_step(self, epoch, nbatch,
-                                                     check_mode)
-                            except _diag.NonFiniteError:
-                                if monitor is not None:
-                                    try:
-                                        monitor.toc_print()
-                                    except _diag.NonFiniteError:
-                                        pass
-                                raise
-                        self.update()
-                        self.update_metric(eval_metric, data_batch.label)
-                    if telem and sent:
-                        # compute-exclusive phase ends here; monitor dumps,
-                        # numerics checks, heartbeats and callbacks below
-                        # fold into the sentinel's "stall" residual
-                        comp_s = time.perf_counter() - c0
-                    if monitor is not None:
-                        if fast is not None:
-                            # bridge: rows for toc() from the sampled
-                            # step's published stats (parameter RMS)
-                            fast.monitor_feed(monitor)
-                        monitor.toc_print()
-                    if fast is not None and check_mode is not None:
-                        # fused path: update is inside the donated XLA program,
-                        # so the check runs on the step's outputs afterwards
-                        _diag.check_fit_step(self, epoch, nbatch, check_mode,
-                                             outputs=outputs, check_grads=False)
-                    if _diag._armed:
-                        # step heartbeat: the watchdog counts silence from the
-                        # last completed batch
-                        _diag.heartbeat(epoch=epoch, nbatch=nbatch)
-                    if telem:
-                        # counters advance before callbacks so the Speedometer
-                        # reads a sample position that includes this batch;
-                        # padded rows of a final short batch aren't real samples
-                        bs = data_batch.data[0].shape[_batch_axis] \
-                            if data_batch.data else 0
-                        bs -= getattr(data_batch, "pad", None) or 0
-                        epoch_samples += bs
-                        _tel.counter("fit_batches")
-                        _tel.counter("fit_samples", bs)
-                        if _tel.scalar_due(gstep):
-                            # training-curve points: the metric's running
-                            # values and the current lr.  get_name_value()
-                            # reduces on device and syncs scalars — the cost
-                            # MXNET_SCALARS_EVERY exists to bound.  No epoch
-                            # tag: tags are series identity, and one curve
-                            # must not shatter into per-epoch series
-                            for mname, mval in eval_metric.get_name_value():
-                                _tel.scalar("train_%s" % mname, gstep, mval)
-                            lr, lr_step = _lr_point(self, gstep)
-                            if lr is not None:
-                                _tel.scalar("lr", lr_step, lr)
-                            amp = fast.amp_stats() if fast is not None else None
-                            if amp is not None:
-                                # a collapsing loss scale shows up as a curve
-                                # (run_compare-visible), the gauge feeds the
-                                # live endpoint, the counter names how many
-                                # updates were skipped
-                                _tel.scalar("train_loss_scale", gstep, amp[0])
-                                _tel.gauge("loss_scale", amp[0])
-                                if amp[1]:
-                                    _tel.counter("amp_overflow_steps", amp[1])
-                                    if _sen._on:
-                                        # an overflow burst legitimately
-                                        # perturbs every watched series —
-                                        # quiet window, not an anomaly
-                                        _sen.note_overflow()
-                    if batch_end_callback is not None:
-                        batch_end_params = BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                                         eval_metric=eval_metric,
-                                                         locals=locals())
-                        for callback in _as_list(batch_end_callback):
-                            callback(batch_end_params)
-                    if telem:
-                        # whole-step wall time: data_wait + compute + callbacks
-                        total_s = time.perf_counter() - step_t0
-                        _tel.record_span("step", step_wall, total_s,
-                                         cat="step", epoch=epoch, nbatch=nbatch)
-                        mfu = None
-                        if mfu_on and total_s > 0:
-                            # the MFU fold: ledger FLOPs over measured
-                            # wall time, against the resolved peak.  The
-                            # cost row appears at the step program's
-                            # first dispatch (this very loop), so the
-                            # gauges start on step 1.
-                            flops = fast.step_flops()
-                            if flops:
-                                achieved = flops / total_s
-                                mfu = achieved / peak_flops
-                                _tel.gauge("model_flops", flops)
-                                _tel.gauge("achieved_flops",
-                                           round(achieved, 3))
-                                _tel.gauge("mfu", round(mfu, 4))
-                        if sent:
-                            # fold the step into the rolling baseline and
-                            # run the anomaly check (sentinel.step_close
-                            # derives comm from the wire-ledger delta and
-                            # stall as the residual; may warn or raise a
-                            # SentinelError in :raise mode).  MFU joins
-                            # the watched series when computed above.
-                            _sen.step_close(total_s, dw_s, comp_s,
-                                            epoch=epoch, nbatch=nbatch,
-                                            mfu=mfu,
-                                            grad_norm=(fast.grad_norm()
-                                                       if fast is not None
-                                                       else None))
-                    # live-resize membership gate (parallel/resize.py,
-                    # installed by fit_elastic under the --elastic
-                    # supervisor): a step BOUNDARY is the quiesce point —
-                    # the optimizer step above fully committed, the next
-                    # one has not begun, so a world transition here
-                    # re-shards a consistent state and the loop resumes
-                    # on the same (rebuilt-in-place) fast engine
-                    rz = getattr(self, "_resize_controller", None)
-                    if rz is not None:
-                        rz.step_gate(fast, epoch=epoch, nbatch=nbatch)
+                            if type(self).forward_backward is not \
+                                    BaseModule.forward_backward:
+                                # a subclass hooked the public forward_backward
+                                # extension point — ONE span (it can't be split
+                                # from outside)
+                                with _tel.span("forward_backward", cat="step",
+                                               **tags):
+                                    self.forward_backward(data_batch)
+                            else:
+                                with _tel.span("forward", cat="step", **tags):
+                                    self.forward(data_batch, is_train=True)
+                                with _tel.span("backward", cat="step", **tags):
+                                    self.backward()
+                            if check_mode is not None:
+                                # non-finite sentinel BEFORE update(): `raise`
+                                # halts with the weights still clean, naming
+                                # this batch
+                                try:
+                                    _diag.check_fit_step(self, epoch, nbatch,
+                                                         check_mode)
+                                except _diag.NonFiniteError:
+                                    if monitor is not None:
+                                        # surface the armed batch's per-tensor
+                                        # rows (Monitor names the first bad
+                                        # tensor) before the halt discards
+                                        # them; the monitor's own raise must
+                                        # not displace the batch-context error
+                                        try:
+                                            monitor.toc_print()
+                                        except _diag.NonFiniteError:
+                                            pass
+                                    raise
+                            with _tel.span("update", cat="step", **tags):
+                                self.update()
+                            with _tel.span("metric", cat="step", **tags):
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                        if telem and sent:
+                            # compute-exclusive phase ends here; monitor dumps,
+                            # numerics checks, heartbeats and callbacks below
+                            # fold into the sentinel's "stall" residual
+                            comp_s = time.perf_counter() - c0
+                        if monitor is not None:
+                            if fast is not None:
+                                # bridge: rows for toc() from the sampled
+                                # step's published stats (parameter RMS)
+                                fast.monitor_feed(monitor)
+                            monitor.toc_print()
+                        if fast is not None and check_mode is not None:
+                            # fused path: update is inside the donated XLA
+                            # program, so the check runs on the step's outputs
+                            # afterwards
+                            _diag.check_fit_step(self, epoch, nbatch, check_mode,
+                                                 outputs=outputs,
+                                                 check_grads=False)
+                        if _diag._armed:
+                            # step heartbeat: the watchdog counts silence from
+                            # the last completed batch
+                            _diag.heartbeat(epoch=epoch, nbatch=nbatch)
+                        if telem:
+                            # counters advance before callbacks so the
+                            # Speedometer reads a sample position that includes
+                            # this batch; padded rows of a final short batch
+                            # aren't real samples
+                            bs = data_batch.data[0].shape[_batch_axis] \
+                                if data_batch.data else 0
+                            bs -= getattr(data_batch, "pad", None) or 0
+                            epoch_samples += bs
+                            _tel.counter("fit_batches")
+                            _tel.counter("fit_samples", bs)
+                            if _tel.scalar_due(gstep):
+                                # training-curve points: the metric's running
+                                # values and the current lr.  get_name_value()
+                                # reduces on device and syncs scalars — the
+                                # cost MXNET_SCALARS_EVERY exists to bound.  No
+                                # epoch tag: tags are series identity, and one
+                                # curve must not shatter into per-epoch series
+                                for mname, mval in \
+                                        eval_metric.get_name_value():
+                                    _tel.scalar("train_%s" % mname, gstep, mval)
+                                lr, lr_step = _lr_point(self, gstep)
+                                if lr is not None:
+                                    _tel.scalar("lr", lr_step, lr)
+                                amp = fast.amp_stats() if fast is not None \
+                                    else None
+                                if amp is not None:
+                                    # a collapsing loss scale shows up as a
+                                    # curve (run_compare-visible), the gauge
+                                    # feeds the live endpoint, the counter
+                                    # names how many updates were skipped
+                                    _tel.scalar("train_loss_scale", gstep,
+                                                amp[0])
+                                    _tel.gauge("loss_scale", amp[0])
+                                    if amp[1]:
+                                        _tel.counter("amp_overflow_steps",
+                                                     amp[1])
+                                        if _sen._on:
+                                            # an overflow burst legitimately
+                                            # perturbs every watched series —
+                                            # quiet window, not an anomaly
+                                            _sen.note_overflow()
+                        if batch_end_callback is not None:
+                            with _tel.span("callback", cat="step", **tags):
+                                batch_end_params = BatchEndParam(
+                                    epoch=epoch, nbatch=nbatch,
+                                    eval_metric=eval_metric, locals=locals())
+                                for callback in _as_list(batch_end_callback):
+                                    callback(batch_end_params)
+                        if telem:
+                            # whole-batch wall time: data_wait + dispatch +
+                            # callbacks.  Nothing here waits for the device, so
+                            # one iteration is the host's time until the
+                            # runtime's queue pushes back; it equals the
+                            # device's step only on average over many batches
+                            total_s = time.perf_counter() - step_t0
+                            _tel.record_span("step", step_wall, total_s,
+                                             cat="step", **tags)
+                            mfu = None
+                            if mfu_on and total_s > 0:
+                                # the MFU fold: ledger FLOPs over this
+                                # iteration's wall time (see above: a mean
+                                # over batches is the number, one batch is
+                                # not), against the resolved peak.  The cost
+                                # row appears at the step program's first
+                                # dispatch (this very loop), so the gauges
+                                # start on step 1.
+                                flops = fast.step_flops()
+                                if flops:
+                                    achieved = flops / total_s
+                                    mfu = achieved / peak_flops
+                                    _tel.gauge("model_flops", flops)
+                                    _tel.gauge("achieved_flops",
+                                               round(achieved, 3))
+                                    _tel.gauge("mfu", round(mfu, 4))
+                            if sent:
+                                # fold the step into the rolling baseline and
+                                # run the anomaly check (sentinel.step_close
+                                # derives comm from the wire-ledger delta and
+                                # stall as the residual; may warn or raise a
+                                # SentinelError in :raise mode).  MFU joins
+                                # the watched series when computed above.
+                                _sen.step_close(total_s, dw_s, comp_s,
+                                                epoch=epoch, nbatch=nbatch,
+                                                mfu=mfu,
+                                                grad_norm=(fast.grad_norm()
+                                                           if fast is not None
+                                                           else None))
+                        # live-resize membership gate (parallel/resize.py,
+                        # installed by fit_elastic under the --elastic
+                        # supervisor): a step BOUNDARY is the quiesce point —
+                        # the optimizer step above fully committed, the next
+                        # one has not begun, so a world transition here
+                        # re-shards a consistent state and the loop resumes
+                        # on the same (rebuilt-in-place) fast engine
+                        rz = getattr(self, "_resize_controller", None)
+                        if rz is not None:
+                            rz.step_gate(fast, epoch=epoch, nbatch=nbatch)
                     nbatch += 1
                     gstep += 1
 
@@ -552,27 +543,29 @@ class BaseModule(object):
                 # here with the first divergent ledger entry, before the
                 # next epoch's collectives can deadlock on the skew
                 _san.collective_sync("epoch%d" % epoch)
-            if fast is not None:
-                fast.sync_back()
-            arg_params_, aux_params_ = self.get_params()
-            self.set_params(arg_params_, aux_params_)
-            if epoch_end_callback is not None:
-                for callback in _as_list(epoch_end_callback):
-                    callback(epoch, self.symbol, arg_params_, aux_params_)
+            # parameters synced back, epoch callbacks (checkpoints), score
+            with _tel.span("epoch_end", cat="epoch", epoch=epoch):
+                if fast is not None:
+                    fast.sync_back()
+                arg_params_, aux_params_ = self.get_params()
+                self.set_params(arg_params_, aux_params_)
+                if epoch_end_callback is not None:
+                    for callback in _as_list(epoch_end_callback):
+                        callback(epoch, self.symbol, arg_params_, aux_params_)
 
-            if eval_data:
-                res = self.score(eval_data, validation_metric,
-                                 score_end_callback=eval_end_callback,
-                                 batch_end_callback=eval_batch_end_callback,
-                                 epoch=epoch)
-                for name, val in res:
-                    self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name,
-                                     val)
-                    if _tel._enabled:
-                        # per-epoch eval curve, on the same step axis as
-                        # the train_* scalars (never sampled away —
-                        # epoch-end points are rare and load-bearing)
-                        _tel.scalar("val_%s" % name, gstep, val)
+                if eval_data:
+                    res = self.score(eval_data, validation_metric,
+                                     score_end_callback=eval_end_callback,
+                                     batch_end_callback=eval_batch_end_callback,
+                                     epoch=epoch)
+                    for name, val in res:
+                        self.logger.info("Epoch[%d] Validation-%s=%f", epoch,
+                                         name, val)
+                        if _tel._enabled:
+                            # per-epoch eval curve, on the same step axis as
+                            # the train_* scalars (never sampled away —
+                            # epoch-end points are rare and load-bearing)
+                            _tel.scalar("val_%s" % name, gstep, val)
             train_data.reset()
 
     # ------------------------------------------------------------ param API

@@ -175,19 +175,13 @@ class DataParallelExecutorGroup(object):
 
     def forward(self, data_batch, is_train=None):
         """Scatter batch slices and run each device's computation (parity:
-        executor_group.forward + _load_data/_load_label).  Staging all
-        slices before dispatching keeps the host→device input copies in one
-        telemetry span ('load_data') separate from the compute dispatch."""
-        from .. import telemetry as _tel
+        executor_group.forward + _load_data/_load_label).  All slices are
+        staged before any device's computation is dispatched."""
         if is_train is None:
             is_train = self.for_training
         data = data_batch.data
         label = data_batch.label if self.label_shapes else None
-        if _tel._enabled:
-            with _tel.span("exec_group.load_data", cat="io"):
-                self._load_batch(data, label)
-        else:
-            self._load_batch(data, label)
+        self._load_batch(data, label)
         for ex in self.execs:
             ex.forward(is_train=is_train)
 

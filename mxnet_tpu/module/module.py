@@ -8,6 +8,7 @@ from ..base import MXNetError, string_types
 from ..context import Context, cpu
 from .. import ndarray as nd
 from .. import optimizer as opt
+from .. import telemetry as _tel
 from ..initializer import Uniform, InitDesc
 from ..io import DataDesc
 from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
@@ -466,19 +467,6 @@ class Module(BaseModule):
                 "on-device numerics stats (parameter rows; per-op "
                 "activation streaming needs the general path — "
                 "MXNET_FUSED_FIT=0)")
-        from .. import telemetry as _tel
-        if _tel.enabled() and get_env("MXNET_TELEMETRY_FUSED", "0") != "1" \
-                and not (pp_req and pp_req > 1) and not zero_req:
-            # the fused step is ONE XLA program — it cannot be split into
-            # forward/backward/update spans.  Telemetry implies the operator
-            # wants the step-time breakdown, so run the general path; set
-            # MXNET_TELEMETRY_FUSED=1 to keep the fused path (the breakdown
-            # then shows a single fused_step span per batch).  A requested
-            # pipeline (MXNET_PP) never downgrades here: the pipelined step
-            # emits its own per-stage breakdown (pp.stage spans), and the
-            # general path would silently change placement entirely.
-            return fallback("telemetry step breakdown active "
-                            "(MXNET_TELEMETRY_FUSED=1 keeps the fused path)")
         if len(self._context) != 1:
             return fallback("multi-context binding")
         if (self._state_names or self._fixed_param_names or
@@ -867,9 +855,9 @@ class _FusedFit(object):
 
     def prefetch(self, data_iter):
         """Wrap an epoch's batch iterator in the depth-2 device prefetcher
-        (MXNET_DEVICE_PREFETCH; the fit loop's existing ``data_wait`` span
-        times the queue fetch, so the overlap win is directly visible in
-        telemetry).  Returns ``data_iter`` unchanged when disabled or when
+        (MXNET_DEVICE_PREFETCH; the fit loop's ``data_wait`` span times the
+        queue fetch and the producer thread's ``input.*`` spans the
+        staging, so the overlap is directly visible in a trace).  Returns ``data_iter`` unchanged when disabled or when
         a sequence mesh is active (those batches need mesh placement, which
         the step's own dispatch handles)."""
         from .. import io as _io
@@ -977,8 +965,9 @@ class _FusedFit(object):
             dst = NamedSharding(self._ts.mesh, PartitionSpec("dp"))
         else:
             dst = self._dev
-        labels = [nd.NDArray(jax.device_put(batch[n], dst))
-                  for n in self._mod._label_names if n in batch]
+        with _tel.span("label_put", cat="executor"):
+            labels = [nd.NDArray(jax.device_put(batch[n], dst))
+                      for n in self._mod._label_names if n in batch]
         return [nd.NDArray(o) for o in outs], labels
 
     def sync_back(self):

@@ -134,6 +134,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mxtpu_flash_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, t, d), lse.reshape(b, h, t, 1)
 
@@ -244,6 +245,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
+        name="mxtpu_flash_dq",
     )(qf, kf, vf, gf, lsef, deltaf)
 
     dk, dv = pl.pallas_call(
@@ -267,6 +269,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
             jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
         ],
         interpret=interpret,
+        name="mxtpu_flash_dkv",
     )(qf, gf, lsef, deltaf, kf, vf)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))

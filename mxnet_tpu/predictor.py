@@ -135,10 +135,7 @@ class Predictor(object):
                              % (name, self._input_names))
         arr = self._executor.arg_dict[name]
         from . import telemetry as _tel
-        if _tel._enabled:
-            with _tel.span("predict.set_input", cat="serve", input=name):
-                arr[:] = _np.asarray(value, dtype=arr.dtype)
-        else:
+        with _tel.span("predict.set_input", cat="serve", input=name):
             arr[:] = _np.asarray(value, dtype=arr.dtype)
 
     def forward(self, **inputs):
@@ -162,11 +159,10 @@ class Predictor(object):
             staged[name] = _np.asarray(
                 value, dtype=self._executor.arg_dict[name].dtype)
         from . import telemetry as _tel
-        if not _tel._enabled:
-            self._outputs = self._executor.forward(is_train=False, **staged)
-            return
         with _tel.span("predict.forward", cat="serve"):
             self._outputs = self._executor.forward(is_train=False, **staged)
+        if not _tel._enabled:
+            return
         _tel.counter("predict_requests")
         if self._input_names:
             _tel.counter("predict_samples", int(

@@ -4,7 +4,8 @@ SURVEY.md §5.1).
 TPU-first: op-level timing comes from the JAX/XLA profiler rather than engine
 worker instrumentation.  ``dump_profile`` writes a chrome://tracing JSON like the
 reference's DumpProfile; ``set_state('run')`` also starts the JAX trace collector
-so XLA-level timelines land in ``<filename>.xplane/`` for TensorBoard.
+so XLA-level timelines land in ``<filename>.xplane/`` for TensorBoard; the
+program's own spans (``telemetry.span``) are ``mx:<name>`` host events there.
 """
 from __future__ import annotations
 
@@ -63,14 +64,14 @@ def is_running():
     return _state["running"]
 
 
-def record_event(name, start_us, dur_us, cat="operator", tid=0):
+def record_event(name, start_us, dur_us, cat="operator"):
     """Append one chrome-trace complete event (engine-level op timing)."""
     if not _state["running"]:
         return
     with _lock:
         _state["events"].append({"name": name, "cat": cat, "ph": "X",
                                  "ts": start_us, "dur": dur_us, "pid": 0,
-                                 "tid": tid})
+                                 "tid": 0})
 
 
 class Scope(object):
@@ -107,12 +108,9 @@ def dump_profile():
         # retry with a corrected filename
         events = _state["events"]
         meta = [{"name": "process_name", "ph": "M", "pid": 0,
-                 "args": {"name": "mxnet_tpu"}}]
-        for tid in sorted({e.get("tid", 0) for e in events} | {0}):
-            meta.append({"name": "thread_name", "ph": "M", "pid": 0,
-                         "tid": tid,
-                         "args": {"name": "python-main" if tid == 0
-                                  else "worker-%d" % tid}})
+                 "args": {"name": "mxnet_tpu"}},
+                {"name": "thread_name", "ph": "M", "pid": 0, "tid": 0,
+                 "args": {"name": "python-main"}}]
         trace = {"traceEvents": meta + events, "displayTimeUnit": "ms"}
         with open(_state["filename"], "w") as f:
             json.dump(trace, f)

@@ -318,7 +318,6 @@ class _CacheHandle(object):
             self._compile_s += seconds
             total = self._compile_s
         if _tel._enabled:
-            _tel.counter("compile_ms", int(seconds * 1e3), cache=self.name)
             _tel.gauge("compile_seconds", round(total, 3), cache=self.name)
 
     def snapshot(self):

@@ -47,7 +47,6 @@ ever do.
 """
 from __future__ import annotations
 
-import contextlib as _contextlib
 import json
 import math as _math
 import queue as _queue_mod
@@ -416,18 +415,12 @@ class ServedModel(object):
                     # queue wait = enqueue -> tick start; recorded from
                     # the batcher thread with the request's own timestamp
                     _tel.record_span("serve.queue_wait", r.wall, now - r.t0,
-                                     cat="serve", mirror=False,
-                                     model=self.name)
+                                     cat="serve", model=self.name)
                 _tel.gauge("serve_batch_size", n, model=self.name)
                 _tel.gauge("serve_queue_depth", self._queue.qsize(),
                            model=self.name)
-                # built under the gate (TEL001): span() no-ops when
-                # disabled, but the tag dict would still be paid per tick
-                batch_span = _tel.span("serve.batch", cat="serve",
-                                       model=self.name, bucket=bucket, n=n)
-            else:
-                batch_span = _contextlib.nullcontext()
-            with batch_span:
+            with _tel.span("serve.batch", cat="serve", model=self.name,
+                           bucket=bucket, n=n):
                 pred = self._predictor(bucket)
                 padded = {}
                 for k, shape in self._sample_shapes.items():

@@ -23,17 +23,27 @@ all of them: a process-wide, thread-safe registry of
   those values cost stay bounded; ``tools/run_compare.py`` aligns the
   recorded curves across runs,
 
-exported as JSON-lines events.  Every span is also forwarded to
-``profiler.record_event`` so the chrome-trace output and the JSON-lines
-stream describe the SAME timeline; ``tools/telemetry_report.py`` renders a
+exported as JSON-lines events; ``tools/telemetry_report.py`` renders a
 step-time breakdown table from a JSON-lines file.
 
-Zero-overhead-by-default contract: when telemetry is disabled (the normal
-state) every entry point degrades to a single module-global bool check —
-``span()`` returns a shared no-op singleton, ``counter``/``gauge`` return
-immediately, nothing imports jax, and no hot path gains a device sync.
-Call sites in hot loops additionally guard with ``if telemetry._enabled:``
-so they do not even build the kwargs dict.
+One span, two sinks: ``span(name, **tags)`` always enters a
+``jax.profiler.TraceAnnotation("mx:" + name, **tags)`` — an atomic check
+and nothing else while no profiler session is live — so the program's
+spans land on the host plane of the same xplane as the device's
+operations, on its clock, whenever anyone traces (``mx.profiler``,
+``jax.profiler.start_trace``, the benchmark's ``--trace 1``).  Only while
+the registry is enabled does it also record the JSON-lines event under
+the unprefixed name.  Recording never changes what runs: no path is
+switched and no span waits for the device, so a span around a dispatch
+is the host's launch time and the device's time is the device trace's.
+
+Off-by-default contract: when telemetry is disabled (the normal state)
+``counter``/``gauge``/``scalar`` return at a single module-global bool
+check, ``span()`` builds the annotation alone (no clock read, no event),
+importing this module does not import jax (the first span does), and no
+hot path gains a device sync.  Call sites in hot loops guard their
+counters with ``if telemetry._enabled:`` so they do not even build the
+kwargs dict.
 
 Enable programmatically with ``start(path)`` / ``stop()``, or for a whole
 process with ``MXNET_TELEMETRY=<path.jsonl>`` (autostart at import, flush
@@ -43,9 +53,8 @@ Flight recorder: ``MXNET_FLIGHT_RECORDER=N`` arms a bounded in-memory
 ring of the last N closed events (spans / counter deltas / scalars —
 shape/time metadata only) WITHOUT a file sink, threads, or device syncs.
 The hot-path call sites light up (``_enabled`` goes True) but
-``enabled()`` stays False so nothing that keys a behaviour change on
-"full telemetry" (the Module.fit fused-path downgrade, ``scalar_due``
-device syncs, file export) reacts.  The ring's only consumer is the
+``enabled()`` stays False so nothing that keys a cost on "full
+telemetry" (``scalar_due`` device syncs, file export) reacts.  The ring's only consumer is the
 diagnostics bundle: a crash, fatal signal, sanitizer ``:raise``
 violation, or watchdog stall dump carries the last ~N events of
 timeline without anyone having pre-armed full telemetry
@@ -84,12 +93,13 @@ _BUFFER_CAP = 262144  # in-memory mode: drop oldest beyond this
 _RECENT_CAP = 512     # event-stream tail kept past flushes (diagnostics)
 _recent = deque(maxlen=_RECENT_CAP)
 _dropped = 0
+_gc_open = None       # (annotation, wall, perf_counter) of the running gc pass
+_gc_done = deque()    # (wall, seconds, generation) of passes not yet emitted
 # Flight recorder (MXNET_FLIGHT_RECORDER=N): a bounded ring of the last N
 # events, fed by _emit_locked whenever armed.  In *fr-only* mode (_enabled
 # True purely because the recorder armed it) events go ONLY to the ring —
 # no buffer growth, no file sink, no _recent churn — and enabled() stays
-# False so behaviour keyed on "full telemetry" (fused-path downgrade,
-# scalar_due syncs) does not change.
+# False so costs keyed on "full telemetry" (scalar_due syncs) stay off.
 _fr_ring = None       # deque(maxlen=_fr_cap) while armed, else None
 _fr_cap = 0
 _fr_only = False
@@ -98,9 +108,9 @@ _fr_only = False
 def enabled():
     """True while the registry is recording a FULL session (``start()`` /
     ``MXNET_TELEMETRY``).  Deliberately False in flight-recorder-only mode:
-    call sites that key behaviour — not just emission — on telemetry (the
-    Module.fit fused-path downgrade, per-step device syncs) must not react
-    to a crash ring that promises zero overhead."""
+    call sites that key a cost — not just emission — on telemetry (the
+    sampled scalar syncs) must not react to a crash ring that promises
+    zero overhead."""
     return _enabled and not _fr_only
 
 
@@ -115,6 +125,7 @@ def start(path=None):
             open(path, "w").close()   # truncate: one run per file
         _buffer.clear()
         _recent.clear()
+        _gc_done.clear()
         _counters.clear()
         _gauges.clear()
         _histograms.clear()
@@ -147,6 +158,7 @@ def stop():
     with _lock:
         if not _enabled or _fr_only:
             return
+        _drain_gc_locked()
         summary = {"type": "summary", "ts": time.time() * 1e6,
                    "counters": dict(_counters), "gauges": dict(_gauges)}
         if _histograms:
@@ -175,6 +187,7 @@ def reset():
     with _lock:
         _buffer.clear()
         _recent.clear()
+        _gc_done.clear()
         _counters.clear()
         _gauges.clear()
         _histograms.clear()
@@ -195,6 +208,11 @@ def sink_path():
 
 
 def _emit_locked(ev):
+    _drain_gc_locked()
+    _append_locked(ev)
+
+
+def _append_locked(ev):
     global _dropped
     if _fr_ring is not None:
         _fr_ring.append(ev)      # bounded: deque(maxlen) evicts the oldest
@@ -558,16 +576,11 @@ def nbytes_of(arr):
 
 
 # --------------------------------------------------------------------- spans
-def record_span(name, start_wall_s, dur_s, cat="runtime", mirror=True,
-                **tags):
-    """Record one already-timed span (seconds in, microseconds stored).
-
-    This is the single sink both ``span()`` and manually-timed call sites
-    feed; it also mirrors the span into the profiler's chrome-trace stream
-    so both outputs stay consistent.  Call sites whose region is ALREADY
-    wrapped in a ``profiler.Scope`` (executor forward/backward, train_step)
-    pass ``mirror=False`` so a profiler+telemetry run doesn't record the
-    same region twice in the trace.
+def record_span(name, start_wall_s, dur_s, cat="runtime", **tags):
+    """Record one already-timed span in the registry (seconds in,
+    microseconds stored): the sink ``span()`` feeds while recording, and
+    the one for regions whose two ends are not one ``with`` block (a
+    request's queue wait, the whole-batch ``step``).
 
     Every close also feeds the latency histogram of the same name (µs), so
     spans get p50/p90/p99 visibility for free — ``quantile("step", 0.99)``,
@@ -575,78 +588,118 @@ def record_span(name, start_wall_s, dur_s, cat="runtime", mirror=True,
     """
     if not _enabled:
         return
+    with _lock:
+        if _enabled:
+            _drain_gc_locked()
+            _span_locked(name, start_wall_s, dur_s, cat, tags)
+
+
+def _span_locked(name, start_wall_s, dur_s, cat, tags):
     ev = {"type": "span", "name": name, "cat": cat,
           "ts": start_wall_s * 1e6, "dur": dur_s * 1e6}
     if tags:
         ev["tags"] = tags
-    with _lock:
-        if not _enabled:
-            return
-        _hist_update_locked(name, ev["dur"])
-        _emit_locked(ev)
-    if not mirror:
-        return
-    from . import profiler as _profiler
-    cur = threading.current_thread()
-    _profiler.record_event(name, start_wall_s * 1e6, dur_s * 1e6, cat,
-                           tid=0 if cur is threading.main_thread()
-                           else threading.get_ident())
+    _hist_update_locked(name, ev["dur"])
+    _append_locked(ev)
 
 
 class _Span(object):
-    """Context manager timing a region into the telemetry stream.  Extra
-    tags may be attached mid-flight via ``self.tags[...] = ...`` (they are
-    read at ``__exit__``); ``cancel()`` suppresses emission."""
+    """A span while the registry records: the profiler's annotation plus
+    the timed JSON-lines event.  Extra tags may be attached mid-flight via
+    ``self.tags[...] = ...`` (read at ``__exit__``, for the registry's
+    event alone); ``cancel()`` suppresses the registry's event."""
 
-    __slots__ = ("name", "cat", "tags", "mirror", "_t0", "_wall",
+    __slots__ = ("name", "cat", "tags", "_ann", "_t0", "_wall",
                  "_cancelled")
 
-    def __init__(self, name, cat, tags, mirror=True):
+    def __init__(self, name, cat, tags, ann):
         self.name = name
         self.cat = cat
         self.tags = tags
-        self.mirror = mirror
+        self._ann = ann
         self._cancelled = False
 
     def __enter__(self):
+        self._ann.__enter__()
         self._wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._cancelled:
-            return
-        record_span(self.name, self._wall, time.perf_counter() - self._t0,
-                    self.cat, mirror=self.mirror, **self.tags)
+        dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if not self._cancelled:
+            record_span(self.name, self._wall, dur, self.cat, **self.tags)
 
     def cancel(self):
         self._cancelled = True
 
 
-class _NullSpan(object):
-    """Shared no-op span handed out while telemetry is disabled."""
-
-    __slots__ = ()
-    tags = {}   # class-level scratch dict: writes are cheap and ignored
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
-
-    def cancel(self):
-        pass
+_Annotation = None    # made at the first span: jax is not imported before
 
 
-_NULL_SPAN = _NullSpan()
+def _first_span():
+    """Import jax, make the annotation class that ``span()`` hands out
+    while the registry is off, and hook the collector."""
+    global _Annotation
+    import gc
+    from jax.profiler import TraceAnnotation
+
+    class Annotation(TraceAnnotation):
+        """The profiler's annotation with ``_Span``'s surface, so a call
+        site reads the same whether or not the registry records."""
+        __slots__ = ()
+        tags = {}   # class-level scratch dict: writes are cheap and ignored
+
+        def cancel(self):
+            pass
+
+    with _lock:
+        if _Annotation is None:
+            _Annotation = Annotation
+            gc.callbacks.append(_gc_hook)
+    return _Annotation
 
 
-def span(name, cat="runtime", mirror=True, **tags):
-    """Timed-region context manager; a shared no-op while disabled."""
+def span(name, cat="runtime", **tags):
+    """Timed-region context manager with two sinks: always a
+    ``jax.profiler.TraceAnnotation`` named ``"mx:" + name`` carrying
+    ``tags`` (an atomic check while no profiler session is live), and,
+    only while the registry is enabled, the JSON-lines / ring event under
+    the unprefixed name.  With the registry off no clock is read and no
+    event is built."""
+    ann = (_Annotation or _first_span())("mx:" + name, **tags)
     if not _enabled:
-        return _NULL_SPAN
-    return _Span(name, cat, tags, mirror)
+        return ann
+    return _Span(name, cat, tags, ann)
+
+
+# A collector pause is host work under no layer's boundary.  The hook
+# writes it into the profiler's trace as ``mx:host.gc`` like any span;
+# for the registry it only queues (when, seconds, generation) — the
+# collector can run inside any allocation, the registry's locked sections
+# included — and the next emission drains the queue.
+def _gc_hook(phase, info):
+    global _gc_open
+    if phase == "start":
+        ann = _Annotation("mx:host.gc", generation=info["generation"])
+        ann.__enter__()
+        _gc_open = (ann, time.time(), time.perf_counter()) if _enabled \
+            else (ann, 0.0, 0.0)
+    elif _gc_open is not None:
+        ann, wall, t0 = _gc_open
+        _gc_open = None
+        ann.__exit__(None, None, None)
+        if wall and _enabled:
+            _gc_done.append((wall, time.perf_counter() - t0,
+                             info["generation"]))
+
+
+def _drain_gc_locked():
+    while _gc_done:
+        wall, dur, generation = _gc_done.popleft()
+        _span_locked("host.gc", wall, dur, "runtime",
+                     {"generation": generation})
 
 
 # ------------------------------------------------------- flight recorder

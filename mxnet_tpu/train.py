@@ -30,6 +30,7 @@ from .base import MXNetError, trace_env_key
 from . import ndarray as nd
 from . import random as _random
 from . import sanitize as _san
+from . import telemetry as _tel
 from .parallel.placement import PlacementPlan, normalize_zero
 from .parallel import placement as _placement
 
@@ -512,15 +513,20 @@ class TrainStep(object):
 
             def f(p):
                 return fwd(p, aux, batch, rng)
-            outs, vjp_fn, aux_upd = jax.vjp(f, fullp, has_aux=True)
+            # named scopes: metadata only, so that each device operation's
+            # name in a trace says which part of the step it belongs to
+            with jax.named_scope("forward"):
+                outs, vjp_fn, aux_upd = jax.vjp(f, fullp, has_aux=True)
             ones = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-            grads = fold_grads(params, vjp_fn(ones)[0])
+            with jax.named_scope("backward"):
+                grads = fold_grads(params, vjp_fn(ones)[0])
             if plan.bucket_grads:
                 upd = bucket_update
             else:
                 upd = update_zero if self.zero else update_all
-            new_params, new_state = upd(params, grads, opt_state, hyper, t,
-                                        rng)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_state = upd(params, grads, opt_state, hyper,
+                                            t, rng)
             new_aux = dict(aux)
             new_aux.update({k: v.astype(aux[k].dtype)
                             for k, v in aux_upd.items() if k in aux})
@@ -554,38 +560,44 @@ class TrainStep(object):
                 # scale-backward identity): the heads ignore incoming
                 # cotangents, so seeding would not reach the chain
                 return fwd(p, aux, batch, rng, scale)
-            outs, vjp_fn, aux_upd = jax.vjp(f, fullp, has_aux=True)
+            with jax.named_scope("forward"):
+                outs, vjp_fn, aux_upd = jax.vjp(f, fullp, has_aux=True)
             ones = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-            gtree = vjp_fn(ones)[0]
-            grads = fold_grads(params, gtree)
+            with jax.named_scope("backward"):
+                gtree = vjp_fn(ones)[0]
+                grads = fold_grads(params, gtree)
+            with jax.named_scope("overflow_check"):
+                if plan.bucket_grads:
+                    # overflow detection on the bucket — the only gradient
+                    # residency (an inf/nan survives the reduce-scatter sum)
+                    _layout, bucket = grads
+                    finite = jnp.isfinite(bucket).all() \
+                        if bucket is not None else jnp.bool_(True)
+                else:
+                    # overflow detection on the SCALED f32 grads, on device
+                    finite = jnp.stack(
+                        [jnp.isfinite(g).all()
+                         for g in jax.tree_util.tree_leaves(gtree)]).all()
             if plan.bucket_grads:
-                # overflow detection on the bucket — the only gradient
-                # residency (an inf/nan survives the reduce-scatter sum)
-                _layout, bucket = grads
-                finite = jnp.isfinite(bucket).all() \
-                    if bucket is not None else jnp.bool_(True)
                 upd = bucket_update
             else:
-                # overflow detection on the SCALED f32 grads, on device
-                finite = jnp.stack(
-                    [jnp.isfinite(g).all()
-                     for g in jax.tree_util.tree_leaves(gtree)]).all()
                 upd = update_zero if self.zero else update_all
             inv = jnp.float32(1.0) / scale
 
             def do_update(_):
                 # unscale by 1/S exactly once; the optimizer's own
                 # rescale_grad (1/batch) applies inside the rule as always
-                if plan.bucket_grads:
-                    layout, bucket = grads
-                    grads_u = (layout,
-                               bucket * inv.astype(bucket.dtype)
-                               if bucket is not None else None)
-                else:
-                    grads_u = {n: g * inv.astype(g.dtype)
-                               for n, g in grads.items()}
-                new_params, new_state = upd(params, grads_u, opt_state,
-                                            hyper, t, rng)
+                with jax.named_scope("optimizer_update"):
+                    if plan.bucket_grads:
+                        layout, bucket = grads
+                        grads_u = (layout,
+                                   bucket * inv.astype(bucket.dtype)
+                                   if bucket is not None else None)
+                    else:
+                        grads_u = {n: g * inv.astype(g.dtype)
+                                   for n, g in grads.items()}
+                    new_params, new_state = upd(params, grads_u, opt_state,
+                                                hyper, t, rng)
                 new_aux = dict(aux)
                 new_aux.update({k: v.astype(aux[k].dtype)
                                 for k, v in aux_upd.items() if k in aux})
@@ -732,7 +744,6 @@ class TrainStep(object):
         if self.zero < 3:
             return params
         import jax
-        from . import telemetry as _tel
         if self._gather_fn is None:
             plan, mesh = self.plan, self.mesh
             from jax.sharding import NamedSharding
@@ -1155,7 +1166,9 @@ class TrainStep(object):
             args = args + (self._scale_state_dev(),)
         if _san._donate_on:
             _san.check_donated("run_steps", self._donate_pairs(args))
-        with _san.hot_region("run_steps"):
+        with _san.hot_region("run_steps"), \
+                _tel.span("train_chunk", cat="executor",
+                          num_update=self.num_update, num_steps=num_steps):
             res = fn(*(args + (batch, rng, hyper, _np.int32(t0))))
         if _san._donate_on:
             _san.note_donated("run_steps", self._donate_pairs(args),
@@ -1316,7 +1329,6 @@ class TrainStep(object):
     def __call__(self, params, opt_state, aux, batch, rng=None):
         """One fused step.  Returns (params, opt_state, aux, outputs)."""
         from . import profiler as _profiler
-        from . import telemetry as _tel
         from . import diagnostics as _diag
         from . import numerics as _num
         if rng is None:
@@ -1364,24 +1376,15 @@ class TrainStep(object):
             # a buffer donated by an earlier step re-entering here is the
             # delete-on-donate bug — name it before XLA crashes cryptically
             _san.check_donated("train_step", self._donate_pairs(args))
+        # the span is the host's launch of the step program: nothing here
+        # waits for the device, whose time is the device trace's to give
         with _profiler.Scope("train_step[%d]" % self.num_update,
                              "symbolic"), \
-                _san.hot_region("train_step"):
-            if _tel._enabled:
-                with _tel.span("train_step", cat="executor", mirror=False,
-                               num_update=self.num_update):
-                    res = step_prog(*args, batch, rng, hyper,
-                                    _np.int32(self.num_update))
-                    import jax
-                    with _san.allow_sync("telemetry span device time"):
-                        jax.block_until_ready(res[-1])
-            else:
-                res = step_prog(*args, batch, rng, hyper,
-                                _np.int32(self.num_update))
-                if _profiler.is_running():
-                    import jax
-                    with _san.allow_sync("profiler device time"):
-                        jax.block_until_ready(res[-1])
+                _san.hot_region("train_step"), \
+                _tel.span("train_step", cat="executor",
+                          num_update=self.num_update):
+            res = step_prog(*args, batch, rng, hyper,
+                            _np.int32(self.num_update))
         if _san._donate_on:
             _san.note_donated("train_step", self._donate_pairs(args),
                               step=self.num_update)
@@ -2544,7 +2547,6 @@ class PipelineTrainStep(object):
         import time as _time
         from jax.sharding import NamedSharding
         from . import profiler as _profiler
-        from . import telemetry as _tel
         from . import diagnostics as _diag
         if self._stages is None:
             raise MXNetError(

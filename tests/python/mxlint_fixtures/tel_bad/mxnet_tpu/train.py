@@ -8,7 +8,7 @@ class TrainStep(object):
         loss, grads = self._step(params, batch)
         _tel.counter("train_steps")                     # ungated: finding
         _tel.gauge("loss_scale", self.scale)            # ungated: finding
-        with _tel.span("train_step", cat="executor"):   # ungated: finding
+        with _tel.span("train_step", cat="executor"):   # ungated by design
             res = self._finish(loss, grads)
         return res
 

@@ -987,9 +987,6 @@ def test_sigkill_respawn_resume_e2e(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     env["MXNET_CKPT_EVERY_N_STEPS"] = "3"
     env["MXNET_TELEMETRY"] = tfile
-    # keep the fused fast path under telemetry: the live fused pytrees
-    # are what the step-interval sharded checkpoints snapshot
-    env["MXNET_TELEMETRY_FUSED"] = "1"
     env["MXNET_WATCHDOG_SEC"] = "300"
     env["MXNET_DIAG_DIR"] = str(tmp_path)
     proc = subprocess.run(

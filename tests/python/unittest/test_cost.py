@@ -272,7 +272,6 @@ def test_fused_fit_mfu_gauges_and_cost_section(monkeypatch):
     """With peaks configured, an armed fused fit captures the step's
     cost, emits model_flops/mfu gauges, and the diagnostics bundle grows
     a `cost` section with the resolved peaks."""
-    monkeypatch.setenv("MXNET_TELEMETRY_FUSED", "1")
     # peaks scaled to the toy model so its MFU lands in (0, 1) — a 1T
     # peak would round the gauge's 4 decimals to 0.0
     monkeypatch.setenv("MXNET_PEAK_FLOPS", "100M")
@@ -309,7 +308,6 @@ def test_fused_fit_without_peaks_stays_dark(monkeypatch):
     strict no-op contract holds even for an armed sentinel fit."""
     monkeypatch.delenv("MXNET_PEAK_FLOPS", raising=False)
     monkeypatch.delenv("MXNET_PEAK_BW", raising=False)
-    monkeypatch.setenv("MXNET_TELEMETRY_FUSED", "1")
     cost.resolve_peaks(refresh=True)
     assert sen.arm("step:3sigma") is True
     x = np.random.RandomState(0).rand(16, 6).astype(np.float32)

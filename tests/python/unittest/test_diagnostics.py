@@ -185,6 +185,7 @@ def test_watchdog_env_autoarm(monkeypatch, tmp_path):
 def test_sentinel_raise_names_offending_batch(tmp_path, monkeypatch):
     """MXNET_CHECK_NUMERICS=raise halts on the NaN batch with the batch
     index in the message, counters recorded, and a crash bundle behind."""
+    monkeypatch.setenv("MXNET_FUSED_FIT", "0")   # the general loop's checks
     monkeypatch.setenv("MXNET_CHECK_NUMERICS", "raise")
     monkeypatch.setenv("MXNET_DIAG_DIR", str(tmp_path))
     it = _data(nan_at=25)   # batch 2 of 4 (batch_size 10)
@@ -210,7 +211,7 @@ def test_sentinel_raise_names_offending_batch(tmp_path, monkeypatch):
 
 
 def test_sentinel_raise_fused_path_names_batch(tmp_path, monkeypatch):
-    """Without telemetry, fit rides the fused TrainStep — the sentinel
+    """By default fit rides the fused TrainStep — the sentinel
     must still halt with the BATCH index (the step-level check defers to
     the fit loop's epoch/nbatch context)."""
     monkeypatch.setenv("MXNET_DIAG_DIR", str(tmp_path))
@@ -224,6 +225,7 @@ def test_sentinel_raise_fused_path_names_batch(tmp_path, monkeypatch):
 def test_sentinel_warn_counts_and_continues(monkeypatch):
     """warn mode finishes the epoch, warning per hit and counting both the
     loss and the grad-global-norm non-finites."""
+    monkeypatch.setenv("MXNET_FUSED_FIT", "0")   # the general loop's checks
     monkeypatch.setenv("MXNET_CHECK_NUMERICS", "warn")
     it = _data(nan_at=25)
     mod = _module()
@@ -240,6 +242,7 @@ def test_sentinel_warn_counts_and_continues(monkeypatch):
 def test_sentinel_healthy_fit_records_grad_norm(monkeypatch):
     """On a healthy run the sentinel is silent and leaves the
     grad_global_norm gauge as a free blow-up trend line."""
+    monkeypatch.setenv("MXNET_FUSED_FIT", "0")   # the general loop's checks
     monkeypatch.setenv("MXNET_CHECK_NUMERICS", "raise")
     mod = _module()
     tel.start()

@@ -106,7 +106,7 @@ def test_span_close_feeds_histogram():
     tel.start()
     with tel.span("region", cat="unit"):
         pass
-    tel.record_span("region", time.time(), 0.002, mirror=False)
+    tel.record_span("region", time.time(), 0.002)
     h = tel.histograms()["region"]
     assert h["count"] == 2
     assert h["max"] == pytest.approx(2000.0, rel=0.01)   # µs
@@ -175,9 +175,9 @@ def _write_rank_files(base, rank_step_ms, nsteps=40):
         t = time.time()
         for i in range(nsteps):
             tel.record_span("step", t, step_ms / 1e3, cat="step",
-                            epoch=0, nbatch=i, mirror=False)
+                            epoch=0, nbatch=i)
             tel.record_span("dist.allreduce", t, step_ms / 4e3, cat="comm",
-                            rank=rank, mirror=False)
+                            rank=rank)
         tel.counter("fit_samples", nsteps * 10)
         tel.gauge("epoch_time", step_ms * nsteps / 1e3)
         tel.stop()
@@ -250,11 +250,11 @@ def test_agg_live_file_without_summary(tmp_path):
     base = str(tmp_path / "t.jsonl")
     # rank 0: completed run (summary present)
     tel.start(base + ".rank0")
-    tel.record_span("step", time.time(), 0.01, cat="step", mirror=False)
+    tel.record_span("step", time.time(), 0.01, cat="step")
     tel.stop()
     # rank 1: killed mid-run — no summary event
     tel.start(base + ".rank1")
-    tel.record_span("step", time.time(), 0.03, cat="step", mirror=False)
+    tel.record_span("step", time.time(), 0.03, cat="step")
     tel.histogram("queue_depth", 5.0)
     tel.counter("fit_samples", 10)
     tel.flush()   # file on disk, but no summary event written
@@ -336,7 +336,7 @@ def test_endpoint_serves_prometheus_and_json():
     # (dist_allreduce vs dist.allreduce) must not emit two conflicting
     # # TYPE lines — Prometheus drops the whole scrape on that
     tel.counter("dist_allreduce")
-    tel.record_span("dist.allreduce", time.time(), 0.001, mirror=False)
+    tel.record_span("dist.allreduce", time.time(), 0.001)
     text = _http_get(port, "/metrics")
     families = [line.split()[2] for line in text.splitlines()
                 if line.startswith("# TYPE")]
@@ -492,10 +492,8 @@ def test_bench_telemetry_summary():
     tel.start()
     t = time.time()
     for i, ms_ in enumerate((10.0, 11.0, 12.0, 13.0)):
-        tel.record_span("step", t, ms_ / 1e3, cat="step", nbatch=i,
-                        mirror=False)
-        tel.record_span("data_wait", t, ms_ / 1e4, cat="step", nbatch=i,
-                        mirror=False)
+        tel.record_span("step", t, ms_ / 1e3, cat="step", nbatch=i)
+        tel.record_span("data_wait", t, ms_ / 1e4, cat="step", nbatch=i)
     tel.histogram("bench.step", 5000.0)
     s = bench.telemetry_summary()
     assert s["step"]["count"] == 4
@@ -712,15 +710,13 @@ def test_step_anatomy_names_rank_and_phase(tmp_path, capsys):
         t = time.time()
         for i in range(30):
             tel.record_span("step", t, step_ms / 1e3, cat="step",
-                            epoch=0, nbatch=i, mirror=False)
-            tel.record_span("data_wait", t, 1.0 / 1e3, cat="step",
-                            mirror=False)
+                            epoch=0, nbatch=i)
+            tel.record_span("data_wait", t, 1.0 / 1e3, cat="step")
             # comm nests INSIDE the fused compute span (the kvstore
             # allreduce runs inside update)
             tel.record_span("fused_step", t, (step_ms - 1.0) / 1e3,
-                            cat="step", mirror=False)
-            tel.record_span("dist.allreduce", t, comm_ms / 1e3, cat="comm",
-                            mirror=False)
+                            cat="step")
+            tel.record_span("dist.allreduce", t, comm_ms / 1e3, cat="comm")
         tel.stop()
     merged = agg.aggregate(agg.rank_files(base))
     an = merged["anatomy"]
@@ -997,10 +993,10 @@ def test_agg_since_window_drops_old_steps(tmp_path, capsys):
         tel.start("%s.rank%d" % (base, rank))
         for i in range(20):          # old regime: 50 ms steps, pre-cut
             tel.record_span("step", cut_s - 100.0 + i, 0.050, cat="step",
-                            epoch=0, nbatch=i, mirror=False)
+                            epoch=0, nbatch=i)
         for i in range(20):          # new regime: 10 ms steps, post-cut
             tel.record_span("step", cut_s + i, 0.010, cat="step",
-                            epoch=1, nbatch=i, mirror=False)
+                            epoch=1, nbatch=i)
         tel.counter("fit_samples", 400)
         tel.stop()
     files = agg.rank_files(base)
@@ -1042,12 +1038,11 @@ def test_agg_last_n_steps_window_and_anatomy(tmp_path, capsys):
             slow = rank == 1 and i < 15
             step_s = 0.030 if slow else 0.010
             tel.record_span("step", t0 + i, step_s, cat="step",
-                            epoch=0, nbatch=i, mirror=False)
+                            epoch=0, nbatch=i)
             tel.record_span("data_wait", t0 + i,
                             0.021 if slow else 0.001,
-                            cat="step", mirror=False)
-            tel.record_span("fused_step", t0 + i, 0.009, cat="step",
-                            mirror=False)
+                            cat="step")
+            tel.record_span("fused_step", t0 + i, 0.009, cat="step")
         tel.stop()
     files = agg.rank_files(base)
     whole = agg.aggregate(files)

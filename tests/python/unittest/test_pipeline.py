@@ -494,7 +494,6 @@ def test_pp_fit_with_telemetry_keeps_pipeline(monkeypatch, tmp_path):
     # requested pipeline to the single-program general path — the
     # pipelined step provides its own per-stage breakdown
     monkeypatch.setenv("MXNET_PP", "2")
-    monkeypatch.delenv("MXNET_TELEMETRY_FUSED", raising=False)
     tel.start(str(tmp_path / "t.jsonl"))
     try:
         data = _fit_data()

@@ -664,7 +664,6 @@ def test_live_resize_preemption_drill_e2e(tmp_path):
     env["JAX_PLATFORMS"] = "cpu"
     env["MXNET_SAN"] = "all:raise"
     env["MXNET_RESIZE_GATE_SEC"] = "5"
-    env["MXNET_TELEMETRY_FUSED"] = "1"
 
     # fixed-world reference: the same training, one uncoupled process
     ref_tel = str(tmp_path / "ref.jsonl")

@@ -334,10 +334,9 @@ def test_opt_stats_update_still_correct(monkeypatch):
 
 
 def test_fused_fit_lr_reads_live_counter(monkeypatch, tmp_path):
-    """Under MXNET_TELEMETRY_FUSED=1 the optimizer's num_update only
+    """On the fused path the optimizer's num_update only
     syncs back at epoch end — the fit loop's `lr` points must read the
     TrainStep's live counter, so a schedule visibly decays MID-epoch."""
-    monkeypatch.setenv("MXNET_TELEMETRY_FUSED", "1")
     fname = str(tmp_path / "fused.jsonl")
     x = RS(0).rand(64, 6).astype(np.float32)
     y = RS(1).randint(0, 4, 64).astype(np.float32)

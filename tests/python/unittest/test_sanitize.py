@@ -364,10 +364,6 @@ def test_run_steps_trace_env_keying(monkeypatch):
 
 # ------------------------------------------------------ gauge + telemetry
 def test_jit_cache_size_gauge_sourced_from_registry(monkeypatch):
-    # keep the fused path under telemetry (the general path would be a
-    # legitimate fallback, but this test pins the fused-fit cache's
-    # visibility in the gauge)
-    monkeypatch.setenv("MXNET_TELEMETRY_FUSED", "1")
     telemetry.start()
     try:
         mod = _fit_once()                # fused fit registers its caches

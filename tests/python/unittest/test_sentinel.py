@@ -375,7 +375,6 @@ def test_hbm_report_agrees_with_ledger(tmp_path, capsys):
 def test_fused_fit_populates_ledger_and_diag_section(monkeypatch):
     """An armed fused fit leaves per-program rows in the ledger, and the
     diagnostics bundle grows matching sentinel/hbm sections."""
-    monkeypatch.setenv("MXNET_TELEMETRY_FUSED", "1")
     assert sen.arm("step:3sigma") is True
     x = np.random.RandomState(0).rand(32, 6).astype(np.float32)
     y = np.random.RandomState(1).randint(0, 4, 32).astype(np.float32)

@@ -48,8 +48,7 @@ def _free_port():
 # verbatim: the per-rank offset (rank N serves on port+N) is applied by
 # mxnet_tpu.metrics_server itself from MXTPU_PROCESS_ID, so the offset
 # logic lives in exactly one place.
-OBSERVABILITY_ENV = ("MXNET_TELEMETRY", "MXNET_TELEMETRY_FUSED",
-                     "MXNET_METRICS_PORT", "MXNET_DIAG_DIR",
+OBSERVABILITY_ENV = ("MXNET_TELEMETRY", "MXNET_METRICS_PORT", "MXNET_DIAG_DIR",
                      "MXNET_WATCHDOG_SEC", "MXNET_CHECK_NUMERICS",
                      # elastic-v2 checkpoint cadence: every worker must
                      # agree on the interval or resume points desync

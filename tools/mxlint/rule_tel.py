@@ -11,7 +11,7 @@ enforced idiom is an explicit gate around every emission:
 Inside the configured hot-path functions this rule flags telemetry /
 wire-bytes emission calls —
 
-    telemetry.counter/gauge/scalar/hist/span/record_span
+    telemetry.counter/gauge/scalar/hist/record_span
     sanitize.record_wire_bytes
 
 — that do not sit under such a gate (an ``if`` consulting the
@@ -20,6 +20,11 @@ environment, ``_tel._enabled`` / a ``telem`` snapshot of it,
 functions DO no-op internally when disabled, but reaching that early
 return still pays argument evaluation (tag dicts, ``nbytes_of`` sums)
 on every step — exactly the cost the contract forbids.
+
+``telemetry.span`` is not policed: it is the one primitive that is on by
+design (it enters the profiler's ``TraceAnnotation`` whether or not the
+registry records, about a microsecond), so a hot path opens it ungated
+and has one body, recording or not.
 """
 from __future__ import annotations
 
@@ -52,7 +57,7 @@ HOT_PATHS = {
 
 # telemetry-module emission entry points (resolved through the import
 # table: ``from . import telemetry as _tel`` -> 'telemetry.counter')
-_EMITS = ("counter", "gauge", "scalar", "hist", "span", "record_span")
+_EMITS = ("counter", "gauge", "scalar", "hist", "record_span")
 
 # identifiers that mark an opt-in telemetry/ledger branch; ``telem`` is
 # the fit loop's local snapshot of ``_tel._enabled``

@@ -70,13 +70,20 @@ STRAGGLER_RATIO = 1.25
 # comm and stall spans nest INSIDE the compute spans (the kvstore
 # allreduce runs inside ``update``, the pipeline bubble inside
 # ``fused_step``), so compute is reported exclusive of them.
+# ``callback`` is the batch-end callbacks' time on the loop's thread;
+# ``input.stage`` is the device prefetcher's staging on its producer
+# thread, beside the step and not part of it (OFF_THREAD: a column of
+# its own, left out of the residual ``other``).
 ANATOMY_PHASES = (
     ("data_wait", ("data_wait",)),
     ("compute", ("fused_step", "forward_backward", "forward", "backward",
                  "update", "metric")),
     ("comm", ("dist.allreduce", "zero.gather")),
     ("stall", ("pp.bubble",)),
+    ("callback", ("callback",)),
+    ("input.stage", ("input.stage",)),
 )
+OFF_THREAD = ("input.stage",)
 
 # span-fed histograms and span durations are microseconds (telemetry.py)
 _US_PER_MS = 1e3
@@ -459,8 +466,9 @@ def step_anatomy(per_rank, ratio=STRAGGLER_RATIO):
             0.0, totals["compute"] - totals["comm"] - totals["stall"])
         for phase in totals:
             row[phase + "_ms"] = totals[phase] / n / _US_PER_MS
+        on_thread = sum(v for k, v in totals.items() if k not in OFF_THREAD)
         row["other_ms"] = max(
-            0.0, row["step_ms"] - sum(totals.values()) / n / _US_PER_MS)
+            0.0, row["step_ms"] - on_thread / n / _US_PER_MS)
         # the rank's last MFU gauge (fit loop, MXNET_PEAK_FLOPS): the
         # efficiency column next to the time decomposition — absent
         # when peaks were unset during the run

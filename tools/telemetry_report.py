@@ -8,7 +8,9 @@ The fit loop (mxnet_tpu.module.base_module.fit) emits, per batch, one
 ``step`` span (whole-batch wall time) plus component spans tagged with the
 same (epoch, nbatch): ``data_wait``, then either ``forward``/``backward``/
 ``update``/``metric`` (general path) or ``fused_step``/``metric`` (fused
-path).  This tool groups those spans per step and prints:
+path), then ``callback`` (the batch-end callbacks).  The device
+prefetcher's ``input.stage`` runs on its producer thread, beside the step.
+This tool groups those spans per step and prints:
 
 * a per-component summary (total / mean / share of step wall time),
 * coverage — how much of the measured step wall time the components
@@ -54,7 +56,10 @@ from collections import defaultdict
 # component display order; anything else observed lands after these
 # (forward_backward appears when a module subclass overrides that hook)
 _KNOWN = ["data_wait", "forward", "backward", "forward_backward", "update",
-          "fused_step", "metric"]
+          "fused_step", "metric", "callback"]
+# the device prefetcher's staging runs on its producer thread, beside the
+# step: shown as a row of its own below the breakdown, outside coverage
+_BESIDE = "input.stage"
 
 
 def load_events(path):
@@ -95,6 +100,15 @@ def collect_steps(events, epoch=None):
     return dict(steps)
 
 
+def beside_step(events):
+    """(total us, count) of the ``input.stage`` spans: the prefetcher's
+    staging, on its own thread, so a row beside the breakdown and no part
+    of its coverage."""
+    durs = [ev["dur"] for ev in events
+            if ev.get("type") == "span" and ev.get("name") == _BESIDE]
+    return sum(durs), len(durs)
+
+
 def summary_state(events):
     """(counters, gauges, has_summary) from the run's summary event, or
     folded from the raw stream when the run never wrote one (still alive,
@@ -119,7 +133,7 @@ def component_order(steps):
         sorted(c for c in seen if c not in _KNOWN)
 
 
-def render(steps, counters, per_step=False, out=sys.stdout):
+def render(steps, counters, per_step=False, out=sys.stdout, beside=(0, 0)):
     if not steps:
         out.write("no step spans found (was the fit loop run with "
                   "MXNET_TELEMETRY set?)\n")
@@ -176,6 +190,10 @@ def render(steps, counters, per_step=False, out=sys.stdout):
         out.write("%-12s %12.2f %10s %7.1f%%  (span sum vs step wall)\n"
                   % ("coverage", comp_sum / 1e3, "",
                      100.0 * comp_sum / total_step))
+    if beside[1]:
+        out.write("%-12s %12.2f %10.3f %8s  (producer thread, beside the "
+                  "step)\n" % (_BESIDE, beside[0] / 1e3,
+                               beside[0] / beside[1] / 1e3, ""))
     render_counters(counters, out)
 
 
@@ -187,7 +205,7 @@ def render_counters(counters, out):
         out.write("  %-24s %s\n" % (name, counters[name]))
 
 
-def breakdown_json(steps, counters, gauges, has_summary):
+def breakdown_json(steps, counters, gauges, has_summary, beside=(0, 0)):
     """The --json view: the step-time breakdown as one document with the
     SAME fields the rendered table shows (totals/means/shares in ms,
     coverage, counter and gauge totals) — for dashboards and CI scripts
@@ -214,6 +232,8 @@ def breakdown_json(steps, counters, gauges, has_summary):
         "mean_step_ms": total_step / nsteps / 1e3 if nsteps else 0.0,
         "components": components,
         "coverage": comp_sum / total_step if total_step else 0.0,
+        "beside": {_BESIDE: {"total_ms": beside[0] / 1e3,
+                             "count": beside[1]}} if beside[1] else {},
         "counters": counters,
         "gauges": gauges,
         "has_summary": has_summary,
@@ -414,7 +434,8 @@ def main(argv=None):
     counters, gauges, has_summary = summary_state(events)
     if args.json:
         doc = breakdown_json(collect_steps(events, epoch=args.epoch),
-                             counters, gauges, has_summary)
+                             counters, gauges, has_summary,
+                             beside=beside_step(events))
         json.dump(doc, sys.stdout, indent=1, default=str)
         sys.stdout.write("\n")
         return 0
@@ -423,7 +444,7 @@ def main(argv=None):
                          "before telemetry.stop(); totals folded from the "
                          "raw stream\n")
     render(collect_steps(events, epoch=args.epoch), counters,
-           per_step=args.steps)
+           per_step=args.steps, beside=beside_step(events))
     if args.health:
         render_health(counters, gauges, collect_compile_spans(events),
                       sys.stdout)
