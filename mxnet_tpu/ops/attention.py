@@ -37,8 +37,9 @@ def _dot_product_attention(query, key, value, causal=False, scale=None,
     Lowering ladder (impl='auto'):
     1. sequence mesh active -> ring attention (multi-chip, ppermute ring);
     2. TPU + flash-friendly shapes + T >= 512 -> Pallas flash kernel
-       (blocked online-softmax, no (T, T) score matrix; ~2x XLA attention
-       at long T on v5e);
+       (blocked online-softmax, no (T, T) score matrix, so its memory is
+       O(T) where XLA's backward keeps the scores; kernel times on the
+       v5e: PERF.md 5);
     3. otherwise -> the XLA reference expression (fused fine at short T).
     ``impl`` forces 'flash' / 'xla' for testing."""
     from ..parallel import mesh as mesh_mod

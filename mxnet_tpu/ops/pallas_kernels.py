@@ -9,6 +9,26 @@ Pallas kernels (``_dq_kernel``, ``_dkv_kernel``) recomputing scores against
 the saved log-sum-exp under ``jax.custom_vjp`` (flash-style recompute:
 O(T) memory in both directions).
 
+All three kernels form the score tile transposed, (block_k, block_q): keys
+on sublanes, queries on lanes.  Max and sum over keys are then elementwise
+across vregs, and everything a query row owns — ``m``, ``l``, ``lse``,
+``delta`` — is a lane-dense (1, block_q) row that broadcasts over sublanes
+for free; forward and dQ accumulate (D, block_q) and transpose once a grid
+step.
+
+Precision follows the input: every product takes its two operands in the
+dtype q, k and v came in (bfloat16 under the AMP policy) and accumulates in
+f32; the probabilities are cast to that dtype only as operands of the next
+product.  Running max, normaliser, accumulators, ``lse``, ``delta`` and
+``exp`` are f32.  ``scale`` is folded once into the resident tile and into
+the (D, block) gradient, never into a score tile.  Under ``causal`` only
+the blocks the diagonal crosses are masked.
+
+Tile sizes come from ``flash_blocks(t, d, itemsize)``, one pure function
+of the shape that the guard ``flash_available`` asks too, so the guard and
+the kernels cannot disagree; explicit ``block_q`` / ``block_k`` override it
+in all three kernels.
+
 Used by ``dot_product_attention`` (ops/attention.py) on TPU for long
 sequences; everything is shape-guarded so XLA's fused attention remains the
 fallback.  Tested in Pallas interpret mode on the CPU harness and compiled on
@@ -22,96 +42,182 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_available"]
+__all__ = ["flash_attention", "flash_available", "flash_blocks"]
 
 _NEG_INF = -1e30
 
+# dot_general contractions of two 2-D operands: a @ b.T, a.T @ b, a @ b
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
 
-# VMEM budgets of the shape guard, in bytes at the f32 upper bound.  What
-# they admit was compiled, forward and both backward kernels, on a v5e
-# (libtpu 0.0.34): the corners T*D = 2**20 at D = 64, 128 and 256 pass in
-# f32 and bf16; T=32768, D=32 in f32 is what the compiler refuses (128 MB
-# by the dK/dV estimate below), and it is no longer admitted.
-_KV_BUDGET = 8 * 1024 * 1024
-_DKV_BUDGET = 64 * 1024 * 1024
+# VMEM a kernel may plan for, by ``_vmem_bytes``' estimate at the f32 upper
+# bound.  What it admits was compiled, forward and both backward kernels, for
+# a v5e (libtpu 0.0.34): the corners T*D = 2**20 at D = 64, 128 and 256 pass
+# in f32 and bf16 (39 MB by the estimate at most); T=32768, D=32 in f32,
+# which the compiler refused, reads 71 MB and is not admitted.
+_VMEM_BUDGET = 48 * 1024 * 1024
+
+# (block_q, block_k), most wanted first: the order measured on the v5e at
+# (BH, T, D) = (128, 2048, 64), bf16, causal, the same for all three kernels
+# (PERF.md 6, PR 27): block_q, the score tile's lane dimension, counts most.
+_TILES = [(bq, bk) for bq in (512, 256, 128) for bk in (512, 256, 128)]
 
 
-def flash_available(q_shape, k_shape=None, v_shape=None, block_q=128,
-                    block_k=128):
-    """Shape guard: self-attention only (q/k/v shapes equal), T divisible
-    into blocks, D lane-friendly, and each kernel's whole-T residents must
-    fit VMEM: one head's K+V in the forward and dQ kernels, and in the
-    dK/dV kernel q, dO and the (T, 1) lse/delta columns — double-buffered,
-    with the last dimension padded to the 128 lanes of a VMEM tile, so a
-    narrow head costs as much as D=128 and each column as much as a
-    (T, 128) block."""
+def _vmem_bytes(t, d, itemsize, block_q, block_k):
+    """What one grid step of any of the three kernels keeps in VMEM, at
+    most: the pipeline's two buffers of every block, the last dimension
+    padded to the 128 lanes of a tile (a narrow head costs as much as
+    D=128), the lse / delta rows (each padded to 8 sublanes), and the f32
+    score tiles the body holds at once (s, p, dp, ds)."""
+    lanes = max(d, 128)
+    whole = 2 * 2 * t * lanes * itemsize          # the two whole-T operands
+    tile = max(block_q, block_k)
+    tiles = 4 * 2 * tile * lanes * itemsize       # resident and result tiles
+    rows = 2 * 2 * 8 * t * 4                      # lse, delta
+    return whole + tiles + rows + 4 * block_q * block_k * 4
+
+
+def _fits(t, d, itemsize, block_q, block_k):
+    return t % block_q == 0 and t % block_k == 0 and \
+        _vmem_bytes(t, d, itemsize, block_q, block_k) <= _VMEM_BUDGET
+
+
+def flash_blocks(t, d, itemsize):
+    """``(block_q, block_k)`` of all three kernels for sequence length
+    ``t``, head size ``d`` and operands of ``itemsize`` bytes: the first of
+    ``_TILES`` that divides ``t`` and fits the VMEM budget; None where none
+    does.  In forward and dQ the Q tile (block_q) is resident and K/V stream
+    in block_k steps; in dK/dV the K/V tile is resident and Q/dO stream."""
+    return next((tile for tile in _TILES if _fits(t, d, itemsize, *tile)),
+                None)
+
+
+def flash_available(q_shape, k_shape=None, v_shape=None, block_q=None,
+                    block_k=None):
+    """Shape guard: self-attention only (q/k/v shapes equal), D
+    sublane-friendly, T divisible into blocks, and each kernel's whole-T
+    residents and score tiles within the VMEM budget at the f32 upper bound
+    (``flash_blocks`` at 4 bytes; with explicit blocks, those blocks)."""
     if len(q_shape) != 4:
         return False
     for other in (k_shape, v_shape):
         if other is not None and tuple(other) != tuple(q_shape):
             return False  # cross-attention -> XLA path
     t, d = q_shape[2], q_shape[3]
-    if 2 * t * d * 4 > _KV_BUDGET:
+    if d % 8 or d > 256:
         return False
-    if 2 * 2 * t * (max(d, 128) + 128) * 4 > _DKV_BUDGET:
-        return False
-    return t % block_q == 0 and t % block_k == 0 and t >= block_q and \
-        d % 8 == 0 and d <= 256
+    if block_q is None and block_k is None:
+        return flash_blocks(t, d, 4) is not None
+    return _fits(t, d, 4, block_q or 128, block_k or 128)
+
+
+def _blocks(t, d, dtype, block_q, block_k):
+    """(block_q, block_k) of one call: the chooser's, or the explicit
+    pair."""
+    if block_q is not None or block_k is not None:
+        return block_q or 128, block_k or 128
+    blocks = flash_blocks(t, d, jnp.dtype(dtype).itemsize)
+    if blocks is None:
+        raise ValueError("flash_attention: no tiling for T=%d, D=%d "
+                         "(see flash_available)" % (t, d))
+    return blocks
+
+
+def _scaled(x, scale):
+    """``x * scale`` in x's dtype, through f32 (the one place scale enters
+    a product's operand)."""
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _scores(k, q, k_pos, q_pos, masked):
+    """The transposed score tile k @ q.T, (block_k, block_q) f32; with
+    ``masked`` the entries above the diagonal (key after query) are
+    -inf-like.  ``k_pos`` is a (block_k, 1) column, ``q_pos`` a (1, block_q)
+    row of positions."""
+    s = jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+    if masked:
+        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
+    return s
+
+
+def _positions(start, block, axis):
+    """Positions ``start..start+block`` as a column (axis 0) or a row."""
+    shape = (block, 1) if axis == 0 else (1, block)
+    return start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _stream_kv(fold, carry, j, causal, block_q, block_k, seq_len):
+    """Forward's and dQ's loop over the K/V blocks of Q tile ``j``:
+    ``fold(masked)(kb, carry)``.  Under ``causal`` the blocks wholly at or
+    below every row's diagonal run unmasked, the ones the diagonal crosses
+    (ceil, so partial blocks count) masked, the rest not at all."""
+    if not causal:
+        return jax.lax.fori_loop(0, seq_len // block_k, fold(False), carry)
+    whole = (j * block_q + 1) // block_k
+    crossed = ((j + 1) * block_q + block_k - 1) // block_k
+    carry = jax.lax.fori_loop(0, whole, fold(False), carry)
+    return jax.lax.fori_loop(whole, crossed, fold(True), carry)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 block_q, block_k, seq_len):
     # refs carry one (bh) slice: q (1, block_q, D), k/v (1, T, D)
     j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * scale          # (bq, D)
+    q = _scaled(q_ref[0], scale)                      # (bq, D), input dtype
     bq, d = q.shape
-    q_pos = j * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q_pos = _positions(j * block_q, bq, 1)
 
-    def fold(kb, carry):
-        acc, m, l = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bk)
-        if causal:
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        blk_max = jnp.max(s, axis=1, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        p = jnp.exp(s - new_m)
-        corr = jnp.exp(m - new_m)
-        l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * corr + jax.lax.dot(p, v)
-        return acc, new_m, l
+    def fold(masked):
+        def body(kb, carry):
+            acc, m, l = carry                         # (D, bq), (1, bq) x 2
+            start = pl.multiple_of(kb * block_k, block_k)
+            k = k_ref[0, pl.ds(start, block_k), :]
+            v = v_ref[0, pl.ds(start, block_k), :]
+            s = _scores(k, q, _positions(start, block_k, 0), q_pos, masked)
+            new_m = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - new_m)
+            corr = jnp.exp(m - new_m)
+            l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+            acc = acc * corr + jax.lax.dot_general(
+                v, p.astype(v.dtype), _TN,
+                preferred_element_type=jnp.float32)
+            return acc, new_m, l
+        return body
 
-    acc = jnp.zeros((bq, d), jnp.float32)
-    m = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    if causal:
-        # blocks at or below the diagonal only; ceil so partial blocks count
-        num_kb = ((j + 1) * block_q + block_k - 1) // block_k
-    else:
-        num_kb = seq_len // block_k
-    acc, m, l = jax.lax.fori_loop(0, num_kb, fold, (acc, m, l))
+    carry = (jnp.zeros((d, bq), jnp.float32),
+             jnp.full((1, bq), _NEG_INF, jnp.float32),
+             jnp.zeros((1, bq), jnp.float32))
+    acc, m, l = _stream_kv(fold, carry, j, causal, block_q, block_k, seq_len)
     l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # log-sum-exp residual for the blocked backward
-    lse_ref[0] = m + jnp.log(l)
+    # one transpose a grid step, of whole 128-row tiles: rows 0..D-1 are the
+    # output, the rest the log-sum-exp residual for the blocked backward
+    lse = jnp.broadcast_to(m + jnp.log(l), (128 - d % 128, bq))
+    both = jnp.concatenate([acc / l, lse], axis=0).T
+    o_ref[0] = both[:, :d].astype(o_ref.dtype)
+    lse_ref[0] = both[:, d:d + 1]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128, interpret=False):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, interpret=False):
     """Blocked attention over (B, H, T, D); same semantics as
-    ``attention_reference``."""
+    ``attention_reference``.  ``block_q`` / ``block_k`` None: each kernel's
+    blocks from ``flash_blocks``."""
     return _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
                            interpret)[0]
+
+
+_PARALLEL = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"))
 
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     b, h, t, d = q.shape
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = _blocks(t, d, q.dtype, block_q, block_k)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
     vf = v.reshape(b * h, t, d)
@@ -133,6 +239,7 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
         ],
+        compiler_params=_PARALLEL,
         interpret=interpret,
         name="mxtpu_flash_fwd",
     )(qf, kf, vf)
@@ -149,32 +256,33 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                scale, causal, block_q, block_k, seq_len):
     """dQ: one Q-tile resident, K/V blocks stream (mirrors the forward)."""
     j = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                       # (bq, 1) f32
-    delta = delta_ref[0]                   # (bq, 1) f32
+    q = _scaled(q_ref[0], scale)
+    do = do_ref[0]
+    lse = lse_ref[0, 0]                    # (1, bq) f32
+    delta = delta_ref[0, 0]                # (1, bq) f32
     bq, d = q.shape
-    q_pos = j * block_q + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q_pos = _positions(j * block_q, bq, 1)
 
-    def fold(kb, dq):
-        kblk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ()))) * scale
-        if causal:
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)               # masked entries underflow to 0
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot(ds, kblk)
+    def fold(masked):
+        def body(kb, dq):
+            start = pl.multiple_of(kb * block_k, block_k)
+            k = k_ref[0, pl.ds(start, block_k), :]
+            v = v_ref[0, pl.ds(start, block_k), :]
+            s = _scores(k, q, _positions(start, block_k, 0), q_pos, masked)
+            p = jnp.exp(s - lse)           # masked entries underflow to 0
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta)).astype(k.dtype)
+            return dq + jax.lax.dot_general(
+                k, ds, _TN, preferred_element_type=jnp.float32)
+        return body
 
-    if causal:
-        num_kb = ((j + 1) * block_q + block_k - 1) // block_k
-    else:
-        num_kb = seq_len // block_k
-    dq = jax.lax.fori_loop(0, num_kb, fold, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dq = _stream_kv(fold, jnp.zeros((d, bq), jnp.float32), j, causal,
+                    block_q, block_k, seq_len) * scale
+    if d % 128:                            # transpose whole 128-row tiles
+        dq = jnp.concatenate(
+            [dq, jnp.zeros((128 - d % 128, bq), jnp.float32)], axis=0)
+    dq_ref[0] = dq.T[:, :d].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
@@ -182,35 +290,104 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
     """dK/dV: one K/V-tile resident, Q/dO blocks stream; causal skips the
     Q-blocks strictly above the diagonal."""
     j = pl.program_id(1)
-    kblk = k_ref[0].astype(jnp.float32)    # (bk, d)
-    vblk = v_ref[0].astype(jnp.float32)
-    bk, d = kblk.shape
-    k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    v = v_ref[0]                           # (bk, d)
+    k = _scaled(k_ref[0], scale)
+    bk, d = k.shape
+    k_pos = _positions(j * block_k, bk, 0)
 
-    def fold(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
-        s = jax.lax.dot_general(q, kblk, (((1,), (1,)), ((), ()))) * scale
-        if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)               # (bq, bk)
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
-        dp = jax.lax.dot_general(do, vblk, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
-        return dk, dv
+    def fold(masked):
+        def body(qb, carry):
+            dk, dv = carry
+            start = pl.multiple_of(qb * block_q, block_q)
+            q = q_ref[0, pl.ds(start, block_q), :]
+            do = do_ref[0, pl.ds(start, block_q), :]
+            lse = lse_ref[0, qb]                     # (1, bq) f32
+            delta = delta_ref[0, qb]
+            s = _scores(k, q, k_pos, _positions(start, block_q, 1), masked)
+            p = jnp.exp(s - lse)                     # (bk, bq)
+            dv = dv + jax.lax.dot_general(
+                p.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, do, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta)).astype(q.dtype)
+            dk = dk + jax.lax.dot_general(
+                ds, q, _NN, preferred_element_type=jnp.float32)
+            return dk, dv
+        return body
 
-    start_qb = (j * block_k) // block_q if causal else 0
     zeros = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_qb, seq_len // block_q, fold,
-                               (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    carry = (zeros, zeros)
+    num_qb = seq_len // block_q
+    if causal:
+        # Q blocks from the first the diagonal reaches, masked; unmasked
+        # from the first whose every row sees the tile's last column
+        first = (j * block_k) // block_q
+        whole = ((j + 1) * block_k + block_q - 2) // block_q
+        carry = jax.lax.fori_loop(first, whole, fold(True), carry)
+        carry = jax.lax.fori_loop(whole, num_qb, fold(False), carry)
+    else:
+        carry = jax.lax.fori_loop(0, num_qb, fold(False), carry)
+    dk, dv = carry
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _flash_dq(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
+              interpret):
+    """dQ of (BH, T, D) operands; ``lsef`` / ``deltaf`` f32 rows
+    (BH, T / block_q, 1, block_q), one a grid step."""
+    bh, t, d = qf.shape
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=sc, causal=causal,
+                          block_q=block_q, block_k=block_k, seq_len=t),
+        grid=(bh, t // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda i, j: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_q), lambda i, j: (i, j, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, t, d), qf.dtype),
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="mxtpu_flash_dq",
+    )(qf, kf, vf, gf, lsef, deltaf)
+
+
+def _flash_dkv(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
+               interpret):
+    """dK, dV of (BH, T, D) operands; ``lsef`` / ``deltaf`` as for
+    ``_flash_dq``, all of a head's rows resident."""
+    bh, t, d = qf.shape
+    rows = (t // block_q, 1, block_q)
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=sc, causal=causal,
+                          block_q=block_q, block_k=block_k, seq_len=t),
+        grid=(bh, t // block_k),
+        in_specs=[
+            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1,) + rows, lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((1,) + rows, lambda i, j: (i, 0, 0, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t, d), kf.dtype),
+            jax.ShapeDtypeStruct((bh, t, d), vf.dtype),
+        ],
+        compiler_params=_PARALLEL,
+        interpret=interpret,
+        name="mxtpu_flash_dkv",
+    )(qf, gf, lsef, deltaf, kf, vf)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
@@ -220,57 +397,15 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     b, h, t, d = q.shape
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
+    block_q, block_k = _blocks(t, d, q.dtype, block_q, block_k)
     # delta = rowsum(dO * O): one fused elementwise+reduce pass in XLA
-    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(
-        axis=-1, keepdims=True)
-    qf = q.reshape(b * h, t, d)
-    kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
-    gf = g.reshape(b * h, t, d)
-    lsef = lse.reshape(b * h, t, 1)
-    deltaf = delta.reshape(b * h, t, 1)
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=sc, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=t),
-        grid=(b * h, t // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        interpret=interpret,
-        name="mxtpu_flash_dq",
-    )(qf, kf, vf, gf, lsef, deltaf)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=sc, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=t),
-        grid=(b * h, t // block_k),
-        in_specs=[
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, 1), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, t, d), v.dtype),
-        ],
-        interpret=interpret,
-        name="mxtpu_flash_dkv",
-    )(qf, gf, lsef, deltaf, kf, vf)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(axis=-1)
+    # q, k, v, dO flat over heads; lse, delta as one (1, block_q) row a Q block
+    args = [x.reshape(b * h, t, d) for x in (q, k, v, g)] + \
+        [x.reshape(b * h, t // block_q, 1, block_q) for x in (lse, delta)]
+    args += [causal, sc, block_q, block_k, interpret]
+    dq = _flash_dq(*args)
+    dk, dv = _flash_dkv(*args)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))
 

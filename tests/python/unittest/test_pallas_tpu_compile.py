@@ -1,0 +1,85 @@
+"""The flash kernels compiled for a described (not attached) TPU v5e, at the
+benchmark cell's shape and the shape guard's corners: what interpret mode
+cannot show — a slice Mosaic cannot tile, a transpose it cannot lower, more
+VMEM than a kernel may use — fails here, on the CPU harness, at no chip time.
+Nothing runs: results and times come from ``tools/tpu_numerics_check.py``
+and the benchmark.
+
+All such compiles live in this one file, and the topology is described in a
+fixture (never at import): one process at a time may hold the TPU library,
+and under xdist only the worker given this file loads it."""
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from mxnet_tpu.ops.pallas_kernels import (flash_attention, flash_available,
+                                          flash_blocks)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, shape, dtype, causal=True, **blocks):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal, None, **blocks)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    text = compiled.as_text()
+    # forward, dQ and dK/dV are there as Mosaic kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    return text
+
+
+# (B, H, T, D): opt-1.3b-steps' attention, then the corners T*D = 2**20 of
+# flash_available at each lane width (tools/tpu_numerics_check.py runs them)
+CELL = (4, 32, 2048, 64)
+CORNERS = [(1, 1, 16384, 64), (1, 1, 8192, 128), (1, 1, 4096, 256)]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_the_benchmark_cells_shape_compiles_with_the_choosers_blocks(
+        one_chip, causal):
+    assert flash_blocks(2048, 64, 2) == (512, 512)
+    text = _compile(one_chip, CELL, jnp.bfloat16, causal)
+    # the results the benchmark's readers tell the kernels apart by
+    assert "(bf16[128,2048,64]" in text and "f32[128,2048,1]" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("shape", CORNERS)
+def test_the_guards_corners_compile(one_chip, shape, dtype):
+    """The guard plans VMEM at the f32 upper bound, so what it admits has to
+    compile with f32 operands too."""
+    assert flash_available(shape)
+    _compile(one_chip, shape, dtype)
+
+
+@pytest.mark.parametrize("d", [8, 32, 96, 192])
+def test_head_sizes_off_the_lane_width_compile(one_chip, d):
+    """D is the sublane dimension of forward's and dQ's accumulators and is
+    padded to whole 128-row tiles for their one transpose."""
+    assert flash_available((1, 2, 1024, d))
+    _compile(one_chip, (1, 2, 1024, d), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 512), (512, 256)])
+def test_explicit_blocks_compile(one_chip, bq, bk):
+    _compile(one_chip, (1, 4, 2048, 64), jnp.bfloat16, block_q=bq,
+             block_k=bk)

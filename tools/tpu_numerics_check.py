@@ -1,9 +1,10 @@
 """On-chip numerics assertions for the Pallas kernels: each kernel COMPILED
 (no interpret mode) on the TPU and compared against its XLA formulation at
 bf16-appropriate tolerances — flash attention forward and both backward
-kernels, including the corners of its shape guard, and NormConv at the four
-ResNet-50 stage shapes.  The interpret-mode twins of these checks run on
-the CPU harness (test_pallas.py, test_norm_conv.py).
+kernels, including the corners of its shape guard (those with f32 operands
+too), and NormConv at the four ResNet-50 stage shapes.  The interpret-mode
+twins of these checks run on the CPU harness (test_pallas.py,
+test_norm_conv.py).
 
 Run on a machine with a chip:  python tools/tpu_numerics_check.py
 Prints one PASS line per check; exits non-zero on any mismatch, on a shape
@@ -42,7 +43,8 @@ def _rel(a, b):
 def check_flash_attention():
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import flash_attention, flash_available
+    from mxnet_tpu.ops.pallas_kernels import (flash_attention, flash_available,
+                                              flash_blocks)
     from mxnet_tpu.parallel.ring import attention_reference
 
     def loss_f(fn):
@@ -50,25 +52,34 @@ def check_flash_attention():
 
     for (b, h_, t, d, causal) in FLASH_SHAPES:
         assert flash_available((b, h_, t, d)), (b, h_, t, d)
-        rng = np.random.RandomState(0)
-        q, k, v = (jnp.asarray(rng.randn(b, h_, t, d).astype(np.float32))
-                   .astype(jnp.bfloat16) for _ in range(3))
-        q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
-        flash = lambda a, b_, c: flash_attention(a, b_, c, causal)  # noqa: E731
-        ref = lambda a, b_, c: attention_reference(  # noqa: E731
-            a, b_, c, causal=causal)
-        err = _rel(jax.jit(flash)(q, k, v), jax.jit(ref)(q32, k32, v32))
-        assert err < 2e-2, "flash fwd rel err %.2e at %s" % (
-            err, (b, h_, t, d, causal))
-        # gradients: pallas backward kernels vs autodiff of the reference
-        gp = jax.jit(jax.grad(loss_f(flash), argnums=(0, 1, 2)))(q, k, v)
-        gr = jax.jit(jax.grad(loss_f(ref), argnums=(0, 1, 2)))(q32, k32, v32)
-        for name, a, bb in zip("qkv", gp, gr):
-            err = _rel(a, bb)
-            assert err < 5e-2, "flash d%s rel err %.2e at %s" % (
-                name, err, (b, h_, t, d, causal))
-        print("PASS flash_attention %s" % ((b, h_, t, d, causal),),
-              flush=True)
+        # the guard plans VMEM at the f32 upper bound: its corners are
+        # compiled with f32 operands too
+        corner = t * d == 2 ** 20
+        for dtype in (jnp.bfloat16, jnp.float32) if corner else (
+                jnp.bfloat16,):
+            rng = np.random.RandomState(0)
+            q, k, v = (jnp.asarray(rng.randn(b, h_, t, d).astype(np.float32))
+                       .astype(dtype) for _ in range(3))
+            q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+            flash = lambda a, b_, c: flash_attention(a, b_, c, causal)  # noqa: E731
+            ref = lambda a, b_, c: attention_reference(  # noqa: E731
+                a, b_, c, causal=causal)
+            errs = [_rel(jax.jit(flash)(q, k, v), jax.jit(ref)(q32, k32, v32))]
+            assert errs[0] < 2e-2, "flash fwd rel err %.2e at %s" % (
+                errs[0], (b, h_, t, d, causal))
+            # gradients: pallas backward kernels vs autodiff of the reference
+            gp = jax.jit(jax.grad(loss_f(flash), argnums=(0, 1, 2)))(q, k, v)
+            gr = jax.jit(jax.grad(loss_f(ref), argnums=(0, 1, 2)))(
+                q32, k32, v32)
+            for name, a, bb in zip("qkv", gp, gr):
+                errs.append(_rel(a, bb))
+                assert errs[-1] < 5e-2, "flash d%s rel err %.2e at %s" % (
+                    name, errs[-1], (b, h_, t, d, causal))
+            print("PASS flash_attention %s %s blocks %s  rel err fwd %.1e "
+                  "dq %.1e dk %.1e dv %.1e" % (
+                      (b, h_, t, d, causal), jnp.dtype(dtype).name,
+                      flash_blocks(t, d, jnp.dtype(dtype).itemsize),
+                      *errs), flush=True)
 
 
 def check_norm_conv():
