@@ -29,13 +29,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np  # noqa: E402
 
 import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu.models import transformer  # noqa: E402
+from mxnet_tpu import models  # noqa: E402
 from mxnet_tpu.train import TrainStep  # noqa: E402
 from mxnet_tpu.parallel import mesh as mesh_mod  # noqa: E402
 
 
 def parse_args():
     p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--model", default="transformer",
+                   choices=("transformer", "hybrid_lm"),
+                   help="module under mxnet_tpu.models whose get_symbol "
+                        "builds the net (hybrid_lm: state-space, expert "
+                        "and grouped-query layers by its default pattern)")
     p.add_argument("--vocab", type=int, default=256)
     p.add_argument("--seq-len", type=int, default=256)
     p.add_argument("--batch-size", type=int, default=8)
@@ -80,9 +85,11 @@ def main():
                                devices=jax.devices()[:n]))
         logging.info("ring attention over sp=%d devices", n)
 
-    net = transformer.get_symbol(
-        vocab_size=args.vocab, seq_len=T, num_layers=args.num_layers,
-        num_hidden=args.num_hidden, num_heads=args.num_heads)
+    size = dict(vocab_size=args.vocab, seq_len=T, num_hidden=args.num_hidden,
+                num_heads=args.num_heads)
+    if args.model == "transformer":     # hybrid_lm's depth is its pattern
+        size["num_layers"] = args.num_layers
+    net = getattr(models, args.model).get_symbol(**size)
     opt = mx.optimizer.Adam(learning_rate=args.lr)
     ts = TrainStep(net, opt)
     params, state, aux = ts.init({"data": (B, T)},

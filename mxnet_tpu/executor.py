@@ -23,6 +23,8 @@ is the TrainStep path in mxnet_tpu/train.py.
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as _np
 
 from .base import MXNetError, string_types
@@ -566,6 +568,19 @@ class _Lowered(object):
         skip.add(id(conv))
         return True
 
+    def f32_leaves(self):
+        """Names of the variables that feed, directly, an input an op wants
+        in float32 under a mixed-precision policy (``OpDef.f32_inputs``)."""
+        keep = set()
+        for node in self.order:
+            if node.is_var or not node.op.f32_inputs:
+                continue
+            names = node.op.arg_names_for(node.params)
+            keep.update(child.name
+                        for name, (child, _) in zip(names, node.inputs)
+                        if child.is_var and name in node.op.f32_inputs)
+        return keep
+
     def run(self, arg_vals, aux_vals, rng, is_train, collect=False,
             no_grad_inputs=(), head_grad_scale=None, stage=None,
             carry_vals=None):
@@ -713,11 +728,17 @@ class _Lowered(object):
                 ins = [_get_scale_backward()(ins[0], head_grad_scale)] \
                     + ins[1:]
             call = op.make_callable(params, is_train)
-            if op.needs_rng:
-                sub = jax.random.fold_in(rng, _node_uid(node, self.uid))
-                out = call(sub, *ins)
-            else:
-                out = call(*ins)
+            # a node built under ``AttrScope(__scope__=...)`` runs under
+            # that ``jax.named_scope``: metadata only, so that its device
+            # operations, forward and backward, carry the layer's name
+            scope = node.attr.get("__scope__")
+            with jax.named_scope(scope) if scope \
+                    else contextlib.nullcontext():
+                if op.needs_rng:
+                    sub = jax.random.fold_in(rng, _node_uid(node, self.uid))
+                    out = call(sub, *ins)
+                else:
+                    out = call(*ins)
             if not isinstance(out, (tuple, list)):
                 out = (out,)
             n_vis = op.num_outputs_for(node.params)

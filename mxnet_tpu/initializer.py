@@ -344,6 +344,36 @@ class LSTMBias(Initializer):
     _init_weight = _init_bias
 
 
+class LogOfUniform(Initializer):
+    """log(U(low, high)): a state-space scan's ``A_log``, whose decay rate
+    ``A = exp(A_log)`` the Mamba-2 initialisation draws from U(1, 16)."""
+
+    def __init__(self, low=1.0, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, _, arr):
+        tmp = nd.uniform(low=self.low, high=self.high, shape=arr.shape)
+        arr[:] = np.log(tmp.asnumpy())
+
+
+class InverseSoftplusLogUniform(Initializer):
+    """The bias ``b`` with ``softplus(b)`` log-uniform in [low, high] and at
+    least ``floor``: a state-space scan's ``dt_bias`` (Mamba-2: the step
+    ``dt`` starts in 0.001-0.1)."""
+
+    def __init__(self, low=0.001, high=0.1, floor=1e-4):
+        super().__init__(low=low, high=high, floor=floor)
+        self.low, self.high, self.floor = low, high, floor
+
+    def _init_weight(self, _, arr):
+        u = nd.uniform(low=0.0, high=1.0, shape=arr.shape).asnumpy()
+        dt = np.exp(u * (np.log(self.high) - np.log(self.low))
+                    + np.log(self.low))
+        dt = np.maximum(dt, self.floor)
+        arr[:] = dt + np.log(-np.expm1(-dt))
+
+
 # registry of initializer classes by lowercase name, used by the
 # Variable(init=...) '__init__' attr dispatch and Load/Mixed dumps parity
 _INITIALIZER_REGISTRY = {

@@ -8,6 +8,7 @@ from . import inception_v3
 from . import vgg
 from . import ssd
 from . import transformer
+from . import hybrid_lm
 
 get_lenet = lenet.get_symbol
 get_mlp = mlp.get_symbol

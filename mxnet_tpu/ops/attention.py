@@ -1,8 +1,13 @@
 """Attention operators (NEW capability — the reference has no attention op
 anywhere in src/operator, SURVEY.md §5.7; designed TPU-first from the start).
 
-``dot_product_attention`` is the core primitive: (B, H, T, D) Q/K/V in, same
-shape out.  When a sequence-parallel mesh is active
+``dot_product_attention`` is the core primitive: (B, H, T, D) Q/K/V in, Q's
+shape out.  Unequal head counts are grouped-query attention: K and V may come
+with fewer heads, H_kv dividing H; query head ``i`` reads key/value head
+``i // (H / H_kv)``.  The key/value heads are repeated ahead of the lowering
+ladder, so every rung (ring, flash kernels, XLA) sees equal shapes and the
+backward sums each group's gradients; equal head counts take the path they
+always took.  When a sequence-parallel mesh is active
 (``mxnet_tpu.parallel.mesh.set_sequence_mesh``) it lowers to ring attention —
 K/V blocks rotating over the ``sp`` mesh axis via ``ppermute`` with
 online-softmax accumulation — so the same symbol graph scales from one chip
@@ -32,7 +37,9 @@ def _attn_infer(attrs, in_shapes):
           infer_shape=_attn_infer)
 def _dot_product_attention(query, key, value, causal=False, scale=None,
                            impl="auto"):
-    """Scaled dot-product attention over (B, H, T, D).
+    """Scaled dot-product attention over (B, H, T, D); key and value may
+    have H_kv < H heads (grouped-query: each is read by H / H_kv query
+    heads, repeated here before the ladder).
 
     Lowering ladder (impl='auto'):
     1. sequence mesh active -> ring attention (multi-chip, ppermute ring);
@@ -45,6 +52,14 @@ def _dot_product_attention(query, key, value, causal=False, scale=None,
     from ..parallel import mesh as mesh_mod
     from ..parallel import ring
     from . import pallas_kernels
+    groups, rest = divmod(query.shape[1], key.shape[1])
+    if rest or key.shape[1] != value.shape[1]:
+        raise ValueError("dot_product_attention: %d query heads on %d key "
+                         "and %d value heads" % (query.shape[1], key.shape[1],
+                                                 value.shape[1]))
+    if groups > 1:
+        key = jnp.repeat(key, groups, axis=1)
+        value = jnp.repeat(value, groups, axis=1)
     mesh, axis = mesh_mod.sequence_mesh()
     if mesh is not None:
         return ring.ring_attention(query, key, value, mesh, axis=axis,
@@ -98,3 +113,35 @@ def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
     var = ((data - mu) ** 2).mean(axis=axis, keepdims=True)
     xhat = (data - mu) * jax.lax.rsqrt(var + eps)
     return xhat * gamma + beta
+
+
+def _rms_args(attrs):
+    return ["data", "gamma", "gate"] if attrs.get("gated", False) else \
+        ["data", "gamma"]
+
+
+@register("RMSNorm", arg_names=_rms_args,
+          attr_types={"eps": parse_float, "num_groups": int,
+                      "gated": parse_bool},
+          defaults={"eps": 1e-5, "num_groups": 1, "gated": False},
+          infer_shape=lambda attrs, ins: (
+              [ins[0], None if ins[0] is None else (ins[0][-1],)]
+              + [ins[0]] * (len(ins) - 2), [ins[0]], None))
+def _rms_norm(data, gamma, gate=None, eps=1e-5, num_groups=1, gated=False):
+    """Root-mean-square normalization over the last axis, ``x / sqrt(mean(x^2)
+    + eps) * gamma``; with ``num_groups`` > 1 the mean is taken over each of
+    that many equal groups of the axis.  ``gated`` adds a third input and
+    norms ``x * silu(gate)``, gate first (the gated norm of a state-space
+    mixer).  Statistics in float32 whatever the input's dtype; of the
+    forward only the inputs are kept."""
+    @jax.checkpoint
+    def norm(data, gamma, gate):
+        x = data.astype(jnp.float32)
+        if gate is not None:
+            x = x * jax.nn.silu(gate.astype(jnp.float32))
+        g = int(num_groups)
+        grouped = x.reshape(x.shape[:-1] + (g, x.shape[-1] // g))
+        var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+        y = (grouped * jax.lax.rsqrt(var + eps)).reshape(x.shape)
+        return (y * gamma.astype(jnp.float32)).astype(data.dtype)
+    return norm(data, gamma, gate)

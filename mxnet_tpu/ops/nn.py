@@ -73,18 +73,24 @@ def _fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False):
 
 
 # ------------------------------------------------------------------ Activation
+# act_type -> function; ``silu`` and ``relu2`` (the squared ReLU) are what
+# state-space mixers and recent expert layers use
+ACTIVATIONS = {
+    "relu": lambda x: jnp.maximum(x, 0),
+    "sigmoid": jax.nn.sigmoid,
+    "tanh": jnp.tanh,
+    "softrelu": jax.nn.softplus,
+    "silu": jax.nn.silu,
+    "relu2": lambda x: jnp.square(jnp.maximum(x, 0)),
+}
+
+
 @register("Activation", attr_types={"act_type": parse_str},
           defaults={"act_type": "relu"})
 def _activation(data, act_type="relu"):
-    if act_type == "relu":
-        return jnp.maximum(data, 0)
-    if act_type == "sigmoid":
-        return jax.nn.sigmoid(data)
-    if act_type == "tanh":
-        return jnp.tanh(data)
-    if act_type == "softrelu":
-        return jax.nn.softplus(data)
-    raise MXNetError("unknown act_type %s" % act_type)
+    if act_type not in ACTIVATIONS:
+        raise MXNetError("unknown act_type %s" % act_type)
+    return ACTIVATIONS[act_type](data)
 
 
 def _lrelu_args(attrs):

@@ -97,8 +97,9 @@ def flash_blocks(t, d, itemsize):
 
 def flash_available(q_shape, k_shape=None, v_shape=None, block_q=None,
                     block_k=None):
-    """Shape guard: self-attention only (q/k/v shapes equal), D
-    sublane-friendly, T divisible into blocks, and each kernel's whole-T
+    """Shape guard: self-attention only (q/k/v shapes equal; grouped-query
+    key/value heads are repeated in ``dot_product_attention`` before this is
+    asked, other unequal shapes go to XLA), D sublane-friendly, T divisible into blocks, and each kernel's whole-T
     residents and score tiles within the VMEM budget at the f32 upper bound
     (``flash_blocks`` at 4 bytes; with explicit blocks, those blocks)."""
     if len(q_shape) != 4:

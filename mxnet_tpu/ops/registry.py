@@ -107,6 +107,8 @@ class OpDef(object):
     needs_rng / train_aware : whether fn takes rng= / is_train=
     key_var_num_args : attr naming the input count for variadic ops ('num_args')
     aliases : extra registered names
+    f32_inputs : names of inputs the op wants in float32 whatever the
+        compute dtype of a mixed-precision policy
     """
 
     def __init__(self, name, fn, arg_names=("data",), aux_names=(), num_outputs=1,
@@ -114,8 +116,14 @@ class OpDef(object):
                  infer_shape_backward=None, input_init_attrs=None,
                  needs_rng=False, train_aware=False, key_var_num_args=None,
                  aliases=(), hidden=False, doc=None, is_loss=False,
-                 layout_rule=None, layout_inputs=(0,), env_attrs=None):
+                 layout_rule=None, layout_inputs=(0,), env_attrs=None,
+                 f32_inputs=()):
         self.name = name
+        # inputs that stay float32 under a mixed-precision policy: a leaf
+        # that feeds one of them directly is not cast to the compute dtype
+        # (a router's weight, a scan's decay rates; TrainStep asks
+        # executor._Lowered.f32_leaves)
+        self.f32_inputs = tuple(f32_inputs)
         # how the executor's NHWC layout pass treats this op (see
         # executor._Lowered.run): None = rigid (inputs restored to logical
         # NCHW), 'aware' = fn accepts layout='NHWC' and executes channel-last

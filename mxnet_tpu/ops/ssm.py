@@ -1,0 +1,173 @@
+"""State-space mixer operators (NEW capability, no reference analogue): the
+causal depthwise convolution over time and the selective state-space scan
+of Mamba-2 (Dao & Gu 2024, "Transformers are SSMs"), per head with a state
+``S`` of (P, N):
+
+    S_t = exp(A dt_t) S_{t-1} + dt_t x_t B_t^T        y_t = S_t C_t + D x_t
+
+``ssm_scan`` computes it in the chunked form: inside a chunk of L steps the
+masked, decay-weighted product ``(C B^T * decay * dt) x`` (matrix products of
+(L, L) blocks, MXU work); between chunks the carried state, a short
+sequential pass over T / L states.  The backward is autodiff of that form
+under ``jax.checkpoint``: only the op's inputs are kept from the forward and
+the (L, L) blocks are formed again, group by group, which costs a few per
+cent of the layer's FLOPs and saves their memory (each (heads, T / L, L, L)
+float32 array is 128 MiB at T = 4096 with 64 heads, and the backward holds
+half a dozen).
+
+Precision follows the published kernels: ``dt``, ``A``, the decays
+``exp(A dt)`` and the carried state are float32 whatever the input's dtype;
+the matrix products take operands in the input's dtype and accumulate in
+float32.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .nn import ACTIVATIONS
+from .registry import register, parse_int, parse_str
+
+
+# ------------------------------------------------------- causal convolution
+def _conv_infer(attrs, in_shapes):
+    data = in_shapes[0]
+    ins = list(in_shapes)
+    if data is not None:
+        ins[1] = (data[-1], int(attrs.get("kernel")))
+        ins[2] = (data[-1],)
+    return ins, [data], None
+
+
+@register("causal_conv1d", arg_names=("data", "weight", "bias"),
+          attr_types={"kernel": parse_int, "act_type": parse_str},
+          defaults={"act_type": None}, infer_shape=_conv_infer)
+def _causal_conv1d(data, weight, bias, kernel=None, act_type=None):
+    """Depthwise causal convolution over time: data (B, T, C), weight
+    (C, K), ``y[t] = sum_j weight[:, j] x[t - (K - 1) + j] + bias`` with
+    zeros before t = 0 (torch ``Conv1d(groups=C, padding=K - 1)`` cut to T),
+    then ``act_type`` (an ``Activation`` type) if given.  K shifted
+    multiply-adds, accumulated in float32; the backward forms them again
+    from the input, which alone is kept."""
+    act = ACTIVATIONS[act_type] if act_type else None
+
+    @jax.checkpoint
+    def conv(data, weight, bias):
+        k, t = weight.shape[1], data.shape[1]
+        padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(
+            jnp.float32)
+        w = weight.astype(jnp.float32)
+        y = sum(padded[:, j:j + t, :] * w[:, j] for j in range(k)) \
+            + bias.astype(jnp.float32)
+        return (act(y) if act else y).astype(data.dtype)
+    return conv(data, weight, bias)
+
+
+# ------------------------------------------------------------------ the scan
+def _group_scan(x, dt, a, b, c):
+    """One group's heads.  x (B, c, L, R, P) in the compute dtype, dt
+    (B, c, L, R) float32, a (R,) float32, b and c (B, c, L, N): y
+    (B, c, L, R, P) float32."""
+    f32 = jnp.float32
+    cd, l = x.dtype, x.shape[2]
+    # per-step log-decay and its running sum inside each chunk: (B,c,R,L)
+    dtc = dt.transpose(0, 1, 3, 2)
+    acs = jnp.cumsum(dtc * a[None, None, :, None], axis=-1)
+    # inside a chunk: y_l = sum_{s <= l} (C_l . B_s) exp(acs_l - acs_s) dt_s x_s
+    cb = jnp.einsum("bcln,bcsn->bcls", c, b, preferred_element_type=f32)
+    seg = acs[..., :, None] - acs[..., None, :]
+    mask = jnp.tril(jnp.ones((l, l), bool))
+    decay = jnp.exp(jnp.where(mask, seg, -jnp.inf))
+    m = (cb[:, :, None] * decay * dtc[..., None, :]).astype(cd)
+    y = jnp.einsum("bcrls,bcsrp->bclrp", m, x, preferred_element_type=f32)
+    # what each chunk adds to the state by its end: (B,c,R,P,N)
+    to_end = jnp.exp(acs[..., -1:] - acs) * dtc
+    xw = (x.astype(f32) * to_end.transpose(0, 1, 3, 2)[..., None]).astype(cd)
+    states = jnp.einsum("bcsrp,bcsn->bcrpn", xw, b,
+                        preferred_element_type=f32)
+    # between chunks: the state carried into each chunk, float32
+    total = jnp.exp(acs[..., -1])                        # (B,c,R)
+
+    def carry(s, inp):
+        decay_c, add = inp
+        return decay_c[..., None, None] * s + add, s
+    _, before = jax.lax.scan(
+        carry, jnp.zeros(states.shape[:1] + states.shape[2:], f32),
+        (jnp.moveaxis(total, 1, 0), jnp.moveaxis(states, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                  # (B,c,R,P,N)
+    y_prev = jnp.einsum("bcln,bcrpn->bclrp", c, before.astype(cd),
+                        preferred_element_type=f32)
+    return y + y_prev * jnp.exp(acs).transpose(0, 1, 3, 2)[..., None]
+
+
+def ssm_scan_chunked(x, dt, a, b, c, chunk):
+    """The chunked scan without the skip term.  x (B, T, H, P) in the
+    compute dtype; dt (B, T, H) float32, after its softplus; a (H,) float32,
+    negative; b, c (B, T, G, N) with head i reading group i // (H / G).
+    Returns y (B, T, H, P) float32.  T is padded to a multiple of ``chunk``
+    with dt = 0, which carries the state unchanged and adds nothing.  The
+    groups are worked one after another, each under ``jax.checkpoint``, so
+    that one group's (L, L) blocks are alive at a time."""
+    bsz, t, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    r = h // g
+    pad = -t % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // chunk
+
+    def by_group(v, *tail):          # (B, T, g, ...) -> (g, B, c, L, ...)
+        return jnp.moveaxis(v.reshape((bsz, nc, chunk, g) + tail), 3, 0)
+    y = jax.lax.map(
+        jax.checkpoint(lambda args: _group_scan(*args)),
+        (by_group(x, r, p), by_group(dt, r), a.reshape(g, r),
+         by_group(b, n), by_group(c, n)))                # (g, B, c, L, R, P)
+    return jnp.moveaxis(y, 0, 3).reshape(bsz, nc * chunk, h, p)[:, :t]
+
+
+def _scan_infer(attrs, in_shapes):
+    h = int(attrs.get("num_heads"))
+    ins = list(in_shapes)
+    for i in (2, 3, 4):
+        ins[i] = (h,)
+    data = ins[0]
+    out = None if data is None else \
+        tuple(data[:-1]) + (h * int(attrs.get("head_dim")),)
+    return ins, [out], None
+
+
+def _scan(xbc, dt, a_log, d, dt_bias, h, p, g, chunk):
+    f32 = jnp.float32
+    bsz, t, _ = xbc.shape
+    inner = h * p
+    n = (xbc.shape[2] - inner) // (2 * g)
+    x = xbc[..., :inner].reshape(bsz, t, h, p)
+    b = xbc[..., inner:inner + g * n].reshape(bsz, t, g, n)
+    c = xbc[..., inner + g * n:].reshape(bsz, t, g, n)
+    step = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    y = ssm_scan_chunked(x, step, -jnp.exp(a_log.astype(f32)), b, c, chunk)
+    y = y + d.astype(f32)[:, None] * x.astype(f32)
+    return y.astype(xbc.dtype).reshape(bsz, t, inner)
+
+
+@register("ssm_scan", arg_names=("data", "dt", "a_log", "d", "dt_bias"),
+          attr_types={"num_heads": parse_int, "head_dim": parse_int,
+                      "num_groups": parse_int, "chunk_size": parse_int},
+          defaults={"num_groups": 1, "chunk_size": 128},
+          infer_shape=_scan_infer, f32_inputs=("a_log", "d", "dt_bias"))
+def _ssm_scan(data, dt, a_log, d, dt_bias, num_heads=None, head_dim=None,
+              num_groups=1, chunk_size=128):
+    """Selective state-space scan.  data (B, T, H*P + 2*G*N) holds x, B and
+    C side by side, as the convolution leaves them; dt (B, T, H); a_log, d,
+    dt_bias (H,), float32 leaves.  The step is ``softplus(dt + dt_bias)``
+    (always above 0, so a time-step limit of (0, inf) clamps nothing), the
+    decay ``exp(-exp(a_log) dt)``, and ``d`` scales the skip ``D x``.
+    Returns y (B, T, H*P) in data's dtype; of the forward only the inputs
+    are kept."""
+    core = jax.checkpoint(functools.partial(
+        _scan, h=int(num_heads), p=int(head_dim), g=int(num_groups),
+        chunk=int(chunk_size)))
+    return core(data, dt, a_log, d, dt_bias)

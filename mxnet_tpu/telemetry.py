@@ -76,7 +76,9 @@ __all__ = ["start", "stop", "enabled", "span", "record_span", "counter",
            "counters", "gauges", "histograms", "scalars", "quantile",
            "quantile_from_hist", "hist_bound", "events", "recent_events",
            "flush", "reset", "sink_path", "flight_recorder",
-           "flight_recorder_armed"]
+           "flight_recorder_armed", "device_counter",
+           "collect_device_counters", "publish_device_counters",
+           "device_counters"]
 
 _lock = threading.RLock()
 _enabled = False
@@ -259,6 +261,73 @@ def flush():
     """Drain buffered events to the file sink (no-op without a path)."""
     with _lock:
         _flush_locked()
+
+
+# ---------------------------------------------------------- device counters
+# Counters that live on the device: an op hands a traced value to
+# ``device_counter`` while a step function traces, the step function returns
+# what was collected beside its outputs, and the caller publishes the device
+# arrays here, where the newest few runs' are kept.  Nothing is fetched until
+# somebody asks (``device_counters``: one ``device_get``), so a counter costs
+# the step its own arithmetic and the hot path no sync.
+_dev_collecting = []   # stack of {name: [traced value, ...]} while tracing
+# ({name: device array (calls, ...)}, steps summed) of the newest runs of a
+# step program, the newest last
+_dev_recent = deque(maxlen=64)
+
+
+def device_counter(name, value):
+    """Called by an op under trace: one more value of counter ``name`` (an
+    array; every call of a name has the same shape), in call order, which
+    is the graph's layer order.  Without a collecting step function the
+    value is dropped."""
+    if _dev_collecting:
+        _dev_collecting[-1].setdefault(name, []).append(value)
+
+
+class collect_device_counters(object):
+    """``with collect_device_counters() as bag:`` around the trace of a
+    graph; ``bag.stacked()`` is {name: array (calls, ...)} of what its ops
+    handed in, still traced."""
+
+    def __enter__(self):
+        self._bag = {}
+        _dev_collecting.append(self._bag)
+        return self
+
+    def __exit__(self, *exc):
+        _dev_collecting.pop()
+
+    def stacked(self):
+        import jax.numpy as jnp
+        return {k: jnp.stack(v) for k, v in self._bag.items()}
+
+
+def publish_device_counters(values, steps):
+    """The counters of one run of a step program, as device arrays summed
+    over the ``steps`` steps it ran."""
+    _dev_recent.append((values, int(steps)))
+
+
+def device_counters(steps=None):
+    """({name: numpy array (calls, ...)}, steps they are summed over),
+    fetched now: of the newest run of a step program or, with ``steps``, of
+    the newest runs that together cover so many steps (a window's chunks);
+    (None, 0) where no step program with counters has run."""
+    if not _dev_recent:
+        return None, 0
+    import jax
+    take, covered = [], 0
+    for values, n in reversed(_dev_recent):
+        if take and {k: v.shape for k, v in values.items()} != {
+                k: v.shape for k, v in take[0].items()}:
+            break                                    # another program's
+        take.append(values)
+        covered += n
+        if steps is None or covered >= steps:
+            break
+    take = jax.device_get(take)
+    return {k: sum(t[k] for t in take) for k in take[0]}, covered
 
 
 # ------------------------------------------------------------------ counters

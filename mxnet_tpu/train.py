@@ -313,6 +313,43 @@ class _FunctionalOptimizer(object):
         raise MXNetError("unreachable")
 
 
+# Device counters (``telemetry.device_counter``) that a graph's ops hand in
+# while the step traces: they ride out of the forward beside the aux updates
+# under this key, and out of the step program as a dict after its outputs.
+_COUNTERS = "__device_counters__"
+
+
+def _with_counters(outs, aux_upd):
+    counters = aux_upd.get(_COUNTERS)
+    return outs + (counters,) if counters else outs
+
+
+def _split_counters(outs):
+    """(the graph's outputs, the counters' dict or None)."""
+    if outs and isinstance(outs[-1], dict):
+        return outs[:-1], outs[-1]
+    return outs, None
+
+
+def _sum_counters(outs, counted):
+    """The last step's outputs with its counters summed with those the scan
+    stacked over the steps before it."""
+    outs, last = _split_counters(outs)
+    if last is None:
+        return outs
+    return outs + ({k: v + counted[k].sum(axis=0)
+                    for k, v in last.items()},)
+
+
+def _publish_counters(outs, steps):
+    """Hand a step program's counters, still on the device, to telemetry;
+    the caller sees the graph's outputs alone."""
+    outs, counters = _split_counters(outs)
+    if counters is not None:
+        _tel.publish_device_counters(counters, steps)
+    return outs
+
+
 class TrainStep(object):
     """Compile a Symbol + Optimizer into one donated, sharded XLA train step.
 
@@ -423,6 +460,7 @@ class TrainStep(object):
                 sizer=lambda ts: 1 if ts._gather_fn is not None else 0,
                 warmup=1, jit_names=("mxtpu_zero_gather",))
         low = self._low
+        f32_leaves = low.f32_leaves()
 
         def fwd(params, aux, batch, rng, head_scale=None):
             vals = dict(batch)
@@ -434,11 +472,20 @@ class TrainStep(object):
                             if k not in self.label_names
                             and v.dtype == _np.float32 else v)
                         for k, v in vals.items()}
-                params = {k: v.astype(dtype) for k, v in params.items()}
+                # leaves an op wants in float32 (a router's weight, a
+                # scan's decay rates) stay as they are
+                params = {k: v if k in f32_leaves else v.astype(dtype)
+                          for k, v in params.items()}
             vals.update(params)
-            outs, aux_upd = low.run(vals, aux, rng, True,
-                                    no_grad_inputs=inputs,
-                                    head_grad_scale=head_scale)
+            with _tel.collect_device_counters() as bag:
+                outs, aux_upd = low.run(vals, aux, rng, True,
+                                        no_grad_inputs=inputs,
+                                        head_grad_scale=head_scale)
+            counters = bag.stacked()
+            if counters:
+                # device counters ride beside the aux updates (no aux state
+                # has this name) and leave the step as a last output
+                aux_upd = dict(aux_upd, **{_COUNTERS: counters})
             return tuple(outs), aux_upd
 
         if remat:
@@ -531,9 +578,11 @@ class TrainStep(object):
             new_aux.update({k: v.astype(aux[k].dtype)
                             for k, v in aux_upd.items() if k in aux})
             if not want_stats:
-                return new_params, new_state, new_aux, outs
+                return new_params, new_state, new_aux, \
+                    _with_counters(outs, aux_upd)
             stats = self._monitor_stats(params, grads, new_params, outs)
-            return new_params, new_state, new_aux, outs, stats
+            return new_params, new_state, new_aux, \
+                _with_counters(outs, aux_upd), stats
 
         def step(params, opt_state, aux, batch, rng, hyper, t):
             return _step_core(False, params, opt_state, aux, batch, rng,
@@ -616,14 +665,16 @@ class TrainStep(object):
             # the loss surface crosses back in f32 (metrics, sentinels)
             outs = tuple(o.astype(jnp.float32) for o in outs)
             if not want_stats:
-                return new_params, new_state, new_aux, new_lsc, outs
+                return new_params, new_state, new_aux, new_lsc, \
+                    _with_counters(outs, aux_upd)
             # stats OUTSIDE the overflow cond: the scaled grads exist on
             # skip steps too (that step's inf IS the finding); the
             # squared sums unscale by inv**2 so published norms are in
             # unscaled units
             stats = self._monitor_stats(params, grads, new_params, outs,
                                         inv=inv)
-            return new_params, new_state, new_aux, new_lsc, outs, stats
+            return new_params, new_state, new_aux, new_lsc, \
+                _with_counters(outs, aux_upd), stats
 
         def step_amp(params, opt_state, aux, lsc, batch, rng, hyper, t):
             return _amp_core(False, params, opt_state, aux, lsc, batch,
@@ -929,10 +980,16 @@ class TrainStep(object):
                 else:
                     from .context import Context
                     ambient = getattr(Context._default_ctx, "value", None)
-                    dst = (ambient.jax_device() if ambient is not None
-                           else jax.devices()[0])
-        self._scale_state = {k: jax.device_put(v, dst)
-                             for k, v in host.items()}
+                    if ambient is not None:
+                        dst = ambient.jax_device()
+        # with nowhere named, the state goes to the default device
+        # UNCOMMITTED, as a caller's own jit-made parameters are: a
+        # committed scalar beside uncommitted parameters commits the
+        # step's outputs, and the second call then lowers and compiles
+        # the whole program again for committed inputs
+        self._scale_state = {
+            k: jax.device_put(v, dst) if dst is not None
+            else jax.numpy.asarray(v) for k, v in host.items()}
         return self._scale_state
 
     def _donate_pairs(self, args):
@@ -1096,14 +1153,15 @@ class TrainStep(object):
                             if stacked else batch
                         p, s, a, l, outs = step(p, s, a, l, b, sub, hyper,
                                                 t0 + i + 1)
-                        return (p, s, a, l), None
-                    (p, s, a, l), _ = jax.lax.scan(
+                        return (p, s, a, l), _split_counters(outs)[1]
+                    (p, s, a, l), counted = jax.lax.scan(
                         body, (params, opt_state, aux, lsc),
                         jax.numpy.arange(num_steps))
                     last = jax.tree_util.tree_map(
                         lambda x: x[num_steps], batch) if stacked else batch
-                    return step(p, s, a, l, last, rng, hyper,
-                                t0 + num_steps + 1)
+                    res = step(p, s, a, l, last, rng, hyper,
+                               t0 + num_steps + 1)
+                    return res[:4] + (_sum_counters(res[4], counted),)
             else:
                 def many(params, opt_state, aux, batch, rng, hyper, t0):
                     def body(carry, i):
@@ -1113,16 +1171,17 @@ class TrainStep(object):
                             if stacked else batch
                         p, s, a, outs = step(p, s, a, b, sub, hyper,
                                              t0 + i + 1)
-                        return (p, s, a), None
-                    (p, s, a), _ = jax.lax.scan(
+                        return (p, s, a), _split_counters(outs)[1]
+                    (p, s, a), counted = jax.lax.scan(
                         body, (params, opt_state, aux),
                         jax.numpy.arange(num_steps))
                     # one extra step emitting outputs (keeps scan carry
                     # lean)
                     last = jax.tree_util.tree_map(
                         lambda x: x[num_steps], batch) if stacked else batch
-                    return step(p, s, a, last, rng, hyper,
-                                t0 + num_steps + 1)
+                    res = step(p, s, a, last, rng, hyper,
+                               t0 + num_steps + 1)
+                    return res[:3] + (_sum_counters(res[3], counted),)
 
             many.__name__ = "mxtpu_many"
             if self.mesh is not None:
@@ -1175,8 +1234,8 @@ class TrainStep(object):
                               step=self.num_update)
         if self._has_scale:
             self._scale_state = res[3]
-            return res[0], res[1], res[2], res[4]
-        return res
+            res = (res[0], res[1], res[2], res[4])
+        return res[:3] + (_publish_counters(res[3], num_steps + 1),)
 
     def step_flops(self):
         """Model FLOPs of one fused step, from the cost row captured at
@@ -1395,6 +1454,8 @@ class TrainStep(object):
         if self._has_scale:
             self._scale_state = res[3]
             res = (res[0], res[1], res[2], res[4])
+        res = res[:3] + (_publish_counters(res[3], 1),)
+        if self._has_scale:
             if _tel._enabled and self._amp_emit \
                     and _tel.scalar_due(self.num_update):
                 # bounded telemetry sync: scale gauge + overflow counter

@@ -1,0 +1,219 @@
+"""The state-space mixer's operators against the plain reference
+(``benchmark/reference/hybrid_lm.py``: the recurrence over t, float32): the
+chunked scan over several chunks with the published initialisation's ranges,
+where the state carried between chunks matters; the causal convolution; the
+norms."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..",
+                                    ".."))
+sys.path.insert(0, ROOT)
+
+import mxnet_tpu as mx  # noqa: E402
+from mxnet_tpu.ops import ssm  # noqa: E402
+from mxnet_tpu.ops.registry import get_op  # noqa: E402
+from benchmark.reference import hybrid_lm as ref  # noqa: E402
+
+
+
+def _scan_inputs(seed, bsz, t, h, p, g, n):
+    """dt in 0.001-0.1 and A in 1-16, the published initialisation: a token
+    is still felt hundreds of steps later, so several chunks of 16 are tied
+    by the carried state."""
+    r = np.random.RandomState(seed)
+    x = r.randn(bsz, t, h, p).astype(np.float32)
+    dt = np.exp(r.uniform(np.log(1e-3), np.log(0.1), (bsz, t, h)))
+    a = -r.uniform(1.0, 16.0, (h,))
+    b = r.randn(bsz, t, g, n).astype(np.float32)
+    c = r.randn(bsz, t, g, n).astype(np.float32)
+    return [jnp.asarray(v, jnp.float32) for v in (x, dt, a, b, c)]
+
+
+def _recurrence(x, dt, a, b, c):
+    h, g = x.shape[2], b.shape[2]
+    b, c = (jnp.repeat(v, h // g, axis=2) for v in (b, c))
+    return jax.vmap(ref._recurrence, in_axes=(0, 0, None, 0, 0))(
+        x, dt, a, b, c)
+
+
+# T = 75 is 4 chunks of 16 and 11 steps of a fifth: the last chunk's edge
+# is padded, not hidden by a multiple
+@pytest.mark.parametrize("t,chunk", [(75, 16), (64, 16), (10, 16), (130, 64)])
+def test_chunked_scan_is_the_recurrence(t, chunk):
+    """Forward and every gradient.  Tolerance 2e-4 relative to the largest
+    entry: both sides are float32, and they differ by the order of up to T
+    additions and by exp(sum) against a product of exps."""
+    args = _scan_inputs(t, 2, t, 4, 8, 2, 8)
+    got = ssm.ssm_scan_chunked(*args, chunk=chunk)
+    want = _recurrence(*args)
+    np.testing.assert_allclose(got, want, atol=2e-4 * float(
+        jnp.abs(want).max()), rtol=0)
+    w = jnp.asarray(np.random.RandomState(1).randn(*want.shape), jnp.float32)
+    g_got = jax.grad(lambda *a: (ssm.ssm_scan_chunked(*a, chunk=chunk)
+                                 * w).sum(), argnums=(0, 1, 2, 3, 4))(*args)
+    g_want = jax.grad(lambda *a: (_recurrence(*a) * w).sum(),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+    for name, a, b in zip("x dt a b c".split(), g_got, g_want):
+        np.testing.assert_allclose(
+            a, b, atol=5e-4 * float(jnp.abs(b).max()), rtol=0, err_msg=name)
+
+
+def test_the_carried_state_matters_at_these_rates():
+    """The test above would pass with the carried state left out if decay
+    were fast: at dt <= 0.1, A <= 16 a chunk of 16 keeps at least
+    exp(-16 * 0.1 * 16) of its start, and most heads far more."""
+    args = _scan_inputs(3, 1, 64, 4, 8, 2, 8)
+    whole = ssm.ssm_scan_chunked(*args, chunk=16)
+    alone = ssm.ssm_scan_chunked(*[v[:, 48:] if v.ndim > 1 else v
+                                   for v in args], chunk=16)
+    gap = jnp.abs(whole[:, 48:] - alone).max() / jnp.abs(whole).max()
+    assert gap > 0.05
+
+
+def test_ssm_scan_op_adds_the_step_the_decay_and_the_skip():
+    r = np.random.RandomState(5)
+    bsz, t, h, p, g, n = 2, 40, 4, 8, 2, 8
+    x, dt, a, b, c = _scan_inputs(5, bsz, t, h, p, g, n)
+    raw = jnp.asarray(r.randn(bsz, t, h), jnp.float32)
+    a_log = jnp.log(-a)
+    d = jnp.asarray(r.randn(h), jnp.float32)
+    dt_bias = jnp.asarray(r.randn(h) - 3.0, jnp.float32)
+    op = get_op("ssm_scan")
+    xbc = jnp.concatenate([x.reshape(bsz, t, h * p), b.reshape(bsz, t, g * n),
+                           c.reshape(bsz, t, g * n)], axis=-1)
+    y = op.fn(xbc, raw, a_log, d, dt_bias, num_heads=h, head_dim=p,
+              num_groups=g, chunk_size=16)
+    step = jax.nn.softplus(raw + dt_bias)
+    want = _recurrence(x, step, a, b, c) + d[:, None] * x
+    np.testing.assert_allclose(y.reshape(want.shape), want, atol=2e-4 * float(
+        jnp.abs(want).max()), rtol=0)
+    assert op.f32_inputs == ("a_log", "d", "dt_bias")
+
+
+def test_ssm_scan_in_bfloat16_keeps_decay_and_state_in_float32():
+    """bfloat16 operands, float32 decays and state: against the float32
+    recurrence the gap is the operands' rounding (2**-8 each, three
+    operands), not that of a bfloat16 running sum over 75 steps."""
+    x, dt, a, b, c = _scan_inputs(7, 1, 75, 4, 8, 2, 8)
+    got = ssm.ssm_scan_chunked(x.astype(jnp.bfloat16), dt, a,
+                               b.astype(jnp.bfloat16),
+                               c.astype(jnp.bfloat16), chunk=16)
+    assert got.dtype == jnp.float32
+    want = _recurrence(x, dt, a, b, c)
+    assert float(jnp.abs(got - want).max() / jnp.abs(want).max()) < 0.03
+
+
+@pytest.mark.parametrize("k", [4, 3])
+def test_causal_conv1d_is_the_shifted_sum(k):
+    r = np.random.RandomState(0)
+    x = r.randn(2, 9, 6).astype(np.float32)
+    w = r.randn(6, k).astype(np.float32)
+    b = r.randn(6).astype(np.float32)
+    args = [mx.nd.array(x), mx.nd.array(w), mx.nd.array(b)]
+    y = mx.nd.causal_conv1d(*args, kernel=k).asnumpy()
+    want = np.zeros_like(x) + b
+    for t in range(9):
+        for j in range(k):
+            src = t - (k - 1) + j
+            if src >= 0:
+                want[:, t] += w[:, j] * x[:, src]
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    # causal: the output at t does not move with the input after t
+    x2 = x.copy()
+    x2[:, 5:] += 1.0
+    y2 = mx.nd.causal_conv1d(mx.nd.array(x2), *args[1:], kernel=k).asnumpy()
+    np.testing.assert_array_equal(y[:, :5], y2[:, :5])
+    # the fused activation is the activation of the sum
+    y3 = mx.nd.causal_conv1d(*args, kernel=k, act_type="silu").asnumpy()
+    np.testing.assert_allclose(y3, want / (1 + np.exp(-want)), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_causal_conv1d_infers_its_leaves():
+    s = mx.sym.causal_conv1d(mx.sym.Variable("data"), kernel=4, name="c")
+    shapes, outs, _ = s.infer_shape(data=(2, 9, 6))
+    assert dict(zip(s.list_arguments(), shapes)) == {
+        "data": (2, 9, 6), "c_weight": (6, 4), "c_bias": (6,)}
+    assert outs == [(2, 9, 6)]
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+def test_rms_norm_plain_and_over_groups(groups):
+    """Float32 statistics: exact to rounding (1e-5)."""
+    r = np.random.RandomState(0)
+    x = r.randn(5, 16).astype(np.float32) * 3
+    g = r.randn(16).astype(np.float32)
+    y = mx.nd.RMSNorm(mx.nd.array(x), mx.nd.array(g), eps=1e-5,
+                      num_groups=groups).asnumpy()
+    xg = x.reshape(5, groups, 16 // groups)
+    want = (xg / np.sqrt((xg ** 2).mean(-1, keepdims=True) + 1e-5)
+            ).reshape(5, 16) * g
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        y, ref._rms(jnp.asarray(x), jnp.asarray(g), 1e-5, groups),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_gated_rms_norm_gates_first():
+    r = np.random.RandomState(2)
+    x = r.randn(5, 16).astype(np.float32)
+    z = r.randn(5, 16).astype(np.float32)
+    g = r.randn(16).astype(np.float32)
+    y = mx.nd.RMSNorm(mx.nd.array(x), mx.nd.array(g), mx.nd.array(z),
+                      gated=True, num_groups=4).asnumpy()
+    gated = x * z / (1 + np.exp(-z))
+    want = np.asarray(ref._rms(jnp.asarray(gated), jnp.asarray(g), 1e-5, 4))
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    s = mx.sym.RMSNorm(mx.sym.Variable("data"), gate=mx.sym.Variable("z"),
+                       gated=True, name="n")
+    shapes, _, _ = s.infer_shape(data=(5, 16))
+    assert dict(zip(s.list_arguments(), shapes)) == {
+        "data": (5, 16), "n_gamma": (16,), "z": (5, 16)}
+
+
+def test_rms_norm_in_bfloat16_takes_its_statistics_in_float32():
+    x = jnp.full((2, 4096), 3.0, jnp.bfloat16)      # sum of squares 36,864
+    y = get_op("RMSNorm").fn(x, jnp.ones((4096,), jnp.bfloat16))
+    assert y.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(y, np.float32), 1.0, atol=1e-2)
+
+
+def test_layer_norm_by_hand():
+    """ROADMAP noted LayerNorm had no test of its own."""
+    r = np.random.RandomState(1)
+    x = r.randn(4, 12).astype(np.float32) * 2 + 1
+    g, b = r.randn(12).astype(np.float32), r.randn(12).astype(np.float32)
+    y = mx.nd.LayerNorm(mx.nd.array(x), mx.nd.array(g), mx.nd.array(b),
+                        eps=1e-5).asnumpy()
+    mu = x.mean(-1, keepdims=True)
+    want = (x - mu) / np.sqrt(x.var(-1, keepdims=True) + 1e-5) * g + b
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    s = mx.sym.LayerNorm(mx.sym.Variable("data"), name="ln")
+    shapes, _, _ = s.infer_shape(data=(4, 12))
+    assert shapes == [(4, 12), (12,), (12,)]
+
+
+@pytest.mark.parametrize("act,fn", [
+    ("silu", lambda x: x / (1 + np.exp(-x))),
+    ("relu2", lambda x: np.maximum(x, 0) ** 2)])
+def test_the_new_activations(act, fn):
+    x = np.linspace(-3, 3, 13).astype(np.float32)
+    y = mx.nd.Activation(mx.nd.array(x), act_type=act).asnumpy()
+    np.testing.assert_allclose(y, fn(x), rtol=1e-5, atol=1e-6)
+
+
+def test_the_ssm_initialisers_draw_the_published_ranges():
+    a_log = mx.nd.zeros((512,))
+    mx.init.LogOfUniform(1, 16)._init_weight("a", a_log)
+    a = np.exp(a_log.asnumpy())
+    assert a.min() >= 1 and a.max() <= 16 and a.std() > 3
+    bias = mx.nd.zeros((512,))
+    mx.init.InverseSoftplusLogUniform(0.001, 0.1)._init_weight("b", bias)
+    dt = np.log1p(np.exp(bias.asnumpy()))
+    assert dt.min() >= 0.00099 and dt.max() <= 0.101
