@@ -10,22 +10,43 @@ What absent experts would add is left out (on several chips the exchange
 brings it; nothing here stands in for them).  With ``experts_held ==
 num_experts`` it is the whole layer.
 
-No token is dropped.  The assignments that land here are sorted by expert,
-each expert's run is padded to whole blocks of 256 rows, and the blocks are
-worked one after another, each on its own expert's matrices: the work
-follows the number of tokens routed here, not tokens x experts held, and not
-how evenly they spread over the held experts.  The rows set aside are four
-times the mean number that lands here: a chip's share of a router that
-nothing has balanced yet (random weights, the first steps of training) is
-anything between a quarter and three times the mean, and a step inside the
-room takes the same time wherever in it.  A step that lands more takes, by
-``lax.cond`` on the counted load alone, the one other path, which has rows
-for every assignment there can be.
+No token is dropped.  The assignments that land here are sorted by expert
+and each expert's run is padded to whole blocks of 256 rows.  The layer's
+two products are grouped products over those rows (``grouped_matmul``: row
+``i`` meets its own expert's matrix; operands in the input's dtype, float32
+accumulation, the activation on the accumulator), and the matrices'
+gradients are transposed grouped products (``grouped_matmul_t``, summed in
+float32 over an expert's rows and written once): two implementations of one
+signature, the Pallas kernels ``mxtpu_gmm`` / ``mxtpu_tgmm`` on a TPU for
+shapes their guard takes and ``jax.lax.ragged_dot_general`` elsewhere,
+chosen by backend and shape as ``dot_product_attention`` chooses flash.
+The kernels' grid is the counted number of blocks, an expert's matrix is
+fetched once for its run of blocks, and a block whose upper half holds
+nothing (a run's last block, half the time) is worked as its lower half:
+the products' work follows the number of tokens routed here (each held
+expert's run rounded up to half blocks, half a block for an expert with
+none), not tokens x experts held, and not the rows set aside.  One
+``custom_vjp`` is round the routed part: its backward keeps the op's
+inputs, forms the sorted rows and the up product once more, and needs the
+down product not at all.
+
+The rows set aside are four times the mean number that lands here: a
+chip's share of a router that nothing has balanced yet (random weights, the
+first steps of training) is anything between a quarter and three times the
+mean.  The room is no longer the work: it is the size of the row gather
+into the sorted layout, of the weighted scatter-add out of it and of the
+backward's elementwise passes, which are over all of its rows whatever
+landed, and the bound of ``lax.cond``: a step that lands more takes, on
+the counted load alone, the one other path, which walks the held experts
+one at a time with rows for every token, so for every assignment there can
+be.  What a kernel leaves in the rows it did not work is not zero; it is
+masked before a weight multiplies it.
 
 Counters, handed to ``telemetry.device_counter`` (accumulated on the device,
-fetched by nobody inside a step): assignments that landed on held experts,
-the fullest held expert's tokens, assignments to absent experts, and landed
-assignments that no block computed (must read 0).
+fetched by nobody inside a step): ``moe``: assignments that landed on held
+experts, the fullest held expert's tokens, assignments to absent experts,
+and landed assignments that no block computed (must read 0); ``moe_rows``:
+the rows the products ran over (the blocks visited, whole or half).
 """
 from __future__ import annotations
 
@@ -35,6 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry as _tel
+from . import pallas_kernels
 from .nn import ACTIVATIONS
 from .registry import register, parse_float, parse_int, parse_str
 
@@ -96,7 +118,8 @@ def capacity(tokens, top_k, num_experts, held):
     be): ``_ROOM`` times the mean number of assignments that land on
     ``held`` experts, and all of them (an expert is chosen at most once a
     token), each in whole blocks of ``_BLOCK`` rows (fewer at a tiny size)
-    with room for every held expert's last block to be part empty."""
+    with room for every held expert's last block to be part empty.  Where
+    the rows set aside are already all, there is no other path."""
     per_expert = tokens * top_k / float(num_experts)
     block = _BLOCK if 2 * per_expert >= _BLOCK \
         else _round_up(max(per_expert, 1), 8)
@@ -106,16 +129,21 @@ def capacity(tokens, top_k, num_experts, held):
         _round_up(most, block) + held * block
 
 
-def _grouped(data, flat_w, order, counts, up, down, rows_cap, block, act,
-             top_k):
+def _blocks_of(counts, block):
+    """Each held expert's run in whole blocks, one block for an expert with
+    no rows (the weights' gradient is written from it: zeros)."""
+    return jnp.maximum(-(-counts // block), 1)
+
+
+def _layout(order, counts, rows_cap, block):
     """The assignments that landed here, sorted by expert (``order``: the
     held experts' first), each expert's run padded to whole blocks of
-    ``block`` rows; one block after another, each on its own expert's two
-    matrices.  ``rows_cap`` rows hold them all (the caller sees to it).
-    Returns (out (N, C) float32, rows computed)."""
-    f32 = jnp.float32
-    held = up.shape[0]
-    padded = -(-counts // block) * block
+    ``block`` rows, in ``rows_cap`` rows that hold them all (the caller
+    sees to it).  Returns (the assignment of every row, whether the row
+    holds one, (the expert of every block, the leading rows of every block
+    that hold an assignment), the blocks that hold the runs)."""
+    held = counts.shape[0]
+    padded = _blocks_of(counts, block) * block
     ends = jnp.cumsum(padded)                    # in the padded layout
     starts = jnp.cumsum(counts) - counts         # in the sorted order
     pos = jnp.arange(rows_cap)
@@ -124,44 +152,203 @@ def _grouped(data, flat_w, order, counts, up, down, rows_cap, block, act,
     within = pos - (ends - padded)[expert]
     valid = (within < counts[expert]) & (pos < ends[-1])
     rows = order[jnp.where(valid, starts[expert] + within, 0)]
+    live = jnp.minimum(ends[-1] // block, rows_cap // block)
+    fill = valid.reshape(-1, block).sum(axis=1)
+    return rows, valid, (expert[::block].astype(jnp.int32),
+                         fill.astype(jnp.int32)), live.astype(jnp.int32)
+
+
+def _group_rows(x, tile_group, live, groups):
+    """The rows of every group among the first ``live`` blocks."""
+    blocks = tile_group.shape[0]
+    mine = (tile_group[None, :] == jnp.arange(groups)[:, None]) \
+        & (jnp.arange(blocks) < live)[None, :]
+    return (x.shape[0] // blocks) * mine.sum(axis=1).astype(jnp.int32)
+
+
+def grouped_matmul(x, w, tile_group, tile_fill, live, transpose_rhs=False,
+                   act=None, out_dtype=None):
+    """``pallas_kernels.grouped_matmul`` in plain ``jax.lax``: row ``i`` of
+    x (rows, k) times the matrix of its block's group, w (groups, k, n) or
+    (groups, n, k); f32 accumulation, ``act`` before the cast.  Every live
+    block is worked whole, whatever ``tile_fill``."""
+    del tile_fill
+    dims = jax.lax.RaggedDotDimensionNumbers(
+        (([1], [2 if transpose_rhs else 1]), ([], [])), [0], [0])
+    acc = jax.lax.ragged_dot_general(
+        x, w, _group_rows(x, tile_group, live, w.shape[0]), dims,
+        preferred_element_type=jnp.float32)
+    return (acc if act is None else act(acc)).astype(out_dtype or x.dtype)
+
+
+def grouped_matmul_t(lhs, rhs, tile_group, tile_fill, live, groups,
+                     out_dtype=None):
+    """``pallas_kernels.grouped_matmul_t`` in plain ``jax.lax``: every
+    group's ``lhs_g.T @ rhs_g``, (rows, k) and (rows, n) -> (groups, k,
+    n), summed in f32."""
+    del tile_fill
+    dims = jax.lax.RaggedDotDimensionNumbers((([0], [0]), ([], [])), [0], [])
+    acc = jax.lax.ragged_dot_general(
+        lhs, rhs, _group_rows(lhs, tile_group, live, groups), dims,
+        preferred_element_type=jnp.float32)
+    return acc.astype(out_dtype or lhs.dtype)
+
+
+def _products(block, data, up):
+    """(grouped product, transposed grouped product, the rows they work a
+    part-empty block in): the Pallas kernels on a TPU for shapes their
+    guard takes, which work a block whose upper half holds nothing as its
+    lower half; else the plain forms above, which work blocks whole."""
+    if jax.default_backend() == "tpu" and pallas_kernels.grouped_available(
+            block, up.shape[2], up.shape[1], data.dtype.itemsize):
+        return pallas_kernels.grouped_matmul, \
+            pallas_kernels.grouped_matmul_t, block // 2
+    return grouped_matmul, grouped_matmul_t, block
+
+
+def _worked(fill, live, unit):
+    """Rows the products run over: every live block in whole ``unit``s,
+    one at least (an expert with no rows)."""
+    units = jnp.maximum(-(-fill // unit), 1) * unit
+    return jnp.where(jnp.arange(fill.shape[0]) < live, units, 0).sum()
+
+
+def _forward(data, flat_w, order, counts, up, down, rows_cap, block, act,
+             top_k):
+    """What the experts of ``up`` and ``down`` add: ((out (N, C) float32,
+    rows that hold an assignment, rows the products ran over), ())."""
+    f32 = jnp.float32
+    rows, valid, tiles, live = _layout(order, counts, rows_cap, block)
+    gmm, _, unit = _products(block, data, up)
     token = rows // top_k
-    weight = jnp.where(valid, flat_w[rows], 0.0)
-
-    @jax.checkpoint
-    def one(args):
-        x, e = args                              # (block, C), its expert
-        hid = jnp.dot(x, up[e].T, preferred_element_type=f32)
-        hid = act(hid).astype(x.dtype)
-        return jnp.dot(hid, down[e].T,
-                       preferred_element_type=f32).astype(x.dtype)
-    y = jax.lax.map(one, (data[token].reshape(-1, block, data.shape[1]),
-                          expert[::block]))
-    y = y.reshape(rows_cap, -1).astype(f32) * weight[:, None]
+    hid = gmm(data[token], up, *tiles, live, transpose_rhs=True, act=act)
+    y = gmm(hid, down, *tiles, live, transpose_rhs=True)
+    # rows past the last that hold something were not written: not zero
+    y = jnp.where(valid[:, None], y.astype(f32) * flat_w[rows][:, None], 0.0)
     out = jnp.zeros(data.shape, f32).at[token].add(y)
-    return out, valid.sum()
+    return (out, valid.sum(), _worked(tiles[1], live, unit)), ()
 
 
-def _routed(data, indices, weights, up, down, first, num_experts, act):
-    """(this chip's part of the routed result (N, C) in data's dtype,
-    float32[4] counters)."""
+def _backward(data, flat_w, order, counts, up, down, d_out, rows_cap, block,
+              act, top_k):
+    """((gradient of data (N, C) float32, of the flat weights), (of up, of
+    down)): the sorted rows and the up product formed once more, the down
+    product not at all (a row's weight meets ``<hid, d_out down>``, which
+    is ``<y, d_out>``)."""
+    f32 = jnp.float32
+    rows, valid, tiles, live = _layout(order, counts, rows_cap, block)
+    gmm, tgmm, _ = _products(block, data, up)
+    token = rows // top_k
+    keep = valid[:, None]
+    weight = flat_w[rows][:, None]
+    x, g = data[token], d_out[token]
+    hid, act_vjp = jax.vjp(act, gmm(x, up, *tiles, live, transpose_rhs=True,
+                                    out_dtype=f32))
+    d_hid = gmm(g, down, *tiles, live, out_dtype=f32)   # before the weight
+    d_weight = jnp.where(valid, (hid * d_hid).sum(axis=1), 0.0)
+    d_pre = jnp.where(keep, act_vjp(d_hid * weight)[0], 0.0).astype(x.dtype)
+    weighted = jnp.where(keep, hid * weight, 0.0).astype(x.dtype)
+    d_up = tgmm(d_pre, x, *tiles, live, up.shape[0], out_dtype=up.dtype)
+    d_down = tgmm(g, weighted, *tiles, live, up.shape[0],
+                  out_dtype=down.dtype)
+    d_x = jnp.where(keep, gmm(d_pre, up, *tiles, live).astype(f32), 0.0)
+    d_data = jnp.zeros(data.shape, f32).at[token].add(d_x)
+    d_flat = jnp.zeros(flat_w.shape, f32).at[rows].add(d_weight)
+    return (d_data, d_flat), (d_up, d_down)
+
+
+def _expert_by_expert(arm, tokens, block):
+    """``arm`` over the held experts one at a time, each with rows for
+    every token: the path of a step that lands more than the rows set
+    aside hold.  Its arrays are an expert's, never a row for every
+    assignment there can be at once; what the experts add is summed, their
+    own gradients are stacked."""
+    rows_cap = _round_up(tokens, block) + block
+
+    def path(data, flat_w, order, counts, up, down, *rest):
+        starts = jnp.cumsum(counts) - counts
+        last = order.shape[0] - 1
+
+        def one(e):
+            mine = order[jnp.minimum(starts[e] + jnp.arange(rows_cap), last)]
+            return arm(data, flat_w, mine, counts[e][None], up[e][None],
+                       down[e][None], *rest, rows_cap=rows_cap)
+
+        def step(sums, e):
+            # the barrier keeps a kernel's result out of the fusion that
+            # stacks it, where the kernel's own VMEM limit does not reach
+            part, own = jax.lax.optimization_barrier(one(e))
+            return jax.tree_util.tree_map(jnp.add, sums, part), own
+        zeros = jax.tree_util.tree_map(
+            lambda x: jnp.zeros(x.shape, x.dtype), jax.eval_shape(one, 0)[0])
+        sums, own = jax.lax.scan(step, zeros, jnp.arange(counts.shape[0]))
+        return sums, jax.tree_util.tree_map(lambda x: x[:, 0], own)
+    return path
+
+
+def _either(arm, indices, first, num_experts, act, held):
+    """``arm`` with the rows set aside or, where more land than they hold,
+    by ``lax.cond`` on the counted load alone, expert by expert with rows
+    for every assignment there can be: (what to call with the arm's
+    remaining arguments, the held experts' counts)."""
     n, k = indices.shape
-    held = up.shape[0]
     local = indices - first
     here = (local >= 0) & (local < held)
     key = jnp.where(here, local, held).reshape(-1)
     counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0)
+    order = jnp.argsort(key, stable=True)
     block, aside, most = capacity(n, k, num_experts, held)
-    needed = (-(-counts // block) * block).sum()
-    paths = [functools.partial(_grouped, rows_cap=cap, block=block, act=act,
-                               top_k=k) for cap in (aside, most)]
-    args = (data, weights.reshape(-1).astype(jnp.float32),
-            jnp.argsort(key, stable=True), counts, up, down)
-    out, computed = paths[0](*args) if aside == most \
-        else jax.lax.cond(needed <= aside, *paths, *args)
+    arm = functools.partial(arm, block=block, act=act, top_k=k)
+    room = functools.partial(arm, rows_cap=aside)
+
+    def call(data, flat_w, *rest):
+        args = (data, flat_w, order, counts) + rest
+        if aside == most:
+            return room(*args)
+        needed = _blocks_of(counts, block).sum() * block
+        return jax.lax.cond(needed <= aside, room,
+                            _expert_by_expert(arm, n, block), *args)
+    return call, counts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _routed(data, indices, weights, up, down, first, num_experts, act):
+    """(this chip's part of the routed result (N, C) in data's dtype,
+    float32[4] counters, float32 rows the products ran over).  Its backward
+    keeps these inputs and nothing else."""
+    return _routed_fwd(data, indices, weights, up, down, first, num_experts,
+                       act)[0]
+
+
+def _routed_fwd(data, indices, weights, up, down, first, num_experts, act):
+    n, k = indices.shape
+    call, counts = _either(_forward, indices, first, num_experts, act,
+                           up.shape[0])
+    (out, computed, worked), _ = call(
+        data, weights.reshape(-1).astype(jnp.float32), up, down)
     landed = counts.sum()
     stats = jnp.stack([landed, counts.max(), n * k - landed,
                        landed - computed]).astype(jnp.float32)
-    return out.astype(data.dtype), stats
+    return (out.astype(data.dtype), stats, worked.astype(jnp.float32)), \
+        (data, indices, weights, up, down)
+
+
+def _routed_bwd(first, num_experts, act, kept, cotangents):
+    data, indices, weights, up, down = kept
+    call, _ = _either(_backward, indices, first, num_experts, act,
+                      up.shape[0])
+    (d_data, d_flat), own = call(
+        data, weights.reshape(-1).astype(jnp.float32), up, down,
+        cotangents[0])
+    # the matrices' gradients leave the ``cond`` as they are: without the
+    # barrier the compiler moves the optimizer's float32 casts of them into
+    # its branches, and every expert layer's stay alive at twice the size
+    d_up, d_down = jax.lax.optimization_barrier(own)
+    return d_data.astype(data.dtype), None, d_flat.reshape(
+        weights.shape).astype(weights.dtype), d_up, d_down
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 def _experts_infer(attrs, in_shapes):
@@ -195,9 +382,9 @@ def _moe_experts(data, indices, weights, up_weight, down_weight,
     and weights (N, k) from ``moe_router``, over all ``num_experts``;
     up_weight (held, F, C), down_weight (held, C, F); expert ``e`` is
     ``down_e(act(up_e u))``, no bias.  Returns (N, C)."""
-    core = jax.checkpoint(functools.partial(
-        _routed, first=int(first_expert), num_experts=int(num_experts),
-        act=ACTIVATIONS[act_type]))
-    out, stats = core(data, indices, weights, up_weight, down_weight)
+    out, stats, worked = _routed(
+        data, indices, weights, up_weight, down_weight, int(first_expert),
+        int(num_experts), ACTIVATIONS[act_type])
     _tel.device_counter("moe", jax.lax.stop_gradient(stats))
+    _tel.device_counter("moe_rows", jax.lax.stop_gradient(worked))
     return out

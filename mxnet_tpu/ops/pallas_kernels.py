@@ -44,7 +44,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_available", "flash_blocks"]
+__all__ = ["flash_attention", "flash_available", "flash_blocks",
+           "grouped_matmul", "grouped_matmul_t", "grouped_available",
+           "grouped_blocks"]
 
 _NEG_INF = -1e30
 
@@ -456,3 +458,184 @@ def _flash_bwd_xla(causal, scale, block_q, block_k, interpret, res, g):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+# ---------------------------------------------------------- grouped products
+# Rows sorted by group (a routed layer's assignments by expert), each
+# group's run padded to whole blocks of rows: block ``i`` belongs to group
+# ``tile_group[i]``, groups ascending, and the first ``live`` blocks hold
+# every run.  ``live`` is a traced scalar and is the grid's extent, so a
+# block past it costs no product and no fetch; what the result holds there
+# was never written (it is not zero: the caller masks it).  A group's matrix
+# is one block of the pipeline whose index does not change along the group's
+# run: it is fetched once a run, not once a block.  ``tile_fill[i]`` is the
+# number of leading rows of block ``i`` that hold something: a block whose
+# upper half holds nothing (a run's last block, half the time) is worked as
+# its lower half alone, and the rows past it are not written either.
+
+def _lane_tile(n, fits):
+    """The tile of a lane dimension ``n``: all of it where ``fits(tile)``,
+    else the multiple of 128 that fits and covers ``n`` in the fewest
+    columns (the last tile may hang over the edge; of two that cover alike,
+    the larger); None where nothing fits."""
+    ok = [t for t in [n] + [t for t in range(1024, 0, -128) if t < n]
+          if fits(t)]
+    return min(ok, key=lambda t: (-(-n // t) * t, -t)) if ok else None
+
+
+def _gmm_vmem(block, k, tn, itemsize):
+    """One grid step of ``mxtpu_gmm``: the pipeline's two buffers of the
+    rows' block, of the group's matrix tile and of the result tile (f32 at
+    most), the f32 accumulator and one temporary of the epilogue."""
+    return 2 * (block * k + tn * k) * itemsize + 4 * block * tn * 4
+
+
+def _tgmm_vmem(block, k, tn, itemsize):
+    """One grid step of ``mxtpu_tgmm``: two buffers of both row blocks and
+    of the result tile, the f32 accumulator and the product added to it."""
+    return 2 * (block * (k + tn) + k * tn) * itemsize + 2 * k * tn * 4
+
+
+def grouped_blocks(block, k, n, itemsize):
+    """``(tile of n in grouped_matmul, tile of n in grouped_matmul_t)`` for
+    rows in blocks of ``block`` and a group's (k, n) matrix, operands of
+    ``itemsize`` bytes; either is None where no tile fits the VMEM budget.
+    ``k`` is never cut: the product contracts all of it and the transposed
+    product keeps it whole in the result, so a width that is no multiple of
+    128 lanes needs no mask."""
+    return (_lane_tile(n, lambda t: _gmm_vmem(block, k, t, itemsize)
+                       <= _VMEM_BUDGET),
+            _lane_tile(n, lambda t: _tgmm_vmem(block, k, t, itemsize)
+                       <= _VMEM_BUDGET))
+
+
+def grouped_available(block, c, f, itemsize):
+    """Shape guard of a routed layer's products, rows in blocks of
+    ``block`` through matrices of (c, f) and (f, c): the block a whole
+    number of 128-row tiles (it is the contraction of the transposed
+    product), and a tile for every product within the VMEM budget."""
+    return block % 128 == 0 and None not in (
+        grouped_blocks(block, c, f, itemsize)
+        + grouped_blocks(block, f, c, itemsize))
+
+
+# the budget the tiles are chosen by, and room for what the estimate leaves
+# out (the compiler's default of 16 MiB does not hold a 10 MB matrix twice)
+_GROUPED = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_BUDGET + 16 * 1024 * 1024)
+
+
+def _whole_or_half(fill_ref, block, work):
+    """``work(rows)`` on the whole block, or on its lower half where the
+    upper half holds nothing."""
+    fill = fill_ref[pl.program_id(1)]
+    pl.when(fill > block // 2)(lambda: work(block))
+    pl.when(fill <= block // 2)(lambda: work(block // 2))
+
+
+def _gmm_kernel(group_ref, fill_ref, x_ref, w_ref, o_ref, *, dims, act):
+    del group_ref                                   # the index maps' alone
+
+    def product(rows):
+        acc = jax.lax.dot_general(x_ref[:rows, :], w_ref[...], dims,
+                                  preferred_element_type=jnp.float32)
+        o_ref[:rows, :] = (acc if act is None else act(acc)).astype(
+            o_ref.dtype)
+    _whole_or_half(fill_ref, x_ref.shape[0], product)
+
+
+def grouped_matmul(x, w, tile_group, tile_fill, live, transpose_rhs=False,
+                   act=None, out_dtype=None, block_n=None, interpret=False):
+    """Row ``i`` of ``x`` (rows, k) times the matrix of the group its block
+    belongs to: ``w`` (groups, k, n), or (groups, n, k) with
+    ``transpose_rhs``.  Operands as they come, f32 accumulation, ``act`` on
+    the f32 accumulator before the cast to ``out_dtype`` (x's by default).
+    Returns (rows, n); the blocks from ``live`` on, and the upper half of a
+    block that ``tile_fill`` says holds nothing there, are not written."""
+    rows, k = x.shape
+    nb = tile_group.shape[0]
+    block = rows // nb
+    n = w.shape[1] if transpose_rhs else w.shape[2]
+    tn = block_n or grouped_blocks(block, k, n, x.dtype.itemsize)[0]
+    if tn is None:
+        raise ValueError("grouped_matmul: no tiling for blocks of %d rows, "
+                         "k=%d, n=%d (see grouped_available)" % (block, k, n))
+    if transpose_rhs:
+        w_spec = pl.BlockSpec((None, tn, k), lambda j, i, g, f: (g[i], j, 0))
+    else:
+        w_spec = pl.BlockSpec((None, k, tn), lambda j, i, g, f: (g[i], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, dims=_NT if transpose_rhs else _NN,
+                          act=act),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(n, tn), live),
+            in_specs=[pl.BlockSpec((block, k), lambda j, i, g, f: (i, 0)),
+                      w_spec],
+            out_specs=pl.BlockSpec((block, tn),
+                                   lambda j, i, g, f: (i, j))),
+        out_shape=jax.ShapeDtypeStruct((rows, n), out_dtype or x.dtype),
+        compiler_params=_GROUPED,
+        interpret=interpret,
+        name="mxtpu_gmm",
+    )(tile_group, tile_fill, x, w)
+
+
+def _tgmm_kernel(group_ref, fill_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
+                 blocks):
+    i, last = pl.program_id(1), pl.num_programs(1) - 1
+    group = group_ref[i]
+    opens = (i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != group)
+    closes = (i == last) | (group_ref[jnp.minimum(i + 1, blocks - 1)]
+                            != group)
+
+    @pl.when(opens)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def add(rows):
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[:rows, :], rhs_ref[:rows, :], _TN,
+            preferred_element_type=jnp.float32)
+    _whole_or_half(fill_ref, lhs_ref.shape[0], add)
+
+    @pl.when(closes)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def grouped_matmul_t(lhs, rhs, tile_group, tile_fill, live, groups,
+                     out_dtype=None, block_n=None, interpret=False):
+    """Every group's ``lhs_g.T @ rhs_g`` over the group's own rows: lhs
+    (rows, k), rhs (rows, n) -> (groups, k, n).  Summed in an f32 VMEM tile
+    over the group's run of blocks and written once, in ``out_dtype``
+    (lhs's by default).  A group that owns none of the first ``live``
+    blocks is not written: the caller gives every group a block.  The
+    upper half of a block that ``tile_fill`` says holds nothing there is
+    left out of the sum (the caller has zeros there)."""
+    rows, k = lhs.shape
+    n = rhs.shape[1]
+    nb = tile_group.shape[0]
+    block = rows // nb
+    tn = block_n or grouped_blocks(block, k, n, lhs.dtype.itemsize)[1]
+    if tn is None:
+        raise ValueError("grouped_matmul_t: no tiling for blocks of %d rows,"
+                         " k=%d, n=%d (see grouped_available)"
+                         % (block, k, n))
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, blocks=nb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(n, tn), live),
+            in_specs=[pl.BlockSpec((block, k), lambda j, i, g, f: (i, 0)),
+                      pl.BlockSpec((block, tn), lambda j, i, g, f: (i, j))],
+            out_specs=pl.BlockSpec((None, k, tn),
+                                   lambda j, i, g, f: (g[i], 0, j)),
+            scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n),
+                                       out_dtype or lhs.dtype),
+        compiler_params=_GROUPED,
+        interpret=interpret,
+        name="mxtpu_tgmm",
+    )(tile_group, tile_fill, lhs, rhs)

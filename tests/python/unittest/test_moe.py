@@ -3,6 +3,7 @@
 and factor; the expert layer with all experts held; the shares of an
 expert-parallel layout adding up to the uncut layer; no token dropped under
 the worst imbalance; the counters."""
+import functools
 import os
 import sys
 
@@ -184,18 +185,225 @@ def test_the_capacity():
     assert moe.capacity(8, 2, 4, 4) == (8, 16 + 32, 16 + 32)
 
 
+def _routed_counts(p, held):
+    """What the router sends each of the first ``held`` experts."""
+    idx, _ = get_op("moe_router").fn(
+        p["u"], p["l_router_weight"], p["l_router_bias"],
+        num_experts=p["up"].shape[0], top_k=K, scale=2.5)
+    return np.bincount(np.asarray(idx).ravel())[:held]
+
+
 def test_the_work_follows_the_tokens_routed_here():
-    """The products run over blocks of 256 rows, one expert's each, that in
-    all hold the assignments landing here, not over every token for every
-    held expert: read off the jaxpr's dot shapes and trip counts."""
+    """The products run over the blocks of 256 rows that hold the landed
+    assignments, each held expert's run rounded up to whole blocks (one
+    block for an expert with none), not over the room set aside and not
+    over every token for every held expert: the ``moe_rows`` counter."""
     p = _layer(5, n=2048, e=64)
-    text = str(jax.make_jaxpr(lambda q: _program(q, held=4, first=0))(p))
     block, aside, most = moe.capacity(2048, K, 64, 4)
     assert (block, aside, most) == (256, 2048 + 1024, 8192 + 1024)
-    assert "f32[256,%d] = dot_general" % F in text
-    assert "f32[2048,%d] = dot_general" % F not in text
-    assert "length=%d" % (aside // 256) in text \
-        and "length=%d" % (most // 256) in text
+    with telemetry.collect_device_counters() as bag:
+        _program(p, held=4, first=0)
+    counted = bag.stacked()
+    counts = _routed_counts(p, 4)
+    landed, _, _, dropped = np.asarray(counted["moe"][0])
+    assert landed == counts.sum() and dropped == 0
+    want = (np.maximum(-(-counts // 256), 1) * 256).sum()
+    assert counted["moe_rows"].shape == (1,)
+    assert float(counted["moe_rows"][0]) == want
+    assert landed <= want < landed + 4 * 256 and want < aside
+    # nothing landing: one block an expert, of rows that hold nothing
+    p["l_router_bias"] = jnp.zeros(64).at[jnp.arange(40, 44)].set(10.0)
+    with telemetry.collect_device_counters() as bag:
+        out = _program(p, held=4, first=0)
+    assert float(bag.stacked()["moe_rows"][0]) == 4 * 256
+    assert not np.asarray(out).any()
+
+
+# ---------------------------------------------------- the grouped products
+def _rowwise(data, idx, w, up, down, first):
+    """Every assignment on its own expert's matrices, ``W[e(i)]`` gathered
+    row by row: what the grouped products have to give, and by autodiff
+    their gradients."""
+    held = up.shape[0]
+    local = idx - first
+    here = (local >= 0) & (local < held)
+    e = jnp.clip(local, 0, held - 1)
+    hp = jax.lax.Precision.HIGHEST
+    hid = jnp.einsum("nc,nkfc->nkf", data, up[e], precision=hp)
+    y = jnp.einsum("nkf,nkcf->nkc", jnp.square(jnp.maximum(hid, 0)),
+                   down[e], precision=hp)
+    return (jnp.where(here, w, 0.0)[:, :, None] * y).sum(axis=1)
+
+
+def _poisoned(fn):
+    """``fn`` with NaN in every row of the blocks from ``live`` on: what a
+    kernel that never wrote them may leave there."""
+    def product(x, w, tiles, fill, live, *args, **kw):
+        out = fn(x, w, tiles, fill, live, *args, **kw)
+        if out.ndim == 3:                       # the transposed product
+            return out
+        block = x.shape[0] // tiles.shape[0]
+        dead = jnp.arange(x.shape[0]) >= live * block
+        return jnp.where(dead[:, None], jnp.nan, out)
+    return product
+
+
+def _kernels(monkeypatch, impl):
+    """Steer ``moe_experts`` to one implementation of the two products:
+    the Pallas kernels in interpret mode, which work a part-empty block in
+    halves, or the plain forms, which work it whole; both leave NaN past
+    the last block that holds rows."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    pair = {"kernel": (functools.partial(pk.grouped_matmul, interpret=True),
+                       functools.partial(pk.grouped_matmul_t,
+                                         interpret=True)),
+            "plain": (moe.grouped_matmul, moe.grouped_matmul_t)}[impl]
+    monkeypatch.setattr(moe, "_products", lambda block, data, up: tuple(
+        _poisoned(fn) for fn in pair) + (
+            block // 2 if impl == "kernel" else block,))
+
+
+def _assignments(case, n=48, e=64, held=4):
+    """(N, K) expert indices, every token's distinct: the held experts'
+    runs as ``case`` names them, the rest on absent experts."""
+    r = np.random.RandomState(7)
+    absent = np.stack([r.choice(np.arange(held, e), K, replace=False)
+                       for _ in range(n)])
+    idx = absent.copy()
+    if case == "uneven":            # 13, 5, 22 and 1 rows
+        for expert, rows in enumerate((13, 5, 22, 1)):
+            idx[r.choice(n, rows, replace=False), expert] = expert
+    elif case == "an_expert_without_rows":
+        idx[:20, 0], idx[10:37, 2], idx[5:8, 3] = 0, 2, 3
+    elif case == "all_on_the_same_two":  # 96 rows: past the 80 set aside
+        idx[:, 0], idx[:, 1] = 1, 2
+    elif case == "runs_end_on_a_block_edge":     # blocks of 8 rows
+        idx[:16, 0], idx[8:16, 1], idx[:24, 2], idx[40:, 3] = 0, 1, 2, 3
+    else:
+        assert case == "nothing_lands"
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("impl", ["kernel", "plain"])
+@pytest.mark.parametrize("case", ["uneven", "an_expert_without_rows",
+                                  "all_on_the_same_two",
+                                  "runs_end_on_a_block_edge",
+                                  "nothing_lands"])
+def test_the_grouped_products_are_the_per_row_products(monkeypatch, case,
+                                                       impl):
+    """Forward and the gradients of the rows, the routing weights and both
+    matrices, widths of 16 and 12 (no multiple of 128); nothing of the NaN
+    past the last live block reaches any of them."""
+    _kernels(monkeypatch, impl)
+    assert moe.capacity(N, K, 64, 4) == (8, 48 + 32, 192 + 32)
+    p = _layer(11, e=4)
+    idx = _assignments(case)
+    r = np.random.RandomState(3)
+    w = jnp.asarray(r.rand(N, K) + 0.1, jnp.float32)
+    t = jnp.asarray(r.randn(N, C), jnp.float32)
+
+    def program(data, w, up, down):
+        return get_op("moe_experts").fn(
+            data, idx, w, up, down, num_experts=64, experts_held=4,
+            first_expert=0, num_hidden=F)
+
+    def reference(data, w, up, down):
+        return _rowwise(data, idx, w, up, down, 0)
+    args = (p["u"], w, p["up"], p["down"])
+    with telemetry.collect_device_counters() as bag:
+        got = program(*args)
+    want = reference(*args)
+    scale = max(float(jnp.abs(want).max()), 1.0)
+    np.testing.assert_allclose(got, want, atol=3e-5 * scale, rtol=0)
+    counts = np.bincount(np.asarray(idx).ravel(), minlength=64)[:4]
+    landed, fullest, absent, dropped = np.asarray(bag.stacked()["moe"][0])
+    assert (landed, fullest, dropped) == (counts.sum(), counts.max(), 0)
+    # whole blocks of 8 rows; the kernels work a run's last block, and an
+    # expert's without rows, in halves
+    unit = 4 if impl == "kernel" else 8
+    blocks = np.maximum(-(-counts // 8), 1)
+    last = counts - 8 * (blocks - 1)
+    assert float(bag.stacked()["moe_rows"][0]) == (
+        8 * (blocks - 1) + np.maximum(-(-last // unit), 1) * unit).sum()
+    grads = [jax.grad(lambda *a: (fn(*a) * t).sum(), argnums=(0, 1, 2, 3))(
+        *args) for fn in (program, reference)]
+    for name, a, b in zip(("data", "weights", "up", "down"), *grads):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(
+            a, b, atol=5e-5 * max(float(jnp.abs(b).max()), 1.0), rtol=0,
+            err_msg=name)
+
+
+@pytest.mark.parametrize("transpose_rhs", [True, False])
+def test_the_kernels_tile_a_width_that_is_no_multiple_of_128(transpose_rhs):
+    """n = 200 in tiles of 128: the last tile hangs over the edge, in the
+    product and in the transposed product; k = 72 is taken whole."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    r = np.random.RandomState(5)
+    block, k, n, groups = 16, 72, 200, 3
+    tiles = jnp.asarray([0, 0, 1, 2, 2, 2, 2, 2], jnp.int32)
+    fill = jnp.full(8, block, jnp.int32)
+    live = jnp.int32(6)
+    x = jnp.asarray(r.randn(8 * block, k), jnp.float32)
+    mat = jnp.asarray(r.randn(groups, k, n), jnp.float32)
+    rows = np.repeat(np.asarray(tiles), block)[:6 * block]
+    want = np.einsum("mk,mkn->mn", np.asarray(x)[:6 * block],
+                     np.asarray(mat)[rows])
+    for fn, kw in ((pk.grouped_matmul, dict(block_n=128, interpret=True)),
+                   (moe.grouped_matmul, {})):
+        got = fn(x, jnp.swapaxes(mat, 1, 2) if transpose_rhs else mat,
+                 tiles, fill, live, transpose_rhs=transpose_rhs, **kw)
+        np.testing.assert_allclose(got[:6 * block], want, atol=1e-4)
+    other = jnp.asarray(r.randn(8 * block, n), jnp.float32)
+    want = np.stack([np.asarray(x)[:6 * block][rows == g].T
+                     @ np.asarray(other)[:6 * block][rows == g]
+                     for g in range(groups)])
+    for fn, kw in ((pk.grouped_matmul_t, dict(block_n=128, interpret=True)),
+                   (moe.grouped_matmul_t, {})):
+        np.testing.assert_allclose(
+            fn(x, other, tiles, fill, live, groups, **kw), want, atol=1e-4)
+
+
+def test_the_kernels_work_a_half_empty_block_as_its_lower_half():
+    """Blocks of 16 rows holding 16, 3, 8, 9 and 0: the product writes the
+    lower 8 rows of the second, third and fifth and leaves their upper
+    rows alone; the transposed product leaves those rows out of the sum."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    r = np.random.RandomState(6)
+    block, k, n = 16, 24, 40
+    tiles = jnp.asarray([0, 0, 1, 1, 2, 2], jnp.int32)
+    fill = jnp.asarray([16, 3, 8, 9, 0, 0], jnp.int32)
+    live = jnp.int32(5)
+    x = jnp.asarray(r.randn(6 * block, k), jnp.float32)
+    mat = jnp.asarray(r.randn(3, k, n), jnp.float32)
+    rows = np.repeat(np.asarray(tiles), block)
+    worked = np.concatenate([np.arange(16) < w for w in (16, 8, 8, 16, 8, 0)])
+    got = np.asarray(pk.grouped_matmul(x, mat, tiles, fill, live,
+                                       interpret=True))
+    want = np.einsum("mk,mkn->mn", np.asarray(x), np.asarray(mat)[rows])
+    np.testing.assert_allclose(got[worked], want[worked], atol=1e-4)
+    assert np.isnan(got[~worked]).all()         # interpret mode's unwritten
+    other = jnp.asarray(r.randn(6 * block, n), jnp.float32)
+    got = pk.grouped_matmul_t(x, other, tiles, fill, live, 3, interpret=True)
+    want = np.stack([np.asarray(x)[worked & (rows == g)].T
+                     @ np.asarray(other)[worked & (rows == g)]
+                     for g in range(3)])
+    np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+def test_the_tiles_come_from_the_shape_and_the_guard_asks_them():
+    from mxnet_tpu.ops import pallas_kernels as pk
+    # the cell's experts, bfloat16: the product takes a matrix whole (10 MB,
+    # fetched once a run); the transposed product's f32 tile is cut
+    assert pk.grouped_blocks(256, 2688, 1856, 2) == (1856, 640)
+    assert pk.grouped_blocks(256, 1856, 2688, 2) == (2688, 896)
+    assert pk.grouped_available(256, 2688, 1856, 2)
+    # the tests' blocks of 8 to 64 rows go to the plain form
+    assert not pk.grouped_available(64, 2688, 1856, 2)
+    assert not pk.grouped_available(8, 16, 12, 4)
+    # a matrix too large for any tile
+    assert pk.grouped_blocks(256, 1 << 20, 1856, 2) == (None, None)
+    assert not pk.grouped_available(256, 1 << 20, 1856, 2)
 
 
 def test_the_counters_reach_telemetry_from_a_train_step():

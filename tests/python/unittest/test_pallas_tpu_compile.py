@@ -1,5 +1,6 @@
-"""The flash kernels compiled for a described (not attached) TPU v5e, at the
-benchmark cell's shape and the shape guard's corners: what interpret mode
+"""The flash kernels and the routed experts' grouped products compiled for a
+described (not attached) TPU v5e, at the benchmark cells' shapes and the
+shape guards' corners: what interpret mode
 cannot show — a slice Mosaic cannot tile, a transpose it cannot lower, more
 VMEM than a kernel may use — fails here, on the CPU harness, at no chip time.
 Nothing runs: results and times come from ``tools/tpu_numerics_check.py``
@@ -16,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.ops.pallas_kernels import (flash_attention, flash_available,
-                                          flash_blocks)
+                                          flash_blocks, grouped_available,
+                                          grouped_matmul, grouped_matmul_t)
 
 
 @pytest.fixture(scope="module")
@@ -83,3 +85,43 @@ def test_head_sizes_off_the_lane_width_compile(one_chip, d):
 def test_explicit_blocks_compile(one_chip, bq, bk):
     _compile(one_chip, (1, 4, 2048, 64), jnp.bfloat16, block_q=bq,
              block_k=bk)
+
+
+# nemotron-twotower-steps-t4096's routed experts: 8 held, hidden 2688, width
+# 1856 (14.5 x 128 lanes), the 8,192 rows set aside in blocks of 256.  The
+# seven products of a layer's forward and backward, by operand shapes:
+# (rows' width, matrix or second rows' shape, keywords)
+ROWS, HELD, HIDDEN, WIDTH = 8192, 8, 2688, 1856
+GROUPED = {
+    "up": (HIDDEN, (HELD, WIDTH, HIDDEN), dict(
+        transpose_rhs=True, act=lambda x: jnp.square(jnp.maximum(x, 0)))),
+    "up_again": (HIDDEN, (HELD, WIDTH, HIDDEN), dict(
+        transpose_rhs=True, out_dtype=jnp.float32)),
+    "down": (WIDTH, (HELD, HIDDEN, WIDTH), dict(transpose_rhs=True)),
+    "d_hid": (HIDDEN, (HELD, HIDDEN, WIDTH), dict(out_dtype=jnp.float32)),
+    "d_rows": (WIDTH, (HELD, WIDTH, HIDDEN), {}),
+    "d_up": (WIDTH, (ROWS, HIDDEN), None),
+    "d_down": (HIDDEN, (ROWS, WIDTH), None)}
+
+
+@pytest.mark.parametrize("product", sorted(GROUPED))
+def test_the_routed_experts_products_compile_at_the_cells_shape(one_chip,
+                                                                product):
+    """The grid's extent is a traced count of blocks; a matrix is taken
+    whole; a block is worked whole or as its lower 128 rows; the transposed
+    products' tiles of 640 and 896 lanes hang over 1856's edge or cut 2688
+    in three."""
+    assert grouped_available(256, HIDDEN, WIDTH, 2)
+    width, second, kw = GROUPED[product]
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    if kw is None:
+        fn = lambda a, b, *t: grouped_matmul_t(a, b, *t, HELD)  # noqa: E731
+    else:
+        fn = lambda a, b, *t: grouped_matmul(a, b, *t, **kw)  # noqa: E731
+    blocks = shaped((ROWS // 256,), jnp.int32)      # their experts, fills
+    text = jax.jit(fn).lower(
+        shaped((ROWS, width), jnp.bfloat16), shaped(second, jnp.bfloat16),
+        blocks, blocks, shaped((), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert ("mxtpu_tgmm" if kw is None else "mxtpu_gmm") in text

@@ -2,9 +2,10 @@
 (no interpret mode) on the TPU and compared against its XLA formulation at
 bf16-appropriate tolerances — flash attention forward and both backward
 kernels, including the corners of its shape guard (those with f32 operands
-too), and NormConv at the four ResNet-50 stage shapes.  The interpret-mode
+too), NormConv at the four ResNet-50 stage shapes, and the grouped products
+of the routed experts at the benchmark cell's own shape.  The interpret-mode
 twins of these checks run on the CPU harness (test_pallas.py,
-test_norm_conv.py).
+test_norm_conv.py, test_moe.py).
 
 Run on a machine with a chip:  python tools/tpu_numerics_check.py
 Prints one PASS line per check; exits non-zero on any mismatch, on a shape
@@ -33,6 +34,15 @@ NORM_CONV_SHAPES = [(56, 1, 1, 0, 256, 64),
                     (56, 3, 1, 1, 64, 64),
                     (56, 3, 2, 1, 128, 128),
                     (56, 1, 2, 0, 256, 512)]
+
+
+# the routed experts of nemotron-twotower-steps-t4096: (experts held, hidden,
+# expert width, rows of a block), and each held expert's rows in three steps:
+# a balanced router's, one that sends nearly all to two experts, none at all
+GROUPED_SHAPE = (8, 2688, 1856, 256)
+GROUPED_RUNS = [(192, 190, 200, 185, 256, 130, 257, 126),
+                (4000, 0, 1, 3071, 0, 512, 0, 300),
+                (0, 0, 0, 0, 0, 0, 0, 0)]
 
 
 def _rel(a, b):
@@ -109,6 +119,62 @@ def check_norm_conv():
                                                          cout), flush=True)
 
 
+def check_grouped_products():
+    """``grouped_matmul`` (both layouts of the matrices, with and without
+    the activation) and ``grouped_matmul_t`` against their plain forms
+    (``ops/moe.py``), on rows laid out as ``moe_experts`` lays them out;
+    only the rows that hold an assignment are compared, the rest being
+    nobody's."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import moe, pallas_kernels as pk
+
+    held, c, f, block = GROUPED_SHAPE
+    assert pk.grouped_available(block, c, f, 2), GROUPED_SHAPE
+    relu2 = moe.ACTIVATIONS["relu2"]
+    rng = np.random.RandomState(2)
+    bf16 = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
+    up, down = bf16(held, f, c) * 0.02, bf16(held, c, f) * 0.02
+    for runs in GROUPED_RUNS:
+        counts = jnp.asarray(runs, jnp.int32)
+        cap = moe.capacity(4096, 6, 128, held)[1 if sum(runs) < 6144 else 2]
+        order = jnp.arange(4096 * 6, dtype=jnp.int32)
+        _, valid, tiles, live = jax.jit(
+            lambda o, n: moe._layout(o, n, cap, block))(order, counts)
+        keep = valid[:, None]
+        x, hid = bf16(cap, c), bf16(cap, f)
+        cases = [("up", (x, up), dict(transpose_rhs=True, act=relu2)),
+                 ("down", (hid, down), dict(transpose_rhs=True)),
+                 ("d_hid", (x, down), dict(out_dtype=jnp.float32)),
+                 ("d_x", (hid, up), {})]
+        errs = []
+        for name, operands, kw in cases:
+            got, want = (jax.jit(lambda a, w, fn=fn: jnp.where(keep, fn(
+                a, w, *tiles, live, **kw), 0))(*operands)
+                         for fn in (pk.grouped_matmul, moe.grouped_matmul))
+            errs.append(_rel(got, want))
+            assert errs[-1] < 2e-2, "grouped %s rel err %.2e at %s" % (
+                name, errs[-1], runs)
+        # the transposed product reads the rows that hold nothing too: they
+        # are zero there, as the layer's backward makes them
+        for name, (a, b) in (("d_up", (hid, x)), ("d_down", (x, hid))):
+            a, b = jnp.where(keep, a, 0), jnp.where(keep, b, 0)
+            got, want = (jax.jit(lambda a, b, fn=fn: fn(a, b, *tiles, live,
+                                                        held))(a, b)
+                         for fn in (pk.grouped_matmul_t,
+                                    moe.grouped_matmul_t))
+            errs.append(_rel(got, want))
+            assert errs[-1] < 2e-2, "grouped %s rel err %.2e at %s" % (
+                name, errs[-1], runs)
+        print("PASS grouped products %s runs %s blocks %d of %d tiles %s %s"
+              "  rel err up %.1e down %.1e d_hid %.1e d_x %.1e d_up %.1e "
+              "d_down %.1e" % (
+                  GROUPED_SHAPE, runs, int(live), cap // block,
+                  pk.grouped_blocks(block, c, f, 2),
+                  pk.grouped_blocks(block, f, c, 2), *errs), flush=True)
+
+
 if __name__ == "__main__":
     import jax
     if jax.default_backend() != "tpu":
@@ -119,4 +185,5 @@ if __name__ == "__main__":
     enable_compile_cache()
     check_flash_attention()
     check_norm_conv()
+    check_grouped_products()
     print("ALL TPU NUMERICS CHECKS PASSED")
