@@ -40,19 +40,19 @@ def get_env(name, default=None, typ=None):
     return val
 
 
-# Env flags whose value is consulted while a computation is being traced
-# (executor layout/fusion passes, op formulation A/B levers).  Every jit
-# dispatch cache keys on trace_env_key() so toggling one of these between
-# calls retraces instead of silently reusing a program compiled under the
-# old value.  Adding a var here is the contract for reading it at trace
-# time; mxlint's JIT001 rule polices reads that bypass it.
+# Env flags whose value is consulted while a computation is being traced.
+# Every jit dispatch cache keys on trace_env_key() so toggling one of these
+# between calls retraces instead of silently reusing a program compiled
+# under the old value.  A row here is the one contract for reading a
+# variable at trace time; mxlint's JIT001 rule polices reads that bypass it.
+# Kept on purpose: the other values of MXNET_CONV_LAYOUT and MXNET_STEM_FUSE
+# are the logical-NCHW and unfused lowerings, the references that
+# test_layout.py and test_stem_fuse.py hold the default against in float64;
+# MXNET_MONITOR is a feature.  A lever that loses its A/B leaves with its
+# row.
 TRACE_ENV_DEFAULTS = (
     ("MXNET_CONV_LAYOUT", "NHWC"),
-    ("MXNET_NORM_CONV", "0"),
     ("MXNET_STEM_FUSE", "1"),
-    ("MXNET_STEM_S2D", "0"),
-    ("MXNET_POOL_MASK_BWD", "0"),
-    ("MXNET_PALLAS_CONV", "auto"),
     # numerics monitor: the spec decides whether the fused step traces
     # the auxiliary stats pytree, so it must retrace on toggle
     ("MXNET_MONITOR", ""),

@@ -155,7 +155,6 @@ class _Lowered(object):
                     act.op.normalize_attrs(act.params).get("act_type") \
                     == "relu":
                 self.fused_relu[id(n)] = act
-        self._init_norm_conv(consumers, outs)
         # peephole: train-mode BatchNorm(fix_gamma) applied directly to a
         # graph input and consumed by exactly one Convolution (the ResNet
         # "bn_data -> conv0" stem) fuses to ops/nn.py input_bn_conv, whose
@@ -197,86 +196,12 @@ class _Lowered(object):
                 "stride": tuple(ca.get("stride") or ()) or (1, 1),
                 "pad": tuple(ca.get("pad") or ()) or (0, 0)}
 
-    @staticmethod
-    def _nc_conv_attrs(n):
-        """Conv geometry if the node is NormConv-fusable, else None
-        (2-D square 1x1/3x3, stride 1/2, pad 0/1, ungrouped, undilated,
-        bias-free — the pre-activation conv-net idiom)."""
-        a = n.op.normalize_attrs(n.params)
-        k = tuple(a.get("kernel") or ())
-        if len(k) != 2 or k[0] != k[1] or k[0] not in (1, 3):
-            return None
-        s = tuple(a.get("stride") or ()) or (1, 1)
-        p = tuple(a.get("pad") or ()) or (0, 0)
-        d = tuple(a.get("dilate") or ()) or (1, 1)
-        if s[0] != s[1] or s[0] not in (1, 2) or p[0] != p[1] or \
-                p[0] not in (0, 1) or d != (1, 1):
-            return None
-        if int(a.get("num_group") or 1) != 1 or not a.get("no_bias"):
-            return None
-        if a.get("layout") not in (None, "NCHW"):
-            return None
-        return {"k": k[0], "s": s[0], "p": p[0]}
-
-    def _init_norm_conv(self, consumers, outs):
-        """NormConv fusion map (TPU-native; no reference graph analogue —
-        the reference reaches the same fusion only through cuDNN).  A
-        BatchNorm[->relu] whose consumers are Convolutions becomes the
-        *prologue* of those convs (ops/pallas_conv.py): the conv kernel
-        applies scale/shift+relu while streaming its input, so the BN apply
-        pass never materialises.  A BatchNorm whose data producer is such a
-        conv reads its batch statistics from that conv's *epilogue* instead
-        of re-sweeping the activation."""
-        self.nc_bn = {}        # bn id -> {act, convs, others, attrs}
-        self.nc_conv = {}      # conv id -> bn id
-        self.nc_stats_src = {} # bn id -> producer conv node
-        self.nc_stats_for = {} # conv id -> [bn ids consuming epilogue stats]
-        for b in self.order:
-            if b.is_var or b.op.name != "BatchNorm":
-                continue
-            attrs = b.op.normalize_attrs(b.params)
-            if attrs.get("output_mean_var"):
-                continue
-            chain, act = b, None
-            cons = consumers.get((id(b), 0), [])
-            if len(cons) == 1 and not cons[0].is_var and \
-                    cons[0].op.name == "Activation" and \
-                    cons[0].op.normalize_attrs(cons[0].params).get(
-                        "act_type") == "relu" and (id(b), 0) not in outs:
-                chain, act = cons[0], cons[0]
-                cons = consumers.get((id(chain), 0), [])
-            convs, others = [], (id(chain), 0) in outs
-            for c in cons:
-                if (not c.is_var and c.op.name == "Convolution"
-                        and c.inputs[0] == (chain, 0)
-                        and self._nc_conv_attrs(c) is not None
-                        # the chain value must not ALSO feed a non-data slot
-                        and sum(1 for inp in c.inputs
-                                if inp == (chain, 0)) == 1):
-                    convs.append(c)
-                else:
-                    others = True
-            if not convs:
-                continue
-            self.nc_bn[id(b)] = {"bn": b, "act": act, "convs": convs,
-                                 "others": others, "attrs": attrs}
-            for c in convs:
-                self.nc_conv[id(c)] = id(b)
-        for b_id, info in self.nc_bn.items():
-            b = info["bn"]
-            src, si = b.inputs[0]
-            if si == 0 and not src.is_var and id(src) in self.nc_conv \
-                    and not info["attrs"].get("use_global_stats"):
-                self.nc_stats_src[b_id] = src
-                self.nc_stats_for.setdefault(id(src), []).append(b_id)
-
     # ------------------------------------------------------ pipeline stages
     def _glue_edges(self):
         """Op-order index pairs (lo, hi) that must stay in one stage: the
-        fusion peepholes (BN+relu, stem BN+conv, NormConv prologue/epilogue)
-        rewrite both members together, so a stage cut between them would
-        change which programs the single-program step and the pipelined
-        stages trace."""
+        fusion peepholes (BN+relu, stem BN+conv) rewrite both members
+        together, so a stage cut between them would change which programs
+        the single-program step and the pipelined stages trace."""
         op_pos = {}
         for n in self.order:
             if not n.is_var:
@@ -291,13 +216,6 @@ class _Lowered(object):
             edge(bn_id, id(act))
         for bn_id, info in self.stem_fuse.items():
             edge(bn_id, id(info["conv"]))
-        for bn_id, info in self.nc_bn.items():
-            if info["act"] is not None:
-                edge(bn_id, id(info["act"]))
-            for c in info["convs"]:
-                edge(bn_id, id(c))
-        for bn_id, src in self.nc_stats_src.items():
-            edge(id(src), bn_id)
         return op_pos, edges
 
     def stage_partition(self, num_stages, input_names=(), param_sizes=None):
@@ -438,102 +356,7 @@ class _Lowered(object):
                 carry_out=list(frontiers[s]) if s < num_stages - 1 else []))
         return stages
 
-    def _nc_run_bn(self, node, values, nhwc, aux_updates, nc_ctx, is_train,
-                   skip):
-        """Resolve a fused BatchNorm to per-channel (scale, shift): stats
-        come from the producer conv's epilogue when available, one XLA
-        reduce otherwise; the apply pass only materialises for non-conv
-        consumers.  Returns False to fall back to the generic path."""
-        import jax
-        import jax.numpy as jnp
-        info = self.nc_bn[id(node)]
-        xk = (id(node.inputs[0][0]), node.inputs[0][1])
-        x = values[xk]
-        if not hasattr(x, "ndim") or x.ndim != 4:
-            return False
-        x_cl = x if xk in nhwc else jnp.moveaxis(x, 1, -1)
-        attrs = info["attrs"]
-        eps = float(attrs.get("eps", 1e-3))
-        momentum = float(attrs.get("momentum", 0.9))
-        fix_gamma = attrs.get("fix_gamma", True)
-        ik = [(id(c), i) for c, i in node.inputs]
-        gamma, beta, mm, mv = (values[k] for k in ik[1:5])
-        acc = jnp.promote_types(x.dtype, jnp.float32)
-        c = x_cl.shape[-1]
-        if is_train and not attrs.get("use_global_stats"):
-            src = self.nc_stats_src.get(id(node))
-            if src is not None and (id(src), 1) in values:
-                ssum = values[(id(src), 1)].astype(acc)
-                ssq = values[(id(src), 2)].astype(acc)
-            else:
-                x32 = x_cl.astype(acc)
-                ssum = x32.sum(axis=(0, 1, 2))
-                ssq = jnp.square(x32).sum(axis=(0, 1, 2))
-            nhw = x_cl.size // c
-            mean = ssum / nhw
-            var = jnp.maximum(ssq / nhw - jnp.square(mean), 0.0)
-            mom = jnp.float32(momentum)
-            for pos, new in ((3, mm * mom + mean.astype(mm.dtype) * (1 - mom)),
-                             (4, mv * mom + var.astype(mv.dtype) * (1 - mom))):
-                child = node.inputs[pos][0]
-                if child.is_var:
-                    aux_updates[child.name] = new
-        else:
-            mean = jax.lax.stop_gradient(mm).astype(acc)
-            var = jax.lax.stop_gradient(mv).astype(acc)
-        inv = jax.lax.rsqrt(var + eps)
-        g = jnp.ones_like(gamma) if fix_gamma else gamma
-        scale = g.astype(acc) * inv
-        shift = beta.astype(acc) - mean * scale
-        nc_ctx[id(node)] = (scale, shift, xk, info["act"] is not None)
-        if info["others"]:
-            from .ops.pallas_conv import _apply
-            out = _apply(x_cl, scale, shift, info["act"] is not None)
-            key = (id(info["act"]), 0) if info["act"] is not None \
-                else (id(node), 0)
-            values[key] = out
-            nhwc.add(key)
-        if info["act"] is not None:
-            skip.add(id(info["act"]))
-        return True
-
-    def _nc_run_conv(self, node, values, nhwc, nc_ctx, is_train, nc_pl):
-        """Run a Convolution as the fused NormConv kernel: the BN(+relu)
-        resolved by _nc_run_bn becomes the prologue; epilogue statistics are
-        emitted when a downstream BatchNorm will consume them."""
-        import jax.numpy as jnp
-        from .ops.pallas_conv import norm_conv
-        scale, shift, xk, relu = nc_ctx[self.nc_conv[id(node)]]
-        x = values[xk]
-        x_cl = x if xk in nhwc else jnp.moveaxis(x, 1, -1)
-        wk = (id(node.inputs[1][0]), node.inputs[1][1])
-        w = values[wk]                       # logical (O, I, kh, kw)
-        w_t = jnp.transpose(w, (2, 3, 1, 0))
-        g = self._nc_conv_attrs(node)
-        stats_out = bool(self.nc_stats_for.get(id(node))) and is_train
-        if nc_pl == "0":
-            up, interp = False, False
-        elif nc_pl == "interpret":
-            up, interp = True, True
-        elif nc_pl in ("k1", "k3"):
-            # perf-bisection filter: pallas only for 1x1 (or 3x3) convs
-            up = None if g["k"] == int(nc_pl[1]) else False
-            interp = False
-        else:
-            up, interp = None, False
-        y, s, q = norm_conv(x_cl, w_t, scale, shift, kernel=g["k"],
-                            stride=g["s"], pad=g["p"], relu=relu,
-                            prologue=True, stats=stats_out,
-                            use_pallas=up, interpret=interp)
-        values[(id(node), 0)] = y
-        nhwc.add((id(node), 0))
-        if stats_out:
-            # pseudo-slots read back by _nc_run_bn of the consuming BN
-            values[(id(node), 1)] = s
-            values[(id(node), 2)] = q
-
-    def _stem_run(self, node, values, nhwc, aux_updates, skip, arg_vals,
-                  s2d=False):
+    def _stem_run(self, node, values, nhwc, aux_updates, skip, arg_vals):
         """Run a fused input-BN + conv pair (see stem_fuse in __init__)."""
         import jax.numpy as jnp
         from .ops.nn import input_bn_conv
@@ -555,7 +378,7 @@ class _Lowered(object):
             w = arg_vals[wvar.name]
         out, mean, var = input_bn_conv(x_cl, beta, w, info["eps"],
                                        info["kernel"], info["stride"],
-                                       info["pad"], s2d=s2d)
+                                       info["pad"])
         mom = jnp.float32(info["momentum"])
         for pos, stat in ((3, mean), (4, var)):
             child = node.inputs[pos][0]
@@ -614,19 +437,9 @@ class _Lowered(object):
         import jax.numpy as jnp
         from .base import get_env
         use_nhwc = get_env("MXNET_CONV_LAYOUT", "NHWC") == "NHWC"
-        # NormConv fusion: BN(+relu) folded into the consuming convs'
-        # prologue, next-BN statistics from the conv epilogue (Pallas on
-        # TPU, equivalent XLA composition elsewhere).  Default OFF until an
-        # A/B on the chip decides it (ROADMAP S4: two benchmark runs, with
-        # and without MXNET_NORM_CONV=1; MXNET_PALLAS_CONV picks the sites).
-        nc_on = (use_nhwc and not collect and bool(self.nc_bn)
-                 and get_env("MXNET_NORM_CONV", "0") == "1")
         stem_on = (use_nhwc and is_train and not collect
                    and bool(self.stem_fuse) and no_grad_inputs
                    and get_env("MXNET_STEM_FUSE", "1") == "1")
-        stem_s2d = get_env("MXNET_STEM_S2D", "0") == "1"
-        nc_pl = get_env("MXNET_PALLAS_CONV", "auto")
-        nc_ctx = {}
         values = {}
         nhwc = set()      # value keys currently stored channel-last
         aux_updates = {}
@@ -662,22 +475,10 @@ class _Lowered(object):
             if id(node) in skip:
                 continue
             if stem_on and id(node) in self.stem_fuse \
-                    and self.stem_fuse[id(node)]["var"] in no_grad_inputs \
-                    and not (nc_on and (id(node) in self.nc_bn or
-                                        id(self.stem_fuse[id(node)]["conv"])
-                                        in self.nc_conv)):
+                    and self.stem_fuse[id(node)]["var"] in no_grad_inputs:
                 if self._stem_run(node, values, nhwc, aux_updates, skip,
-                                  arg_vals, s2d=stem_s2d):
+                                  arg_vals):
                     continue
-            if nc_on and id(node) in self.nc_bn:
-                if self._nc_run_bn(node, values, nhwc, aux_updates, nc_ctx,
-                                   is_train, skip):
-                    continue
-            if nc_on and id(node) in self.nc_conv \
-                    and self.nc_conv[id(node)] in nc_ctx:
-                self._nc_run_conv(node, values, nhwc, nc_ctx, is_train,
-                                  nc_pl)
-                continue
             # monitor mode needs true per-op internals — no fusion there
             fused_act = None if collect else self.fused_relu.get(id(node))
             op = node.op

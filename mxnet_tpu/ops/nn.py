@@ -310,87 +310,6 @@ def _deconv_infer(attrs, in_shapes):
 
 
 # --------------------------------------------------------------------- Pooling
-# ------------------------------------------------- max-pool backward (mask)
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _max_pool_core(data, window, strides, padding):
-    """Max pooling whose backward uses the equality-mask formulation.
-
-    XLA's native grad of reduce_window(max) is select-and-scatter, which
-    routes the gradient to only the FIRST maximal element of a tied
-    window.  The reference's pooling backward (mshadow unpool, reference
-    src/operator/pooling-inl.h) instead gives the gradient to EVERY
-    element equal to the window max; this VJP reproduces that semantics
-    with elementwise work only (see _max_pool_mask_bwd).  It is an
-    OPT-IN semantic-parity path, not a fast path: on the v5e it measured
-    ~0.5 ms/step slower than select-and-scatter on the ResNet stem pool
-    (b32 bench 2485 vs 2855 img/s), so MXNET_POOL_MASK_BWD defaults
-    off."""
-    return jax.lax.reduce_window(data, -jnp.inf, jax.lax.max, window,
-                                 strides, padding)
-
-
-def _max_pool_mask_fwd(data, window, strides, padding):
-    out = jax.lax.reduce_window(data, -jnp.inf, jax.lax.max, window,
-                                strides, padding)
-    return out, (data, out)
-
-
-def _max_pool_mask_bwd(window, strides, padding, res, dy):
-    """dx[i] = sum over windows w containing i of dy[w] * (x[i] == max[w]).
-
-    Formulated per *window offset* a (the a-th window covering a position,
-    a < ceil(k/s) per dim) rather than per kernel tap: the pooled arrays
-    are upsampled with repeat (a broadcast-reshape XLA fuses freely — no
-    interior padding, which breaks TPU loop fusion) and edge-shifted, and
-    window membership is a cheap periodic iota mask.  ceil(k/s)^nd terms
-    (4 for the 3x3/s2 stem pool) of pure elementwise work."""
-    import itertools
-    x, out = res
-    zero = jnp.zeros((), dy.dtype)
-    dims = range(x.ndim)
-    a_ranges = [range(-(-window[d] // strides[d])) for d in dims]
-
-    def place(arr, sentinel, offs):
-        """arr[(i+p)//s - a] on the input grid, `sentinel` out of range."""
-        r = arr
-        for d in dims:
-            s, p, a = strides[d], padding[d][0], offs[d]
-            if s > 1:
-                r = jnp.repeat(r, s, axis=d)
-            off = p - a * s
-            lo = max(0, -off)
-            hi = max(0, off + x.shape[d] - r.shape[d])
-            if lo or hi:
-                cfg = [(0, 0, 0)] * x.ndim
-                cfg[d] = (lo, hi, 0)
-                r = jax.lax.pad(r, sentinel, cfg)
-            r = jax.lax.slice_in_dim(r, off + lo, off + lo + x.shape[d],
-                                     axis=d)
-        return r
-
-    dx = None
-    for offs in itertools.product(*a_ranges):
-        mask = None
-        for d in dims:
-            s, k, p, a = strides[d], window[d], padding[d][0], offs[d]
-            if s == 1 or a * s + s - 1 < k:
-                continue   # every phase of this dim is inside the window
-            phase_ok = (jnp.arange(x.shape[d]) + p) % s + a * s < k
-            phase_ok = phase_ok.reshape(
-                [-1 if dd == d else 1 for dd in dims])
-            mask = phase_ok if mask is None else mask & phase_ok
-        dy_t = place(dy, zero, offs)
-        max_t = place(out, jnp.asarray(jnp.inf, out.dtype), offs)
-        term = jnp.where(x == max_t, dy_t, zero)
-        if mask is not None:
-            term = jnp.where(mask, term, zero)
-        dx = term if dx is None else dx + term
-    return (dx,)
-
-
-_max_pool_core.defvjp(_max_pool_mask_fwd, _max_pool_mask_bwd)
-
-
 def _pool_out_dim(i, k, s, p, convention):
     if convention == "full":
         return int(_np.ceil(float(i + 2 * p - k) / s)) + 1
@@ -417,14 +336,12 @@ def _pool_infer(attrs, in_shapes):
           attr_types={"kernel": parse_tuple, "stride": parse_tuple,
                       "pad": parse_tuple, "pool_type": parse_str,
                       "global_pool": parse_bool, "pooling_convention": parse_str,
-                      "layout": parse_str, "mask_bwd": parse_bool},
+                      "layout": parse_str},
           defaults={"stride": (), "pad": (), "pool_type": "max",
                     "global_pool": False, "pooling_convention": "valid"},
-          env_attrs={"mask_bwd": ("MXNET_POOL_MASK_BWD", "0")},
           infer_shape=_pool_infer, layout_rule="aware")
 def _pooling(data, kernel=None, stride=(), pad=(), pool_type="max",
-             global_pool=False, pooling_convention="valid", layout=None,
-             mask_bwd=None):
+             global_pool=False, pooling_convention="valid", layout=None):
     """N-D pooling via XLA reduce_window (parity: pooling-inl.h / pool.h)."""
     nd = data.ndim - 2
     sp_axes = tuple(range(1, 1 + nd)) if layout == "NHWC" \
@@ -458,19 +375,6 @@ def _pooling(data, kernel=None, stride=(), pad=(), pool_type="max",
             return jax.lax.reduce_window(data, jnp.iinfo(data.dtype).min,
                                          jax.lax.max, window, strides,
                                          padding)
-        if not global_pool and mask_bwd:
-            # equality-mask backward — the reference's unpool tie
-            # semantics (every tied max gets the gradient) as an opt-in
-            # (MXNET_POOL_MASK_BWD, resolved to the mask_bwd attr at
-            # dispatch time — never read while tracing).
-            # Default OFF: on the v5e the fused elementwise formulation
-            # measured ~0.5 ms/step SLOWER than XLA's native
-            # select-and-scatter on the ResNet stem pool (b32 bench 2485
-            # vs 2855 img/s) — XLA materialises the per-offset terms
-            # instead of fusing them.  Global max pool always keeps the
-            # native grad (one window = H*W offsets here).
-            return _max_pool_core(data, window, strides,
-                                  tuple(tuple(p_) for p_ in padding))
         return jax.lax.reduce_window(data, -jnp.inf, jax.lax.max, window,
                                      strides, padding)
     ssum = jax.lax.reduce_window(data, 0.0, jax.lax.add,
@@ -619,84 +523,20 @@ _bn_relu_train_core.defvjp(_bn_relu_train_core_fwd, _bn_relu_train_core_bwd)
 
 
 # ------------------------------------------------- fused input-BN + stem conv
-def _s2d_eligible(x_shape, geom):
-    """Space-to-depth applies when both spatial strides are 2, the input
-    spatial dims are even, AND the packed stride-1 conv reproduces the
-    strided conv's output extent exactly: the packed form always emits
-    H/2, which equals floor((H + 2p - k)/2) + 1 only when k - 2p is 1 or
-    2 (the 7x7/p3 ImageNet stem qualifies)."""
-    k, s, p = geom
-    return (s == (2, 2)
-            and x_shape[1] % 2 == 0 and x_shape[2] % 2 == 0
-            and k[0] - 2 * p[0] in (1, 2) and k[1] - 2 * p[1] in (1, 2))
-
-
-def _s2d_pack_weights(w, geom):
-    """Logical (O, C, kh, kw) stem weights -> packed (khp, kwp, 4C, O)
-    HWIO weights for the space-to-depth conv, plus the packed padding.
-
-    A stride-2 conv on (H, W, C) is exactly a stride-1 conv on the 2x2
-    depth-packed (H/2, W/2, 4C) input: input row 2i - p + kh splits into
-    parity a = (kh - p) % 2 and packed tap u = (kh - p - a)//2 relative to
-    output row i.  Packing quadruples the MXU contraction depth — the
-    C=3 ImageNet stem runs ~4x denser (MLPerf-style stem optimisation,
-    same arithmetic)."""
-    o, c, kh, kw = w.shape
+def _stem_conv(y, w, geom):
+    """The stem convolution: ``y`` channel-last, ``w`` logical."""
     _, s, p = geom
-
-    def taps(kdim, pad):
-        ms = [t - pad for t in range(kdim)]
-        us = [(m - (m % 2)) // 2 for m in ms]
-        umin, umax = min(us), max(us)
-        return us, [m % 2 for m in ms], umin, umax
-
-    us_h, as_h, uhmin, uhmax = taps(kh, p[0])
-    us_w, as_w, uwmin, uwmax = taps(kw, p[1])
-    khp, kwp = uhmax - uhmin + 1, uwmax - uwmin + 1
-    wp = jnp.zeros((khp, kwp, 4 * c, o), w.dtype)
-    for ih in range(kh):
-        for iw in range(kw):
-            # packed channel layout: (a*2 + b)*C + c, matching the pack
-            # order in _s2d_pack_input
-            ch0 = (as_h[ih] * 2 + as_w[iw]) * c
-            wp = wp.at[us_h[ih] - uhmin, us_w[iw] - uwmin,
-                       ch0:ch0 + c, :].set(
-                jnp.transpose(w[:, :, ih, iw], (1, 0)))
-    pads = ((-uhmin, uhmax), (-uwmin, uwmax))
-    return wp, pads
-
-
-def _s2d_pack_input(y):
-    """(N, H, W, C) -> (N, H/2, W/2, 4C), channel layout (a*2+b)*C + c."""
-    n, h, w_, c = y.shape
-    y = jnp.reshape(y, (n, h // 2, 2, w_ // 2, 2, c))
-    y = jnp.transpose(y, (0, 1, 3, 2, 4, 5))
-    return jnp.reshape(y, (n, h // 2, w_ // 2, 4 * c))
-
-
-def _stem_conv(y, w, geom, s2d=False):
-    """The stem convolution, via space-to-depth when eligible and enabled
-    (MXNET_STEM_S2D=1; default off — see the A/B note in docs/perf.md).
-    ``s2d`` is resolved by the caller at dispatch time (the env var is
-    never read while tracing — it keys the jit caches instead)."""
-    k, s, p = geom
-    if s2d and _s2d_eligible(y.shape, geom):
-        wp, pads = _s2d_pack_weights(w, geom)
-        return jax.lax.conv_general_dilated(
-            _s2d_pack_input(y), wp, window_strides=(1, 1),
-            padding=list(pads), dimension_numbers=("NHWC", "HWIO", "NHWC"))
     return jax.lax.conv_general_dilated(
         y, jnp.transpose(w, (2, 3, 1, 0)), window_strides=s,
         padding=[(pp, pp) for pp in p],
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
-def _ibc_fwd_impl(x, b, w, eps, geom, s2d):
+def _ibc_fwd_impl(x, b, w, eps, geom):
     """Forward of the fused input BatchNorm(fix_gamma) + Convolution.
 
     ``x`` channel-last (N, H, W, C); ``w`` logical (O, C, kh, kw).
     Returns (conv_out_cl, mean, var, inv)."""
-    k, s, p = geom
     axes, cshape = _bn_axes(x.ndim, -1)
     acc = jnp.promote_types(x.dtype, jnp.float32)
     x32 = x.astype(acc)
@@ -707,7 +547,7 @@ def _ibc_fwd_impl(x, b, w, eps, geom, s2d):
     shift = b.astype(acc) - mean * inv
     y = x * inv.reshape(cshape).astype(x.dtype) \
         + shift.reshape(cshape).astype(x.dtype)
-    out = _stem_conv(y, w, geom, s2d)
+    out = _stem_conv(y, w, geom)
     return out, mean, var, inv
 
 
@@ -722,8 +562,8 @@ def _ibc_tap_ranges(in_dim, out_dim, k, s, p):
     return ranges
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _input_bn_conv_core(x, b, w, eps, geom, s2d):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _input_bn_conv_core(x, b, w, eps, geom):
     """BatchNorm(train, fix_gamma) on a no-gradient input, fused with the
     consuming Convolution — the ResNet stem pattern (bn_data -> conv0,
     reference example/image-classification/symbol_resnet.py).
@@ -740,16 +580,16 @@ def _input_bn_conv_core(x, b, w, eps, geom, s2d):
     finishes the reduction.  d(x) is NOT produced (hard zero): the
     executor only fuses this pattern when the input is declared
     no-gradient."""
-    out, mean, var, _ = _ibc_fwd_impl(x, b, w, eps, geom, s2d)
+    out, mean, var, _ = _ibc_fwd_impl(x, b, w, eps, geom)
     return out, mean, var
 
 
-def _input_bn_conv_fwd(x, b, w, eps, geom, s2d):
-    out, mean, var, inv = _ibc_fwd_impl(x, b, w, eps, geom, s2d)
+def _input_bn_conv_fwd(x, b, w, eps, geom):
+    out, mean, var, inv = _ibc_fwd_impl(x, b, w, eps, geom)
     return (out, mean, var), (x, b, w, mean, inv)
 
 
-def _input_bn_conv_bwd(eps, geom, s2d, res, cts):
+def _input_bn_conv_bwd(eps, geom, res, cts):
     g, _dmean_ct, _dvar_ct = cts      # mean/var flow only to x (dropped)
     x, b, w, mean, inv = res
     k, s, p = geom
@@ -762,7 +602,7 @@ def _input_bn_conv_bwd(eps, geom, s2d, res, cts):
         + shift.reshape(cshape).astype(x.dtype)
 
     def conv_of_w(wt):
-        return _stem_conv(y, wt, geom, s2d)
+        return _stem_conv(y, wt, geom)
     _, w_vjp = jax.vjp(conv_of_w, w)
     dw = w_vjp(g)[0]
     # d(beta) = sum over the input grid of dgrad(g, w), computed without the
@@ -790,15 +630,13 @@ def _input_bn_conv_bwd(eps, geom, s2d, res, cts):
 _input_bn_conv_core.defvjp(_input_bn_conv_fwd, _input_bn_conv_bwd)
 
 
-def input_bn_conv(x_cl, beta, weight, eps, kernel, stride, pad, s2d=False):
+def input_bn_conv(x_cl, beta, weight, eps, kernel, stride, pad):
     """Executor entry point: fused train-mode input-BN + conv, channel-last.
     Returns (out_cl, mean, var) with mean/var in f32 for the moving-stat
-    update.  ``s2d`` is the caller-resolved MXNET_STEM_S2D lever (a static
-    nondiff arg of the custom VJP, so flipping it retraces)."""
+    update."""
     geom = (tuple(int(v) for v in kernel), tuple(int(v) for v in stride),
             tuple(int(v) for v in pad))
-    return _input_bn_conv_core(x_cl, beta, weight, float(eps), geom,
-                               bool(s2d))
+    return _input_bn_conv_core(x_cl, beta, weight, float(eps), geom)
 
 
 def _bn_infer(attrs, in_shapes):

@@ -116,8 +116,7 @@ class OpDef(object):
                  infer_shape_backward=None, input_init_attrs=None,
                  needs_rng=False, train_aware=False, key_var_num_args=None,
                  aliases=(), hidden=False, doc=None, is_loss=False,
-                 layout_rule=None, layout_inputs=(0,), env_attrs=None,
-                 f32_inputs=()):
+                 layout_rule=None, layout_inputs=(0,), f32_inputs=()):
         self.name = name
         # inputs that stay float32 under a mixed-precision policy: a leaf
         # that feeds one of them directly is not cast to the compute dtype
@@ -140,13 +139,6 @@ class OpDef(object):
         self._num_outputs = num_outputs
         self.attr_types = dict(attr_types or {})
         self.defaults = dict(defaults or {})
-        # {attr: (env_var, default_str)}: attrs backed by an MXNET_* A/B
-        # lever.  Left unset by the user, the attr is resolved from the
-        # env at DISPATCH time (resolve_env_attrs) so the value lands in
-        # the attr dict — and therefore in every jit cache key derived
-        # from it — instead of being read while tracing, which would
-        # freeze the flag into the first compiled program.
-        self.env_attrs = dict(env_attrs or {})
         self._infer_shape = infer_shape
         self._infer_type = infer_type
         self.infer_shape_backward = infer_shape_backward
@@ -184,36 +176,9 @@ class OpDef(object):
                 out[k] = v
         return out
 
-    def resolve_env_attrs(self, attrs):
-        """Fill env-backed attrs (see ``env_attrs``) that the user left
-        unset from their MXNET_* vars.  Idempotent; an explicitly-passed
-        attr always wins over the env."""
-        if not self.env_attrs:
-            return attrs
-        from ..base import get_env
-        out = dict(attrs)
-        for a, (env, dflt) in self.env_attrs.items():
-            if out.get(a) is None:
-                v = get_env(env, dflt)
-                parser = self.attr_types.get(a)
-                if parser is parse_bool:
-                    # MXNET_* on/off levers are "1"-enabled exactly (the
-                    # repo-wide get_env(...) == "1" convention); the lax
-                    # attr-level parse_bool is for user-passed attrs only
-                    out[a] = v == "1"
-                else:
-                    out[a] = parser(v) if parser is not None else v
-        return out
-
     # ---------------------------------------------------------------- compute
     def make_callable(self, attrs, is_train):
-        """A positional-args-only closure over normalized attrs (jit-friendly).
-
-        Env-backed attrs are resolved here so the symbolic executor (which
-        builds callables while tracing) picks up the CURRENT env value on
-        every retrace — executor._get_jit keys its cache on
-        base.trace_env_key(), so a toggle forces that retrace."""
-        attrs = self.resolve_env_attrs(attrs)
+        """A positional-args-only closure over normalized attrs (jit-friendly)."""
         fn = self.fn
         kw = {}
         if self.train_aware:
@@ -351,10 +316,6 @@ def jitted(op, attrs, is_train=False):
     seq_mesh, seq_axis = _mesh_mod.sequence_mesh()
     seq_key = None if seq_mesh is None else (
         _mesh_mod.mesh_cache_key(seq_mesh), seq_axis)
-    # env-backed attrs resolve BEFORE the cache key is built: toggling
-    # e.g. MXNET_POOL_MASK_BWD between imperative calls lands on a new
-    # key and retraces instead of reusing the frozen first compile
-    attrs = op.resolve_env_attrs(attrs)
     key = (op.name, attr_key(attrs), bool(is_train), seq_key)
     fn = _JIT_CACHE.get(key)
     if fn is None:

@@ -122,26 +122,6 @@ def _scale_state_to_host(step):
             for k, v in host.items()}
 
 
-def _xla_options():
-    """Extra XLA compiler options for the fused step, from
-    MXNET_XLA_OPTIONS="flag=value;flag=value" (perf experiments — e.g.
-    xla_tpu_scoped_vmem_limit_kib; see docs/perf.md).  None when unset."""
-    from .base import get_env
-    spec = get_env("MXNET_XLA_OPTIONS", "")
-    if not spec:
-        return None
-    opts = {}
-    for item in spec.split(";"):
-        if not item.strip():
-            continue
-        if "=" not in item:
-            raise MXNetError(
-                "MXNET_XLA_OPTIONS: expected flag=value;..., got %r" % item)
-        k, v = item.split("=", 1)
-        opts[k.strip()] = v.strip()
-    return opts or None
-
-
 def _seq_replicated_sharding():
     """Replicated NamedSharding on the active sequence mesh, or None when
     sequence parallelism is off (the attention op shards inside)."""
@@ -753,22 +733,18 @@ class TrainStep(object):
                     step_amp,
                     in_shardings=self._in_shardings,
                     out_shardings=self._out_shardings,
-                    donate_argnums=(0, 1, 2, 3),
-                    compiler_options=_xla_options())
+                    donate_argnums=(0, 1, 2, 3))
             else:
                 self._in_shardings = (param_sh, state_sh, None, batch_sh,
                                       rep, None, None)
                 self._step = jax.jit(
                     step,
                     in_shardings=self._in_shardings,
-                    donate_argnums=(0, 1, 2),
-                    compiler_options=_xla_options())
+                    donate_argnums=(0, 1, 2))
         elif self._has_scale:
-            self._step = jax.jit(step_amp, donate_argnums=(0, 1, 2, 3),
-                                 compiler_options=_xla_options())
+            self._step = jax.jit(step_amp, donate_argnums=(0, 1, 2, 3))
         else:
-            self._step = jax.jit(step, donate_argnums=(0, 1, 2),
-                                 compiler_options=_xla_options())
+            self._step = jax.jit(step, donate_argnums=(0, 1, 2))
 
     # ---------------------------------------------------------- ZeRO views
     def _chunk(self, size):
@@ -1197,11 +1173,9 @@ class TrainStep(object):
                         + shardings[bi + 1:]
                 fn = jax.jit(many, in_shardings=shardings,
                              out_shardings=self._out_shardings,
-                             donate_argnums=self._donate,
-                             compiler_options=_xla_options())
+                             donate_argnums=self._donate)
             else:
-                fn = jax.jit(many, donate_argnums=self._donate,
-                             compiler_options=_xla_options())
+                fn = jax.jit(many, donate_argnums=self._donate)
             self._multi_cache[cache_key] = fn
             self._san_cache.miss({"num_steps": num_steps,
                                   "stacked": stacked,
@@ -1319,16 +1293,13 @@ class TrainStep(object):
                 fn = jax.jit(self._mon_fn,
                              in_shardings=self._in_shardings,
                              out_shardings=self._out_shardings + (None,),
-                             donate_argnums=(0, 1, 2, 3),
-                             compiler_options=_xla_options())
+                             donate_argnums=(0, 1, 2, 3))
             else:
                 fn = jax.jit(self._mon_fn,
                              in_shardings=self._in_shardings,
-                             donate_argnums=(0, 1, 2),
-                             compiler_options=_xla_options())
+                             donate_argnums=(0, 1, 2))
         else:
-            fn = jax.jit(self._mon_fn, donate_argnums=self._donate,
-                         compiler_options=_xla_options())
+            fn = jax.jit(self._mon_fn, donate_argnums=self._donate)
         self._mon_cache[key] = fn
         self._san_mon_cache.miss({"trace_env": key})
         return fn

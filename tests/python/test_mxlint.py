@@ -128,12 +128,11 @@ def test_ckey_repo_caches_cover_their_trace_reads():
         p.file("mxnet_tpu/executor.py"), "_Lowered.run"))
     assert reads, "expected trace-time env reads in _Lowered.run"
     tv = rule_ckey._project_trace_vars(p)
-    ev = rule_ckey._project_env_attr_vars(p)
     for rel, qual in (("mxnet_tpu/module/module.py",
                        "_fused_fit_key_fields"),
                       ("mxnet_tpu/train.py", "TrainStep.run_steps"),
                       ("mxnet_tpu/executor.py", "Executor._get_jit")):
-        covered = rule_ckey._key_vars(p, p.file(rel), qual, tv, ev)
+        covered = rule_ckey._key_vars(p, p.file(rel), qual, tv)
         assert reads <= covered, (rel, qual, sorted(reads - covered))
 
 

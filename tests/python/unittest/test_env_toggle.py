@@ -1,93 +1,45 @@
-"""Env A/B levers must take effect AFTER a prior jit compile.
+"""An env var read while a program is traced must take effect AFTER a
+prior compile.
 
-The hazard class (mxlint JIT001): an ``MXNET_*`` read inside a jit-traced
-body freezes the first-seen value into every cached program.  The fix has
-two prongs, each pinned here against its previously-frozen dispatch path:
-
-- ``OpDef.env_attrs``: ``MXNET_POOL_MASK_BWD`` resolves into the attr
-  dict at dispatch time, so the imperative jit cache
-  (``ops/registry._JIT_CACHE``) keys on the CURRENT value — before the
-  hoist, the first compile froze the flag for the process lifetime;
-- ``base.trace_env_key()``: every executor jit keys its cache on the
-  snapshot of ``base.TRACE_ENV_DEFAULTS``, so toggling e.g.
-  ``MXNET_STEM_S2D`` between calls retraces instead of reusing the stale
-  lowering (and the s2d lever genuinely selects a different program —
-  checked on the lowered HLO).
+The hazard (mxlint JIT001): an ``MXNET_*`` read inside a jit-traced body
+freezes the first value seen into every cached program.  The contract that
+prevents it is one row of ``base.TRACE_ENV_DEFAULTS``: every jit cache that
+traces ``executor._Lowered.run`` keys on ``base.trace_env_key()``.  Pinned
+here on the two lowering switches that are left, ``MXNET_CONV_LAYOUT`` and
+``MXNET_STEM_FUSE`` (their other values are the reference lowerings the
+parity tests compare against), through each such cache: one bound
+executor's, ``TrainStep.run_steps``' chunk cache, and the fused fit's step.
+And neither switch is a no-op: each selects a different lowered program.
 """
 import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu.ops import registry
+from mxnet_tpu import models
+from mxnet_tpu.base import TRACE_ENV_DEFAULTS, trace_env_key
+
+# each switch with the value that is not its default
+TOGGLES = [("MXNET_CONV_LAYOUT", "NCHW"), ("MXNET_STEM_FUSE", "0")]
 
 
-def _tied_pool_grad():
-    """d(data) of sum(maxpool(x)) on an all-tied 2x2 window via the
-    imperative jit cache — the path that used to freeze the flag."""
-    op = registry.get_op("Pooling")
-    attrs = op.normalize_attrs({"kernel": (2, 2), "stride": (2, 2),
-                                "pool_type": "max"})
-    fn = registry.jitted(op, attrs, is_train=True)
-    x = jnp.zeros((1, 1, 2, 2), jnp.float32)
-    return np.asarray(jax.grad(lambda xx: jnp.sum(fn(xx)))(x))
-
-
-def test_pool_mask_bwd_toggle_after_compile_imperative(monkeypatch):
-    monkeypatch.delenv("MXNET_POOL_MASK_BWD", raising=False)
-    g_native = _tied_pool_grad()          # compiles with the flag OFF
-    assert (g_native != 0).sum() == 1     # select-and-scatter: first only
-
-    monkeypatch.setenv("MXNET_POOL_MASK_BWD", "1")
-    g_mask = _tied_pool_grad()            # must NOT reuse the stale program
-    assert (g_mask != 0).all(), g_mask    # reference ties: every max wins
-
-    monkeypatch.setenv("MXNET_POOL_MASK_BWD", "0")
-    g_back = _tied_pool_grad()            # and back again
-    assert (g_back != 0).sum() == 1
-
-
-def test_pool_mask_bwd_toggle_after_compile_executor(monkeypatch):
-    """Same toggle through ONE bound symbolic executor: the jit cache is
-    keyed by base.trace_env_key(), so the second backward retraces."""
-    monkeypatch.delenv("MXNET_POOL_MASK_BWD", raising=False)
-    net = mx.sym.Pooling(mx.sym.Variable("data"), kernel=(2, 2),
-                         stride=(2, 2), pool_type="max")
-    ex = net.simple_bind(mx.cpu(), data=(1, 1, 2, 2), grad_req="write")
-    x = mx.nd.zeros((1, 1, 2, 2))         # one all-tied window
-    head = mx.nd.ones((1, 1, 1, 1))
-
-    ex.forward(is_train=True, data=x)
-    ex.backward(head)
-    assert (ex.grad_dict["data"].asnumpy() != 0).sum() == 1
-
-    monkeypatch.setenv("MXNET_POOL_MASK_BWD", "1")
-    n_compiled = len(ex._jit_cache)
-    ex.forward(is_train=True, data=x)
-    ex.backward(head)
-    assert len(ex._jit_cache) > n_compiled        # toggle forced a retrace
-    g = ex.grad_dict["data"].asnumpy()
-    assert (g != 0).all(), g
-
-
-def test_stem_s2d_toggle_retraces_executor(monkeypatch):
-    """MXNET_STEM_S2D is numerically an A/B formulation (same outputs), so
-    'takes effect' here means: the executor retraces under the new key and
-    the results stay identical."""
-    monkeypatch.delenv("MXNET_STEM_S2D", raising=False)
-    net = mx.sym.SoftmaxOutput(
+def _stem_net():
+    """The ResNet stem the peephole takes: bn_data -> conv0 (7x7/s2)."""
+    return mx.sym.SoftmaxOutput(
         mx.sym.Flatten(mx.sym.Convolution(
             mx.sym.BatchNorm(mx.sym.Variable("data"), fix_gamma=True,
                              eps=2e-5, name="bn_data"),
             num_filter=4, kernel=(7, 7), stride=(2, 2), pad=(3, 3),
             no_bias=True, name="conv0")), name="softmax")
-    ex = net.simple_bind(mx.cpu(), data=(2, 3, 16, 16), softmax_label=(2,),
-                         grad_req={"data": "null", "softmax_label": "null",
-                                   "bn_data_gamma": "null",
-                                   "bn_data_beta": "write",
-                                   "conv0_weight": "write"})
+
+
+def _bound_stem():
+    ex = _stem_net().simple_bind(
+        mx.cpu(), data=(2, 3, 16, 16), softmax_label=(2,),
+        grad_req={"data": "null", "softmax_label": "null",
+                  "bn_data_gamma": "null", "bn_data_beta": "write",
+                  "conv0_weight": "write"})
     rs = np.random.RandomState(0)
     ex.arg_dict["bn_data_gamma"][:] = np.ones(3, np.float32)
     ex.arg_dict["conv0_weight"][:] = \
@@ -99,57 +51,121 @@ def test_stem_s2d_toggle_retraces_executor(monkeypatch):
         ex.forward(is_train=True, data=x, softmax_label=y)
         ex.backward()
         return (ex.outputs[0].asnumpy().copy(),
-                ex.grad_dict["conv0_weight"].asnumpy().copy())
+                ex.grad_dict["conv0_weight"].asnumpy().copy(),
+                ex.grad_dict["bn_data_beta"].asnumpy().copy())
+    return ex, step
 
-    out0, dw0 = step()
+
+def test_trace_key_holds_the_switches_and_the_monitor():
+    assert [n for n, _ in TRACE_ENV_DEFAULTS] == \
+        ["MXNET_CONV_LAYOUT", "MXNET_STEM_FUSE", "MXNET_MONITOR"]
+
+
+@pytest.mark.parametrize("var,value", TOGGLES)
+def test_toggle_retraces_bound_executor(var, value, monkeypatch):
+    """Both values compute the same function, so 'takes effect' means: the
+    one bound executor retraces under the new key, and back again it finds
+    the first program."""
+    monkeypatch.delenv(var, raising=False)
+    ex, step = _bound_stem()
+    out0, dw0, db0 = step()
     n_compiled = len(ex._jit_cache)
-    out0b, _ = step()
+    step()
     assert len(ex._jit_cache) == n_compiled       # warm cache: no retrace
 
-    monkeypatch.setenv("MXNET_STEM_S2D", "1")
-    out1, dw1 = step()
+    monkeypatch.setenv(var, value)
+    out1, dw1, db1 = step()
     assert len(ex._jit_cache) > n_compiled        # toggle keyed a retrace
     np.testing.assert_allclose(out1, out0, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(dw1, dw0, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(db1, db0, rtol=1e-4, atol=1e-5)
+
+    n_both = len(ex._jit_cache)
+    monkeypatch.delenv(var)
+    step()
+    assert len(ex._jit_cache) == n_both
 
 
-def test_stem_s2d_selects_a_different_program():
-    """The lever is not a no-op: on the eligible 7x7/s2 stem the s2d
-    lowering packs the input (4x channels, stride-1 conv), so the lowered
-    HLO differs from the direct strided conv."""
-    from mxnet_tpu.ops.nn import input_bn_conv
+def test_layout_toggle_retraces_run_steps_chunk(monkeypatch):
+    """(test_sanitize.py toggles MXNET_STEM_FUSE through this cache and
+    the next; the layout goes through them here, on a conv graph.)"""
+    from mxnet_tpu.train import TrainStep
+    var, value = TOGGLES[0]
+    monkeypatch.delenv(var, raising=False)
+    ts = TrainStep(_stem_net(), mx.optimizer.SGD(learning_rate=0.1))
+    state = ts.init({"data": (2, 3, 16, 16)}, {"softmax_label": (2,)})
     rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.rand(2, 16, 16, 3).astype(np.float32))
-    w = jnp.asarray(rs.rand(4, 3, 7, 7).astype(np.float32))
-    b = jnp.asarray(rs.rand(3).astype(np.float32))
+    batch = {"data": rs.rand(2, 3, 16, 16).astype(np.float32),
+             "softmax_label": np.array([1.0, 0.0], np.float32)}
 
-    def lowered(s2d):
-        fn = jax.jit(lambda xx, bb, ww: input_bn_conv(
-            xx, bb, ww, 2e-5, (7, 7), (2, 2), (3, 3), s2d=s2d))
-        return fn.lower(x, b, w).as_text()
-
-    direct, packed = lowered(False), lowered(True)
-    assert direct != packed
-    # the packed path convolves a 12-channel (4*3) space-to-depth input
-    assert "2,8,8,12" in packed.replace(" ", "") or "12" in packed
-    # and the two programs agree numerically
-    o0, m0, v0 = jax.jit(lambda: input_bn_conv(
-        x, b, w, 2e-5, (7, 7), (2, 2), (3, 3), s2d=False))()
-    o1, m1, v1 = jax.jit(lambda: input_bn_conv(
-        x, b, w, 2e-5, (7, 7), (2, 2), (3, 3), s2d=True))()
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o0),
-                               rtol=1e-5, atol=1e-5)
+    def chunk(state):
+        return ts.run_steps(*state, batch, num_steps=1)[:3]
+    state = chunk(chunk(state))
+    assert len(ts._multi_cache) == 1
+    before = trace_env_key()
+    monkeypatch.setenv(var, value)
+    state = chunk(state)
+    assert set(ts._multi_cache) == {(1, False, before),
+                                    (1, False, trace_env_key())}
+    assert all(np.isfinite(np.asarray(v)).all() for v in state[0].values())
 
 
-def test_env_attr_explicit_wins_over_env(monkeypatch):
-    """An explicitly-passed attr beats the env lever (resolve_env_attrs
-    is a default-filler, not an override)."""
-    monkeypatch.setenv("MXNET_POOL_MASK_BWD", "1")
-    op = registry.get_op("Pooling")
-    attrs = op.normalize_attrs({"kernel": (2, 2), "stride": (2, 2),
-                                "pool_type": "max", "mask_bwd": False})
-    resolved = op.resolve_env_attrs(attrs)
-    assert resolved["mask_bwd"] is False
-    unset = op.normalize_attrs({"kernel": (2, 2), "stride": (2, 2),
-                                "pool_type": "max"})
-    assert op.resolve_env_attrs(unset)["mask_bwd"] is True
+def test_layout_toggle_rebuilds_fused_fit_step(monkeypatch):
+    var, value = TOGGLES[0]
+    monkeypatch.delenv(var, raising=False)
+    rs = np.random.RandomState(0)
+    x = rs.randn(8, 1, 16, 16).astype(np.float32)
+    y = rs.randint(0, 4, 8).astype(np.float32)
+    mod = mx.Module(models.get_lenet(num_classes=4))
+
+    def fit():
+        mod.fit(mx.io.NDArrayIter(x, y, batch_size=4), num_epoch=1,
+                optimizer_params={"learning_rate": 0.01})
+        return mod._fused_ts_cache[1]
+    first = fit()
+    assert fit() is first                 # same key: the step is kept
+    monkeypatch.setenv(var, value)
+    assert fit() is not first             # new key: a step traced anew
+
+
+def _lowered_stem_grad():
+    """The lowered text of the stem's forward and backward, as an executor
+    traces it with ``data`` needing no gradient."""
+    from mxnet_tpu.executor import _Lowered
+    net = _stem_net()
+    low = _Lowered(net)
+    shapes, _, aux_shapes = net.infer_shape(data=(2, 3, 16, 16),
+                                            softmax_label=(2,))
+    args = {n: np.ones(s, np.float32)
+            for n, s in zip(net.list_arguments(), shapes)}
+    aux = {n: np.ones(s, np.float32)
+           for n, s in zip(net.list_auxiliary_states(), aux_shapes)}
+    learned = ("bn_data_beta", "conv0_weight")
+
+    def loss(grad_args):
+        outs, _ = low.run(dict(args, **grad_args), aux,
+                          jax.random.PRNGKey(0), True,
+                          no_grad_inputs=("data", "softmax_label"))
+        return outs[0].sum()
+    return jax.jit(jax.grad(loss)).lower(
+        {n: args[n] for n in learned}).as_text()
+
+
+def test_conv_layout_selects_a_different_program(monkeypatch):
+    monkeypatch.delenv("MXNET_CONV_LAYOUT", raising=False)
+    channel_last = _lowered_stem_grad()
+    monkeypatch.setenv("MXNET_CONV_LAYOUT", "NCHW")
+    logical = _lowered_stem_grad()
+    assert "[b, 0, 1, f]x" in channel_last and "[b, f, 0, 1]x" in logical
+    assert "[b, f, 0, 1]x" not in channel_last
+
+
+def test_stem_fuse_selects_a_different_program(monkeypatch):
+    """Fused, the backward holds the weight gradient's convolution alone;
+    unfused it also convolves back into the 3-channel input grid."""
+    monkeypatch.delenv("MXNET_STEM_FUSE", raising=False)
+    fused = _lowered_stem_grad()
+    monkeypatch.setenv("MXNET_STEM_FUSE", "0")
+    unfused = _lowered_stem_grad()
+    assert fused.count("stablehlo.convolution") == 2
+    assert unfused.count("stablehlo.convolution") == 3

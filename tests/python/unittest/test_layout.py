@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
+from mxnet_tpu import models
 from mxnet_tpu.executor import _Lowered
 from mxnet_tpu import random as mxr
 
@@ -26,43 +27,72 @@ def f64():
     jax.config.update("jax_enable_x64", False)
 
 
-def _train_step_params(layout, net, dshape, nclass, seed=0):
-    os.environ["MXNET_CONV_LAYOUT"] = layout
-    try:
-        from mxnet_tpu.train import TrainStep
-        opt = mx.optimizer.SGD(learning_rate=0.1, momentum=0.9)
-        ts = TrainStep(net, opt)
-        params, state, aux = ts.init({"data": dshape},
-                                     {"softmax_label": (dshape[0],)})
-        params = {k: v.astype(jnp.float64) for k, v in params.items()}
-        aux = {k: v.astype(jnp.float64) for k, v in aux.items()}
-        rng = np.random.RandomState(seed)
-        bd = {"data": jnp.asarray(rng.uniform(-1, 1, dshape)),
-              "softmax_label": jnp.asarray(
-                  rng.randint(0, nclass, (dshape[0],)).astype(np.float64))}
-        mxr.seed(seed)
-        key = mxr.next_key()
-        hyper = ts.fopt.hyper(0)
-        p, s, a, outs = jax.jit(ts._step_fn)(params, state, aux, bd, key,
-                                             hyper, np.int32(1))
-        return p, a, outs
-    finally:
-        os.environ.pop("MXNET_CONV_LAYOUT", None)
+def _train_step_params(layouts, net, dshape, nclass, seed=0):
+    """One float64 train step of ``net`` under each of ``layouts``, from
+    one initialisation: the layout is read when the step is traced."""
+    from mxnet_tpu.train import TrainStep
+    opt = mx.optimizer.SGD(learning_rate=0.1, momentum=0.9)
+    ts = TrainStep(net, opt)
+    # values straight from numpy: the initializers compile a program for
+    # every shape, which is most of a small case's time and not its subject
+    rng = np.random.RandomState(seed)
+    arg_shapes, _, aux_shapes = net.infer_shape(
+        data=dshape, softmax_label=(dshape[0],))
+    params = {}
+    for n, shape in zip(net.list_arguments(), arg_shapes):
+        if n.endswith("weight"):
+            params[n] = rng.randn(*shape) * np.sqrt(2.0 / np.prod(shape[1:]))
+        elif n not in ("data", "softmax_label"):
+            params[n] = n.endswith("gamma") + 0.1 * rng.randn(*shape)
+    aux = {n: np.full(shape, float(n.endswith("var")))
+           for n, shape in zip(net.list_auxiliary_states(), aux_shapes)}
+    state = ts.fopt.init_state(params)
+    bd = {"data": jnp.asarray(rng.uniform(-1, 1, dshape)),
+          "softmax_label": jnp.asarray(
+              rng.randint(0, nclass, (dshape[0],)).astype(np.float64))}
+    mxr.seed(seed)
+    key = mxr.next_key()
+    hyper = ts.fopt.hyper(0)
+    results = []
+    for layout in layouts:
+        os.environ["MXNET_CONV_LAYOUT"] = layout
+        try:
+            # a function of its own each time: jit keeps its traces by
+            # the function, and the same one would not be traced again
+            p, s, a, outs = jax.jit(lambda *args: ts._step_fn(*args))(
+                params, state, aux, bd, key, hyper, np.int32(1))
+            results.append((p, a, outs))
+        finally:
+            os.environ.pop("MXNET_CONV_LAYOUT", None)
+    return results
 
 
-@pytest.mark.parametrize("model", ["resnet", "inception"])
+def _resnet18():
+    from mxnet_tpu.models import resnet
+    return resnet.get_symbol(num_classes=10, num_layers=18,
+                             image_shape="3,32,32")
+
+
+# each model at the smallest image its strides take: BatchNorm, residual
+# adds and Concat (resnet, inception); LRN, Dropout and Flatten after
+# pooling (alexnet); a plain conv stack (vgg); tanh (lenet)
+PARITY_MODELS = {
+    "resnet": (_resnet18, (4, 3, 32, 32)),
+    "inception": (lambda: models.get_inception_v3(num_classes=10),
+                  (2, 3, 299, 299)),
+    "alexnet": (lambda: models.get_alexnet(num_classes=10), (1, 3, 67, 67)),
+    "vgg": (lambda: models.get_vgg(num_classes=10, num_layers=11),
+            (1, 3, 32, 32)),
+    "lenet": (lambda: models.get_lenet(num_classes=10), (2, 1, 16, 16)),
+}
+
+
+@pytest.mark.parametrize("model", list(PARITY_MODELS))
 def test_nhwc_pass_parity_f64(f64, model):
-    if model == "resnet":
-        from mxnet_tpu.models import resnet
-        net = resnet.get_symbol(num_classes=10, num_layers=18,
-                                image_shape="3,32,32")
-        shape, ncls = (4, 3, 32, 32), 10
-    else:
-        from mxnet_tpu.models import inception_v3
-        net = inception_v3.get_symbol(num_classes=10)
-        shape, ncls = (2, 3, 299, 299), 10
-    p1, a1, o1 = _train_step_params("NCHW", net, shape, ncls)
-    p2, a2, o2 = _train_step_params("NHWC", net, shape, ncls)
+    build, shape = PARITY_MODELS[model]
+    net, ncls = build(), 10
+    (p1, a1, o1), (p2, a2, o2) = _train_step_params(
+        ("NCHW", "NHWC"), net, shape, ncls)
     for k in p1:
         np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p2[k]),
                                    atol=1e-9, err_msg=k)

@@ -140,19 +140,3 @@ def test_eval_step():
     bd = _batch(ts, batch=4)
     outs = ev(params, aux, bd)
     assert np.asarray(outs[0]).shape == (4, 4)
-
-
-def test_xla_options_env_parsing(monkeypatch):
-    """MXNET_XLA_OPTIONS -> compiler_options dict (perf-experiment
-    plumbing; docs/perf.md round-5 flag sweep)."""
-    from mxnet_tpu.train import _xla_options
-    monkeypatch.delenv("MXNET_XLA_OPTIONS", raising=False)
-    assert _xla_options() is None
-    monkeypatch.setenv("MXNET_XLA_OPTIONS",
-                       "xla_tpu_scoped_vmem_limit_kib=32768; "
-                       "xla_flag_b = true ;")
-    assert _xla_options() == {"xla_tpu_scoped_vmem_limit_kib": "32768",
-                              "xla_flag_b": "true"}
-    monkeypatch.setenv("MXNET_XLA_OPTIONS", "not-a-flag")
-    with pytest.raises(mx.base.MXNetError):
-        _xla_options()

@@ -11,11 +11,9 @@ whose jit lands in that cache* must appear in that cache's key
 expression.
 
 A cache's key expression "covers" a var when the key-building function
-reads it directly (``get_env("MXNET_X")``), snapshots the shared
+reads it directly (``get_env("MXNET_X")``) or snapshots the shared
 trace-env registry (``base.trace_env_key()`` — expands to every var in
-``TRACE_ENV_DEFAULTS``), or resolves registered OpDef ``env_attrs``
-(``resolve_env_attrs`` — expands to every env-backed attr in the repo,
-which land in the attr dict the key hashes).
+``TRACE_ENV_DEFAULTS``).
 
 ``CACHES`` mirrors the repo's ``sanitize.register_cache`` call sites the
 way SYNC001's ``HOT_PATHS`` mirrors its hot loops; entries whose files
@@ -96,23 +94,7 @@ def _project_trace_vars(project):
     return out
 
 
-def _project_env_attr_vars(project):
-    """Env vars registered as OpDef env_attrs anywhere in the tree —
-    resolved into the attr dict (and thus any attr-hashing key) at
-    dispatch time."""
-    out = set()
-    for fi in project.files:
-        for n in ast.walk(fi.tree):
-            if isinstance(n, ast.keyword) and n.arg == "env_attrs" \
-                    and isinstance(n.value, ast.Dict):
-                for v in n.value.values:
-                    if isinstance(v, ast.Tuple) and v.elts \
-                            and isinstance(v.elts[0], ast.Constant):
-                        out.add(v.elts[0].value)
-    return out
-
-
-def _key_vars(project, fi, qualname, trace_vars, env_attr_vars):
+def _key_vars(project, fi, qualname, trace_vars):
     """Env vars the key expression covers, or None when the key fn is
     missing from this tree.  Nested function defs are EXCLUDED: for key
     sites that are whole hot functions (``TrainStep.run_steps``) the
@@ -140,8 +122,6 @@ def _key_vars(project, fi, qualname, trace_vars, env_attr_vars):
             d = fi.dotted(n)
         if d.endswith("trace_env_key"):
             covered |= trace_vars
-        elif d.endswith("resolve_env_attrs"):
-            covered |= env_attr_vars
     return covered
 
 
@@ -185,14 +165,12 @@ def _ops_roots(project):
 def run(project):
     findings = []
     trace_vars = _project_trace_vars(project)
-    env_attr_vars = _project_env_attr_vars(project)
     for spec in CACHES:
         key_rel, key_qual = spec["key"]
         key_fi = project.file(key_rel)
         if key_fi is None:
             continue
-        covered = _key_vars(project, key_fi, key_qual, trace_vars,
-                            env_attr_vars)
+        covered = _key_vars(project, key_fi, key_qual, trace_vars)
         if covered is None:
             continue
         key_node = key_fi.functions()[key_qual]
@@ -214,7 +192,6 @@ def run(project):
                     "%s is read at trace time by %s (%s) but missing "
                     "from the %s key expression — a toggle would silently "
                     "reuse the stale compiled program; add it to the "
-                    "cache key, register it in base.TRACE_ENV_DEFAULTS, "
-                    "or resolve it via OpDef env_attrs"
+                    "cache key or register it in base.TRACE_ENV_DEFAULTS"
                     % (var, root_qual, root_fi.rel, spec["name"])))
     return findings

@@ -3,10 +3,9 @@
 Two halves:
 
 1. Read discipline: every MXNET_* read in product code goes through
-   ``base.get_env`` (or the registered OpDef ``env_attrs`` /
-   ``base.TRACE_ENV_DEFAULTS`` tables).  Direct ``os.environ`` /
-   ``os.getenv`` reads bypass the one choke point the typed parsing,
-   docs, and trace-key machinery hang off.
+   ``base.get_env`` (or the ``base.TRACE_ENV_DEFAULTS`` table).  Direct
+   ``os.environ`` / ``os.getenv`` reads bypass the one choke point the
+   typed parsing, docs, and trace-key machinery hang off.
 
 2. Bidirectional code <-> docs/env_var.md sync: every var the code reads
    appears in a doc table row; every table row has a live reader.  Vars
@@ -45,15 +44,7 @@ def _code_readers(project):
         for n in ast.walk(fi.tree):
             if astutil.is_env_read(fi, n):
                 add(astutil.env_read_var(fi, n), fi, n.lineno)
-        # registration tables: OpDef env_attrs={attr: ("MXNET_X", dflt)}
-        # and base.TRACE_ENV_DEFAULTS = (("MXNET_X", dflt), ...)
-        for n in ast.walk(fi.tree):
-            if isinstance(n, ast.keyword) and n.arg == "env_attrs" \
-                    and isinstance(n.value, ast.Dict):
-                for v in n.value.values:
-                    if isinstance(v, ast.Tuple) and v.elts \
-                            and isinstance(v.elts[0], ast.Constant):
-                        add(v.elts[0].value, fi, v.lineno)
+        # the registration table base.TRACE_ENV_DEFAULTS
         for var, line in astutil.trace_env_vars(fi).items():
             add(var, fi, line)
     return readers

@@ -5,11 +5,10 @@ besides building the computation is frozen into the compiled program.
 Inside traced code this rule flags:
 
   * environment reads (get_env / os.environ / os.getenv) — the flag value
-    freezes at first compile; resolve it at dispatch time (OpDef
-    env_attrs) or key the jit cache on base.trace_env_key().  Reads of
-    vars registered in base.TRACE_ENV_DEFAULTS are exempt inside
-    TRACE_KEYED_FILES (the executor lowering), where that key is already
-    on every cache lookup.
+    freezes at first compile; resolve it at dispatch time or key the jit
+    cache on base.trace_env_key().  Reads of vars registered in
+    base.TRACE_ENV_DEFAULTS are exempt inside TRACE_KEYED_FILES (the
+    executor lowering), where that key is already on every cache lookup.
   * wall-clock reads (time.time / perf_counter / monotonic)
   * print() — executes at trace, silent on every cached call
   * telemetry emission (counter/gauge/span/scalar/histogram) — records
@@ -146,8 +145,8 @@ def _violations(fi, q, node, findings, trace_keyed_vars=()):
             findings.append(Finding(
                 RULE, fi.rel, n.lineno, q,
                 "env read (%s) inside jit-traced code freezes the value at "
-                "first compile; resolve at dispatch time (OpDef env_attrs) "
-                "or key the cache via base.trace_env_key()" % var))
+                "first compile; resolve at dispatch time or key the cache "
+                "via base.trace_env_key()" % var))
         elif isinstance(n, ast.Call):
             d = fi.dotted(n.func)
             if d in _CLOCKS:

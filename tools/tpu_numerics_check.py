@@ -2,10 +2,9 @@
 (no interpret mode) on the TPU and compared against its XLA formulation at
 bf16-appropriate tolerances — flash attention forward and both backward
 kernels, including the corners of its shape guard (those with f32 operands
-too), NormConv at the four ResNet-50 stage shapes, and the grouped products
-of the routed experts at the benchmark cell's own shape.  The interpret-mode
-twins of these checks run on the CPU harness (test_pallas.py,
-test_norm_conv.py, test_moe.py).
+too), and the grouped products of the routed experts at the benchmark cell's
+own shape.  The interpret-mode twins of these checks run on the CPU harness
+(test_pallas.py, test_moe.py).
 
 Run on a machine with a chip:  python tools/tpu_numerics_check.py
 Prints one PASS line per check; exits non-zero on any mismatch, on a shape
@@ -28,13 +27,6 @@ FLASH_SHAPES = [(2, 4, 512, 64, False),
                 (1, 1, 8192, 128, True),
                 (1, 1, 16384, 64, True),
                 (1, 1, 4096, 256, True)]
-
-# (H=W, K, stride, pad, Cin, Cout): one conv of each ResNet-50 stage kind
-NORM_CONV_SHAPES = [(56, 1, 1, 0, 256, 64),
-                    (56, 3, 1, 1, 64, 64),
-                    (56, 3, 2, 1, 128, 128),
-                    (56, 1, 2, 0, 256, 512)]
-
 
 # the routed experts of nemotron-twotower-steps-t4096: (experts held, hidden,
 # expert width, rows of a block), and each held expert's rows in three steps:
@@ -90,33 +82,6 @@ def check_flash_attention():
                       (b, h_, t, d, causal), jnp.dtype(dtype).name,
                       flash_blocks(t, d, jnp.dtype(dtype).itemsize),
                       *errs), flush=True)
-
-
-def check_norm_conv():
-    import jax
-    import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_conv import norm_conv, norm_conv_available
-
-    for (h, k, s, p, cin, cout) in NORM_CONV_SHAPES:
-        assert norm_conv_available((8, h, h, cin), (k, k, cin, cout),
-                                   (s, s), (p, p)), (h, k, s, p, cin, cout)
-        rng = np.random.RandomState(1)
-        x = jnp.asarray(rng.randn(8, h, h, cin).astype(np.float32)) \
-            .astype(jnp.bfloat16)
-        w = jnp.asarray((rng.randn(k, k, cin, cout) * 0.05)
-                        .astype(np.float32)).astype(jnp.bfloat16)
-        sc = jnp.asarray(rng.rand(cin).astype(np.float32) + 0.5)
-        sh = jnp.asarray(rng.randn(cin).astype(np.float32))
-
-        def run(up):
-            return jax.jit(lambda *a: norm_conv(
-                *a, kernel=k, stride=s, pad=p, relu=True, prologue=True,
-                stats=True, use_pallas=up))(x, w, sc, sh)
-        for name, a, b in zip(("y", "sum", "sumsq"), run(True), run(False)):
-            err = _rel(a, b)
-            assert err < 2e-2, "norm_conv %s rel err %.2e" % (name, err)
-        print("PASS norm_conv k=%d s=%d %dx%d %d->%d" % (k, s, h, h, cin,
-                                                         cout), flush=True)
 
 
 def check_grouped_products():
@@ -184,6 +149,5 @@ if __name__ == "__main__":
     from mxnet_tpu.base import enable_compile_cache
     enable_compile_cache()
     check_flash_attention()
-    check_norm_conv()
     check_grouped_products()
     print("ALL TPU NUMERICS CHECKS PASSED")
