@@ -31,8 +31,20 @@ in all three kernels.
 
 Used by ``dot_product_attention`` (ops/attention.py) on TPU for long
 sequences; everything is shape-guarded so XLA's fused attention remains the
-fallback.  Tested in Pallas interpret mode on the CPU harness and compiled on
-the chip by ``tools/tpu_numerics_check.py``.
+fallback.
+
+``grouped_matmul`` / ``grouped_matmul_t``: the routed experts' products over
+rows sorted by expert (ops/moe.py); tiles from ``grouped_blocks``, guard
+``grouped_available``.
+
+``ssd_scan_fwd`` / ``ssd_scan_bwd``: the chunked state-space scan of
+``ssm_scan`` (ops/ssm.py) as three kernels, ``mxtpu_ssd_fwd``, ``_states``
+and ``_bwd``: the (L, L) blocks and the carried state never leave VMEM;
+heads a grid step from ``ssd_blocks``, guard ``ssd_available``.
+
+All of them are tested in Pallas interpret mode on the CPU harness, compiled
+for a described v5e by ``test_pallas_tpu_compile.py`` and run against their
+plain forms on the chip by ``tools/tpu_numerics_check.py``.
 """
 from __future__ import annotations
 
@@ -46,7 +58,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_available", "flash_blocks",
            "grouped_matmul", "grouped_matmul_t", "grouped_available",
-           "grouped_blocks"]
+           "grouped_blocks", "ssd_scan_fwd", "ssd_scan_bwd", "ssd_available",
+           "ssd_blocks"]
 
 _NEG_INF = -1e30
 
@@ -639,3 +652,413 @@ def grouped_matmul_t(lhs, rhs, tile_group, tile_fill, live, groups,
         interpret=interpret,
         name="mxtpu_tgmm",
     )(tile_group, tile_fill, lhs, rhs)
+
+
+# ------------------------------------------------------- state-space scan
+# The chunked scan of ``ops/ssm.py`` (Mamba-2), forward and backward, with
+# nothing of size (L, L) and no carried (P, N) state in HBM.  A grid step
+# takes one chunk of L rows of ``heads`` heads of one group, straight out of
+# the unsplit ``xBC`` array by column blocks (x: heads * P columns; the
+# group's B and C: N columns each), and the chunk axis is the grid's last,
+# sequential one: the state of those heads lives in a float32 VMEM scratch
+# of (heads * P, N) from chunk to chunk (its gradient, in the backward, from
+# the last chunk to the first).
+#
+# A head narrower than the 128 lanes shares a tile with its neighbours
+# (P = 64: two heads side by side).  What differs from head to head inside
+# a tile, the (L, L) masked decay, multiplies the whole tile and the head's
+# own lanes are selected from the result; what the heads share (``C S^T``,
+# ``xw^T B``) is one product a tile.  No lane is shifted.
+#
+# The per-step scalars are float32 arrays of (T, H), made outside
+# (``ops/ssm.py``): ``dt`` after its softplus and ``acs``, the running sum
+# of ``A dt`` inside each chunk.  The kernels take both twice, as columns
+# (B, H / heads, T, 2 heads) and as rows (B, H / heads, 2 heads, T), ``dt``
+# first: a decay ``exp(acs_l - acs_s)`` needs a column and a row, and a
+# (L, 1) column cannot be turned into a (1, L) row without a transpose.  The
+# backward forms its (L, L) blocks transposed, at (s, l), so that ``m^T dy``
+# needs no transpose either; it hands the gradients of dt and acs back as
+# columns, and as rows the part of acs's that it sums along lanes, and
+# autodiff carries them through the running sum, the softplus and
+# ``-exp(A_log)``.
+
+_SSD_HEADS = 8          # at most: a grid step's loop over its heads is unrolled
+
+
+def _tile_heads(p):
+    """Heads of ``p`` lanes side by side in one 128-lane tile."""
+    return max(1, 128 // p)
+
+
+def _ssd_vmem(heads, p, n, chunk, itemsize):
+    """What one grid step of the backward, the largest of the three, keeps
+    in VMEM: two buffers of x, dy, dx, B, C, of the float32 parts of dB and
+    dC and of the entering state, the scratch, the four arrays of scalars
+    padded to 128 lanes, and the float32 temporaries of its body ((L, L):
+    a dozen; (L, tile): a dozen)."""
+    width = heads * p
+    blocks = 2 * chunk * (3 * width + 2 * n) * itemsize \
+        + 2 * 2 * chunk * n * 4 + 3 * width * n * 4
+    scalars = 4 * 2 * max(chunk, 2 * heads) * 128 * 4
+    return blocks + scalars + 12 * chunk * (chunk + max(p, 128)) * 4
+
+
+def ssd_blocks(t, h, p, g, n, chunk, itemsize):
+    """Heads a grid step of the three scan kernels works, for T steps of H
+    heads of P in G groups with a state of N, chunks of ``chunk``, operands
+    of ``itemsize`` bytes: the most (8 at most) that divide a group's
+    heads, fill whole 128-lane tiles and fit the VMEM budget.  None where
+    the kernels do not apply: T no multiple of the chunk, a chunk that is
+    no multiple of 128, a P that neither divides nor is a multiple of the
+    128 lanes, an N that is no multiple of them or does not divide H P (B
+    and C are column blocks of N of the unsplit array)."""
+    if min(t, h, p, g, n, chunk) <= 0 or h % g or t % chunk or chunk % 128 \
+            or n % 128 or (h * p) % n or (128 % p and p % 128):
+        return None
+    return next((k for k in range(min(h // g, _SSD_HEADS), 0, -1)
+                 if (h // g) % k == 0 and k % _tile_heads(p) == 0
+                 and _ssd_vmem(k, p, n, chunk, itemsize) <= _VMEM_BUDGET),
+                None)
+
+
+def ssd_available(t, h, p, g, n, chunk, itemsize):
+    """Shape guard of the scan kernels: ``ssd_blocks`` finds a tiling."""
+    return ssd_blocks(t, h, p, g, n, chunk, itemsize) is not None
+
+
+_SSD = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=_VMEM_BUDGET + 16 * 1024 * 1024)
+
+
+def _ssd_tiles(heads, p):
+    """[(a 128-lane tile's lanes, its heads)] of a step's ``heads`` heads."""
+    count = _tile_heads(p)
+    return [(slice(i * p, (i + count) * p), range(i, i + count))
+            for i in range(0, heads, count)]
+
+
+def _ssd_own(chunk, p):
+    """For each head of a tile, where it lies, as masks made once a grid
+    step: its lanes of a (chunk, tile) array and its rows of a (tile, 1)
+    column."""
+    count = _tile_heads(p)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (chunk, count * p), 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (count * p, 1), 0)
+    return [[(at >= k * p) & (at < (k + 1) * p) for k in range(count)]
+            for at in (lanes, rows)]
+
+
+def _spread(values, own):
+    """One array from a tile's heads' values, each broadcast over the
+    head's own positions (``own``: the lanes' or the rows' masks)."""
+    out = jnp.broadcast_to(values[-1], own[0].shape)
+    for mask, value in zip(own[:-1], values):
+        out = jnp.where(mask, value, out)
+    return out
+
+
+def _only(mask, value, count):
+    """``value`` on one head's positions of its tile, zero on the other
+    heads'."""
+    return value if count == 1 else jnp.where(mask, value, 0.0)
+
+
+def _ssd_ends(col_ref, r, heads):
+    """Head ``r``'s chunk as its end sees it, float32: ``left`` (L, 1), the
+    decay from each row to the chunk's end, ``dt`` (L, 1), and ``a_end``
+    (1, 1), the log of the whole chunk's decay."""
+    chunk = col_ref.shape[0]
+    a_c = col_ref[:, heads + r:heads + r + 1]
+    a_end = col_ref[chunk - 1:chunk, heads + r:heads + r + 1]
+    return jnp.exp(a_end - a_c), col_ref[:, r:r + 1], a_end
+
+
+def _ssd_causal(chunk, transposed=False):
+    """s <= l over a chunk's (l, s) block; ``transposed``, over (s, l).
+    Made once a grid step."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return rows <= cols if transposed else cols <= rows
+
+
+def _ssd_decay(col_ref, row_ref, r, heads, causal, transposed=False):
+    """Head ``r``'s masked decay inside the chunk, (L, L) float32:
+    ``exp(acs_l - acs_s)`` for s <= l, else 0, at (l, s); ``transposed``,
+    at (s, l), with ``causal`` the same way.  Either way a column of acs
+    against a row of it."""
+    a_c = col_ref[:, heads + r:heads + r + 1]
+    a_r = row_ref[heads + r:heads + r + 1, :]
+    return jnp.exp(jnp.where(causal, a_r - a_c if transposed else a_c - a_r,
+                             _NEG_INF))
+
+
+def _ssd_keep(col_ref, r, heads):
+    """What each row of head ``r``'s chunk sees of the entering state,
+    ``exp(acs)``, (L, 1)."""
+    return jnp.exp(col_ref[:, heads + r:heads + r + 1])
+
+
+def _ssd_total(ends, own_rows):
+    """The whole chunk's decay of a tile's heads down the tile's rows,
+    (tile, 1).  The exp comes after the spreading: a (1, 1) value cannot be
+    broadcast along sublanes and lanes at once."""
+    return jnp.exp(_spread([a_end for _, _, a_end in ends], own_rows))
+
+
+def _ssd_carry(s_ref, lanes, xf, b, ends, own):
+    """A tile's state at the chunk's end from the one at its start:
+    ``exp(acs_L) S + (x * to_end)^T B``, the product's operands in b's
+    dtype."""
+    to_end = _spread([left * dt for left, dt, _ in ends], own[0])
+    s_ref[lanes, :] = _ssd_total(ends, own[1]) * s_ref[lanes, :] \
+        + jax.lax.dot_general((xf * to_end).astype(b.dtype), b, _TN,
+                              preferred_element_type=jnp.float32)
+
+
+def _ssd_fwd_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, d_ref, y_ref,
+                    s_ref, *, heads, p):
+    f32 = jnp.float32
+    cd = x_ref.dtype
+    chunk = x_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    b, c = b_ref[...], c_ref[...]
+    cb = jax.lax.dot_general(c, b, _NT, preferred_element_type=f32)
+    causal, own = _ssd_causal(chunk), _ssd_own(chunk, p)
+    for lanes, tile in _ssd_tiles(heads, p):
+        x = x_ref[:, lanes]
+        xf = x.astype(f32)
+        inside = None
+        for k, r in enumerate(tile):
+            m = cb * _ssd_decay(col_ref, row_ref, r, heads, causal) \
+                * row_ref[r:r + 1, :]
+            part = jax.lax.dot_general(m.astype(cd), x, _NN,
+                                       preferred_element_type=f32)
+            inside = part if k == 0 else jnp.where(own[0][k], part, inside)
+        before = jax.lax.dot_general(c, s_ref[lanes, :].astype(cd), _NT,
+                                     preferred_element_type=f32)
+        keep = _spread([_ssd_keep(col_ref, r, heads) for r in tile], own[0])
+        y = inside + keep * before + d_ref[:, lanes] * xf
+        y_ref[:, lanes] = y.astype(y_ref.dtype)
+        _ssd_carry(s_ref, lanes, xf, b,
+                   [_ssd_ends(col_ref, r, heads) for r in tile], own)
+
+
+def _ssd_states_kernel(x_ref, b_ref, col_ref, before_ref, s_ref, *, heads,
+                       p):
+    """The state entering each chunk, and nothing else of the forward."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    before_ref[...] = s_ref[...]
+    own = _ssd_own(x_ref.shape[0], p)
+    for lanes, tile in _ssd_tiles(heads, p):
+        _ssd_carry(s_ref, lanes, x_ref[:, lanes].astype(jnp.float32),
+                   b_ref[...], [_ssd_ends(col_ref, r, heads) for r in tile],
+                   own)
+
+
+def _ssd_bwd_kernel(x_ref, b_ref, c_ref, dy_ref, col_ref, row_ref, d_ref,
+                    before_ref, dx_ref, db_ref, dc_ref, gcol_ref, grow_ref,
+                    dd_ref, ds_ref, *, heads, p):
+    """One chunk's gradients, the chunks taken last to first.  ``ds_ref``
+    carries the gradient of the state that leaves the chunk; ``dd_ref``,
+    one block for all chunks, sums dy x over the rows."""
+    f32 = jnp.float32
+    cd = x_ref.dtype
+    chunk = x_ref.shape[0]
+    count = _tile_heads(p)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    # the (L, L) blocks transposed, at (s, l): what the backward needs of
+    # them, m^T dy and sums along either axis, then takes no transpose
+    b, c = b_ref[...], c_ref[...]
+    cb = jax.lax.dot_general(b, c, _NT, preferred_element_type=f32)
+    causal, own = _ssd_causal(chunk, True), _ssd_own(chunk, p)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    dcb = jnp.zeros((chunk, chunk), f32)
+    db = jnp.zeros(db_ref.shape, f32)
+    dc = jnp.zeros(dc_ref.shape, f32)
+    for lanes, tile in _ssd_tiles(heads, p):
+        x, dy = x_ref[:, lanes], dy_ref[:, lanes]
+        xf, dyf = x.astype(f32), dy.astype(f32)
+        s, ds = before_ref[lanes, :], ds_ref[lanes, :]
+        s_cd, ds_cd = s.astype(cd), ds.astype(cd)
+        before = jax.lax.dot_general(c, s_cd, _NT,
+                                     preferred_element_type=f32)
+        dxw = jax.lax.dot_general(b, ds_cd, _NT, preferred_element_type=f32)
+        held = jnp.sum(ds * s, axis=1, keepdims=True)        # (tile, 1)
+        dy_before, dxw_x = dyf * before, dxw * xf
+        keeps, ends, inside = [], [], None
+        for k, r in enumerate(tile):
+            mine, my_rows = own[0][k], own[1][k]
+            keep = _ssd_keep(col_ref, r, heads)
+            left, dt_c, a_end = _ssd_ends(col_ref, r, heads)
+            keeps.append(keep)
+            ends.append((left, dt_c, a_end))
+            # y = m x, m = cb * decay * dt_s: dm, then what it gives cb,
+            # dt_s (along each row s) and the two ends of
+            # exp(acs_l - acs_s) (dm m: + down its column l, - along its
+            # row s)
+            decay = _ssd_decay(col_ref, row_ref, r, heads, causal, True)
+            reach = decay * dt_c
+            m = cb * reach
+            dm = jax.lax.dot_general(x, _only(mine, dyf, count).astype(cd),
+                                     _NT, preferred_element_type=f32)
+            dcb = dcb + dm * reach
+            along = jnp.sum(dm * cb * decay, axis=1, keepdims=True)
+            grow_ref[r:r + 1, :] = jnp.sum(dm * m, axis=0, keepdims=True)
+            part = jax.lax.dot_general(m.astype(cd), dy, _NN,
+                                       preferred_element_type=f32)
+            inside = part if k == 0 else jnp.where(mine, part, inside)
+            # the entering state's share of y, and the chunk's of the
+            # state that leaves: columns
+            seen = jnp.sum(_only(mine, dy_before, count), axis=1,
+                           keepdims=True)
+            dte = jnp.sum(_only(mine, dxw_x, count), axis=1, keepdims=True)
+            at_end = jnp.sum(dte * left * dt_c, axis=0, keepdims=True) \
+                + jnp.exp(a_end) * jnp.sum(_only(my_rows, held, count),
+                                           axis=0, keepdims=True)
+            gcol_ref[:, r:r + 1] = dte * left + along
+            gcol_ref[:, heads + r:heads + r + 1] = keep * seen \
+                - dt_c * (dte * left + along) + jnp.where(last, at_end, 0.0)
+        to_end = _spread([left * dt_c for left, dt_c, _ in ends], own[0])
+        dz = (_spread(keeps, own[0]) * dyf).astype(cd)
+        dc = dc + jax.lax.dot_general(dz, s_cd, _NN,
+                                      preferred_element_type=f32)
+        db = db + jax.lax.dot_general((xf * to_end).astype(cd), ds_cd, _NN,
+                                      preferred_element_type=f32)
+        dx_ref[:, lanes] = (inside + dxw * to_end
+                            + d_ref[:, lanes] * dyf).astype(dx_ref.dtype)
+        dd_ref[:, lanes] += jnp.sum(dyf * xf, axis=0, keepdims=True)
+        ds_ref[lanes, :] = _ssd_total(ends, own[1]) * ds \
+            + jax.lax.dot_general(dz, c, _TN, preferred_element_type=f32)
+    dcb = dcb.astype(cd)
+    db_ref[...] = db + jax.lax.dot_general(dcb, c, _NN,
+                                           preferred_element_type=f32)
+    dc_ref[...] = dc + jax.lax.dot_general(dcb, b, _TN,
+                                           preferred_element_type=f32)
+
+
+def _ssd_layout(xbc, dt, acs, d, h, p, g, chunk):
+    """What the three calls share: the heads a step works, the grid, the
+    scalars as columns and rows, ``d`` spread over its heads' lanes, and
+    the column blocks of ``xbc``: x's, B's, C's, for chunk ``c(i)``."""
+    bsz, t, width = xbc.shape
+    n = (width - h * p) // (2 * g)
+    heads = ssd_blocks(t, h, p, g, n, chunk, xbc.dtype.itemsize)
+    if heads is None:
+        raise ValueError("ssd_scan: no tiling for T=%d, H=%d, P=%d, G=%d, "
+                         "N=%d, chunk=%d (see ssd_available)"
+                         % (t, h, p, g, n, chunk))
+    f32 = jnp.float32
+    blocks, per_group = h // heads, h // g // heads
+    both = jnp.stack([dt.astype(f32), acs.astype(f32)], axis=2).reshape(
+        bsz, t, 2, blocks, heads)
+    cols = both.transpose(0, 3, 1, 2, 4).reshape(bsz, blocks, t, 2 * heads)
+    rows = both.transpose(0, 3, 2, 4, 1).reshape(bsz, blocks, 2 * heads, t)
+    lanes = jnp.repeat(d.astype(f32), p).reshape(1, h * p)
+    first_b, first_c = h * p // n, h * p // n + g
+    return heads, n, (bsz, blocks, t // chunk), cols, rows, lanes, (
+        lambda c: pl.BlockSpec((None, chunk, heads * p),
+                               lambda i, j, k: (i, c(k), j)),
+        lambda c: pl.BlockSpec((None, chunk, n), lambda i, j, k: (
+            i, c(k), first_b + j // per_group)),
+        lambda c: pl.BlockSpec((None, chunk, n), lambda i, j, k: (
+            i, c(k), first_c + j // per_group)),
+        lambda c: pl.BlockSpec((None, None, chunk, 2 * heads),
+                               lambda i, j, k: (i, j, c(k), 0)),
+        lambda c: pl.BlockSpec((None, None, 2 * heads, chunk),
+                               lambda i, j, k: (i, j, 0, c(k))),
+        pl.BlockSpec((1, heads * p), lambda i, j, k: (0, j)))
+
+
+def ssd_scan_fwd(xbc, dt, acs, d, h, p, g, chunk, interpret=False):
+    """y (B, T, H P) in xbc's dtype, the skip ``d x`` included.  xbc
+    (B, T, H P + 2 G N): x, B and C side by side; dt (B, T, H) after its
+    softplus and acs (B, T, H), the running sum of ``A dt`` inside each
+    chunk, float32; d (H,)."""
+    heads, n, grid, cols, rows, lanes, specs = _ssd_layout(
+        xbc, dt, acs, d, h, p, g, chunk)
+    x, b, c, col, row, lane = specs
+    forward = lambda k: k                                   # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_ssd_fwd_kernel, heads=heads, p=p),
+        grid=grid,
+        in_specs=[x(forward), b(forward), c(forward), col(forward),
+                  row(forward), lane],
+        out_specs=x(forward),
+        out_shape=jax.ShapeDtypeStruct(xbc.shape[:2] + (h * p,), xbc.dtype),
+        scratch_shapes=[pltpu.VMEM((heads * p, n), jnp.float32)],
+        compiler_params=_SSD,
+        interpret=interpret,
+        name="mxtpu_ssd_fwd",
+    )(xbc, xbc, xbc, cols, rows, lanes)
+
+
+def ssd_scan_bwd(xbc, dt, acs, d, dy, h, p, g, chunk, interpret=False):
+    """Gradients of ``ssd_scan_fwd`` for y's cotangent ``dy``: of xbc (in
+    its dtype), dt, acs (B, T, H) and d (H,), float32.  Two calls: the
+    state entering each chunk, (B, T / chunk, H P, N) float32, formed again
+    from the inputs; then the chunks last to first."""
+    f32 = jnp.float32
+    heads, n, grid, cols, rows, lanes, specs = _ssd_layout(
+        xbc, dt, acs, d, h, p, g, chunk)
+    x, b, c, col, row, lane = specs
+    bsz, t, _ = xbc.shape
+    blocks, nc = grid[1], grid[2]
+    forward = lambda k: k                                   # noqa: E731
+    back = lambda k: nc - 1 - k                             # noqa: E731
+    state = lambda c: pl.BlockSpec(                         # noqa: E731
+        (None, None, heads * p, n), lambda i, j, k: (i, c(k), j, 0))
+    before = pl.pallas_call(
+        functools.partial(_ssd_states_kernel, heads=heads, p=p),
+        grid=grid,
+        in_specs=[x(forward), b(forward), col(forward)],
+        out_specs=state(forward),
+        out_shape=jax.ShapeDtypeStruct((bsz, nc, h * p, n), f32),
+        scratch_shapes=[pltpu.VMEM((heads * p, n), f32)],
+        compiler_params=_SSD,
+        interpret=interpret,
+        name="mxtpu_ssd_states",
+    )(xbc, xbc, cols)
+    part = pl.BlockSpec((None, chunk, n),
+                        lambda i, j, k: (i, back(k), j))
+    dx, db, dc, gcols, grows, dd = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, heads=heads, p=p),
+        grid=grid,
+        in_specs=[x(back), b(back), c(back), x(back), col(back), row(back),
+                  lane, state(back)],
+        out_specs=[x(back), part, part, col(back),
+                   pl.BlockSpec((None, None, heads, chunk),
+                                lambda i, j, k: (i, j, 0, back(k))),
+                   pl.BlockSpec((None, 1, heads * p),
+                                lambda i, j, k: (i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct((bsz, t, h * p), xbc.dtype),
+                   jax.ShapeDtypeStruct((bsz, t, blocks * n), f32),
+                   jax.ShapeDtypeStruct((bsz, t, blocks * n), f32),
+                   jax.ShapeDtypeStruct(cols.shape, f32),
+                   jax.ShapeDtypeStruct((bsz, blocks, heads, t), f32),
+                   jax.ShapeDtypeStruct((bsz, 1, h * p), f32)],
+        scratch_shapes=[pltpu.VMEM((heads * p, n), f32)],
+        compiler_params=_SSD,
+        interpret=interpret,
+        name="mxtpu_ssd_bwd",
+    )(xbc, xbc, xbc, dy, cols, rows, lanes, before)
+    # a group's dB and dC: the sum of its head blocks' parts
+    db, dc = (v.reshape(bsz, t, g, blocks // g, n).sum(3).reshape(
+        bsz, t, g * n).astype(xbc.dtype) for v in (db, dc))
+    ddt, dacs = gcols.reshape(bsz, blocks, t, 2, heads).transpose(
+        3, 0, 2, 1, 4).reshape(2, bsz, t, h)
+    dacs = dacs + grows.transpose(0, 3, 1, 2).reshape(bsz, t, h)
+    return (jnp.concatenate([dx, db, dc], axis=-1), ddt, dacs,
+            dd.reshape(bsz, h, p).sum((0, 2)))

@@ -8,16 +8,34 @@ of Mamba-2 (Dao & Gu 2024, "Transformers are SSMs"), per head with a state
 ``ssm_scan`` computes it in the chunked form: inside a chunk of L steps the
 masked, decay-weighted product ``(C B^T * decay * dt) x`` (matrix products of
 (L, L) blocks, MXU work); between chunks the carried state, a short
-sequential pass over T / L states.  The backward is autodiff of that form
-under ``jax.checkpoint``: only the op's inputs are kept from the forward and
-the (L, L) blocks are formed again, group by group, which costs a few per
-cent of the layer's FLOPs and saves their memory (each (heads, T / L, L, L)
-float32 array is 128 MiB at T = 4096 with 64 heads, and the backward holds
-half a dozen).
+sequential pass over T / L states.  One algorithm, two forms of it, chosen
+from what the op observes (the backend and the shape, never an option):
 
-Precision follows the published kernels: ``dt``, ``A``, the decays
-``exp(A dt)`` and the carried state are float32 whatever the input's dtype;
-the matrix products take operands in the input's dtype and accumulate in
+- On a TPU, for the shapes ``pallas_kernels.ssd_available`` takes (T a
+  multiple of the chunk, chunks of whole 128-lane tiles, P and N
+  lane-friendly): the kernels ``mxtpu_ssd_fwd`` / ``_states`` / ``_bwd``.
+  They read x, B and C as column blocks of ``data`` where it lies; the
+  (L, L) blocks and the carried (P, N) state stay in VMEM; y leaves once, in
+  data's dtype, the skip ``D x`` added in the epilogue.  The backward is
+  written by hand under one ``jax.custom_vjp`` whose residuals are the op's
+  five inputs: the states entering each chunk are formed again by a
+  states-only pass into one (T / L, H P, N) float32 array (64 MiB at T =
+  4096 with 64 heads, alive during that layer's backward alone), then the
+  chunks are taken last to first with the state's gradient carried in VMEM.
+  The step ``softplus(dt + dt_bias)`` and the running sum of ``A dt``
+  inside each chunk are made outside, on their (T, H) arrays, and autodiff
+  carries the kernels' gradients through them to dt, ``A_log`` and
+  ``dt_bias``.
+- Everywhere else: ``ssm_scan_chunked``, plain ``jax.numpy``, which is also
+  the tests' oracle.  Its backward is autodiff under ``jax.checkpoint``:
+  only the op's inputs are kept from the forward and the (L, L) blocks are
+  formed again, group by group (each (heads, T / L, L, L) float32 array is
+  128 MiB at T = 4096 with 64 heads, and the backward holds half a dozen).
+
+Precision follows the published kernels, in both forms alike: ``dt``, ``A``,
+the decays ``exp(A dt)``, the running sums and the carried state are float32
+whatever the input's dtype; the matrix products take operands in the
+input's dtype, cast where the plain form casts them, and accumulate in
 float32.
 """
 from __future__ import annotations
@@ -27,6 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import pallas_kernels
 from .nn import ACTIVATIONS
 from .registry import register, parse_int, parse_str
 
@@ -153,6 +172,46 @@ def _scan(xbc, dt, a_log, d, dt_bias, h, p, g, chunk):
     return y.astype(xbc.dtype).reshape(bsz, t, inner)
 
 
+def _steps(dt, a_log, dt_bias, chunk):
+    """What the kernels take of dt and A, float32 (B, T, H): the step
+    ``softplus(dt + dt_bias)`` and the running sum of ``A dt`` inside each
+    chunk.  T is a multiple of ``chunk``."""
+    f32 = jnp.float32
+    step = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+    bsz, t, h = step.shape
+    acs = jnp.cumsum((step * -jnp.exp(a_log.astype(f32))).reshape(
+        bsz, t // chunk, chunk, h), axis=2)
+    return step, acs.reshape(bsz, t, h)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _scan_kernels(xbc, dt, a_log, d, dt_bias, h, p, g, chunk,
+                  interpret=False):
+    """``_scan`` through the Pallas kernels (``pallas_kernels.ssd_scan_*``),
+    the backward written by hand: the residuals are the five inputs."""
+    return pallas_kernels.ssd_scan_fwd(
+        xbc, *_steps(dt, a_log, dt_bias, chunk), d, h, p, g, chunk,
+        interpret)
+
+
+def _scan_kernels_fwd(xbc, dt, a_log, d, dt_bias, h, p, g, chunk, interpret):
+    return _scan_kernels(xbc, dt, a_log, d, dt_bias, h, p, g, chunk,
+                         interpret), (xbc, dt, a_log, d, dt_bias)
+
+
+def _scan_kernels_bwd(h, p, g, chunk, interpret, res, dy):
+    xbc, dt, a_log, d, dt_bias = res
+    (step, acs), small = jax.vjp(functools.partial(_steps, chunk=chunk),
+                                 dt, a_log, dt_bias)
+    dxbc, dstep, dacs, dd = pallas_kernels.ssd_scan_bwd(
+        xbc, step, acs, d, dy, h, p, g, chunk, interpret)
+    ddt, da_log, dbias = small((dstep, dacs))
+    return dxbc, ddt, da_log, dd.astype(d.dtype), dbias
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
 @register("ssm_scan", arg_names=("data", "dt", "a_log", "d", "dt_bias"),
           attr_types={"num_heads": parse_int, "head_dim": parse_int,
                       "num_groups": parse_int, "chunk_size": parse_int},
@@ -166,8 +225,14 @@ def _ssm_scan(data, dt, a_log, d, dt_bias, num_heads=None, head_dim=None,
     (always above 0, so a time-step limit of (0, inf) clamps nothing), the
     decay ``exp(-exp(a_log) dt)``, and ``d`` scales the skip ``D x``.
     Returns y (B, T, H*P) in data's dtype; of the forward only the inputs
-    are kept."""
-    core = jax.checkpoint(functools.partial(
-        _scan, h=int(num_heads), p=int(head_dim), g=int(num_groups),
-        chunk=int(chunk_size)))
+    are kept.  On a TPU, for the shapes ``ssd_available`` takes, the Pallas
+    kernels; else the plain form."""
+    h, p, g, chunk = (int(v) for v in (num_heads, head_dim, num_groups,
+                                       chunk_size))
+    n = (data.shape[2] - h * p) // (2 * g)
+    if jax.default_backend() == "tpu" and pallas_kernels.ssd_available(
+            data.shape[1], h, p, g, n, chunk, data.dtype.itemsize):
+        return _scan_kernels(data, dt, a_log, d, dt_bias, h, p, g, chunk)
+    core = jax.checkpoint(functools.partial(_scan, h=h, p=p, g=g,
+                                            chunk=chunk))
     return core(data, dt, a_log, d, dt_bias)

@@ -1,4 +1,5 @@
-"""The flash kernels and the routed experts' grouped products compiled for a
+"""The flash kernels, the routed experts' grouped products and the state-space
+scan's kernels compiled for a
 described (not attached) TPU v5e, at the benchmark cells' shapes and the
 shape guards' corners: what interpret mode
 cannot show — a slice Mosaic cannot tile, a transpose it cannot lower, more
@@ -10,15 +11,18 @@ All such compiles live in this one file, and the topology is described in a
 fixture (never at import): one process at a time may hold the TPU library,
 and under xdist only the worker given this file loads it."""
 import os
+import re
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from mxnet_tpu.ops import ssm
 from mxnet_tpu.ops.pallas_kernels import (flash_attention, flash_available,
                                           flash_blocks, grouped_available,
-                                          grouped_matmul, grouped_matmul_t)
+                                          grouped_matmul, grouped_matmul_t,
+                                          ssd_blocks)
 
 
 @pytest.fixture(scope="module")
@@ -125,3 +129,51 @@ def test_the_routed_experts_products_compile_at_the_cells_shape(one_chip,
         blocks, blocks, shaped((), jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert ("mxtpu_tgmm" if kw is None else "mxtpu_gmm") in text
+
+
+# nemotron-twotower-steps-t4096's state-space mixer, (B, T, H, P, G, N, chunk),
+# then the corners of ``ssd_blocks``: a head a tile; four heads a tile; one
+# group of 64 heads in steps of 8, chunks of 256; a state of 256
+SSD_CELL = (1, 4096, 64, 64, 8, 128, 128)
+SSD_CORNERS = [(2, 1024, 8, 128, 2, 128, 128), (1, 1024, 16, 32, 2, 128, 128),
+               (1, 1024, 64, 64, 1, 128, 256), (1, 512, 16, 64, 2, 256, 128)]
+
+
+def _compile_scan(one_chip, shape, dtype):
+    bsz, t, h, p, g, n, chunk = shape
+    assert ssd_blocks(t, h, p, g, n, chunk, jnp.dtype(dtype).itemsize)
+    shaped = lambda dims, kind: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, kind, sharding=one_chip)
+    leaf = shaped((h,), jnp.float32)
+
+    def loss(*args):
+        y = ssm._scan_kernels(*args, h, p, g, chunk)
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        shaped((bsz, t, h * p + 2 * g * n), dtype), shaped((bsz, t, h), dtype),
+        leaf, leaf, leaf).compile().as_text()
+    # forward, the states formed again, backward: nothing run twice
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("mxtpu_ssd_fwd", "mxtpu_ssd_states", "mxtpu_ssd_bwd"):
+        assert name in text
+    # none of the (L, L) blocks, which the plain form keeps as float32
+    # arrays of (..., chunk, chunk), is an array of the program
+    assert not re.search(r"f32\[[0-9,]*\b%d,%d\]" % (chunk, chunk), text)
+    return text
+
+
+def test_the_scan_compiles_at_the_cells_shape(one_chip):
+    """x, B and C are column blocks of the unsplit array: the kernels'
+    operands are the op's own (1, 4096, 6144) input, three times, and the
+    one large temporary is the states' (T / L, H P, N) float32 array."""
+    assert ssd_blocks(*SSD_CELL[1:], 2) == 8
+    text = _compile_scan(one_chip, SSD_CELL, jnp.bfloat16)
+    assert "f32[1,32,4096,128]" in text
+    assert not re.search(r"bf16\[[0-9,]*\b8,[0-9,]*\b128,8,64\]", text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("shape", SSD_CORNERS)
+def test_the_scans_corners_compile(one_chip, shape, dtype):
+    _compile_scan(one_chip, shape, dtype)

@@ -217,3 +217,155 @@ def test_the_ssm_initialisers_draw_the_published_ranges():
     mx.init.InverseSoftplusLogUniform(0.001, 0.1)._init_weight("b", bias)
     dt = np.log1p(np.exp(bias.asnumpy()))
     assert dt.min() >= 0.00099 and dt.max() <= 0.101
+
+
+# ---------------------------------------------- the Pallas kernels of the scan
+# Interpret mode, at tile sizes the TPU takes (chunks of 128, N = 128).  The
+# cases: (B, T, H, P, G, N).  Two heads a 128-lane tile and two tiles a step;
+# a head a tile; four heads a tile; two steps of 8 heads to a group, whose dB
+# and dC are summed outside the kernel.
+KERNEL_SHAPES = {"pairs": (2, 512, 8, 64, 2, 128),
+                 "whole": (2, 512, 4, 128, 2, 128),
+                 "fours": (2, 512, 8, 32, 2, 128),
+                 "parts": (1, 512, 16, 64, 1, 128)}
+
+
+def _op_inputs(seed, shape, dtype=jnp.float32):
+    """The op's five inputs at the rates of ``_scan_inputs``: the step
+    ``softplus(dt + dt_bias)`` spreads round a head's own rate in
+    0.001-0.1, A in 1-16."""
+    bsz, t, h, p, g, n = shape
+    r = np.random.RandomState(seed)
+    data = jnp.asarray(r.randn(bsz, t, h * p + 2 * g * n), jnp.float32)
+    dt = jnp.asarray(r.randn(bsz, t, h) * 0.5, jnp.float32)
+    rate = np.exp(r.uniform(np.log(1e-3), np.log(0.1), (h,)))
+    return (data.astype(dtype), dt.astype(dtype),
+            jnp.asarray(np.log(r.uniform(1.0, 16.0, (h,))), jnp.float32),
+            jnp.asarray(r.randn(h), jnp.float32),
+            jnp.asarray(np.log(np.expm1(rate)), jnp.float32))
+
+
+def _plain(shape):
+    _, _, h, p, g, _ = shape
+    return lambda *a: ssm._scan(*a, h=h, p=p, g=g, chunk=128)
+
+
+def _kernels(shape):
+    _, _, h, p, g, _ = shape
+    return lambda *a: ssm._scan_kernels(*a, h, p, g, 128, True)
+
+
+def _gap(got, want):
+    got, want = (jnp.asarray(v, jnp.float32) for v in (got, want))
+    return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
+def test_the_scan_kernels_are_the_plain_scan_and_the_recurrence(case, dtype,
+                                                                tol):
+    """Against the plain form both sides round alike (bfloat16: y's own
+    rounding, 2**-8, is the gap's size); against the float32 recurrence the
+    bfloat16 gap is the operands' rounding, as for the plain form above."""
+    shape = KERNEL_SHAPES[case]
+    bsz, t, h, p, g, n = shape
+    args = _op_inputs(11, shape, dtype)
+    got = jax.jit(_kernels(shape))(*args)
+    assert got.dtype == dtype and got.shape == (bsz, t, h * p)
+    assert _gap(got, jax.jit(_plain(shape))(*args)) < tol
+    data, dt, a_log, d, dt_bias = (v.astype(jnp.float32) for v in args)
+    x = data[..., :h * p].reshape(bsz, t, h, p)
+    b, c = (data[..., h * p + i * g * n:h * p + (i + 1) * g * n].reshape(
+        bsz, t, g, n) for i in (0, 1))
+    want = _recurrence(x, jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log), b,
+                       c) + d[:, None] * x
+    assert _gap(got.reshape(want.shape), want) < (
+        2e-4 if dtype == jnp.float32 else 0.03)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 5e-4),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(KERNEL_SHAPES))
+def test_the_scan_kernels_gradients_are_autodiffs_of_the_plain_scan(
+        case, dtype, tol):
+    """All five inputs; x, B and C of ``data`` apart, so that a small
+    gradient is not hidden beside a large one."""
+    shape = KERNEL_SHAPES[case]
+    _, _, h, p, g, n = shape
+    args = _op_inputs(13, shape, dtype)
+    w = jnp.asarray(np.random.RandomState(2).randn(
+        *args[0].shape[:2], h * p), jnp.float32)
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+    got, want = grads(_kernels(shape)), grads(_plain(shape))
+    for name, a, b in zip("data dt a_log d dt_bias".split(), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert _gap(a, b) < tol, name
+    for name, cols in (("x", slice(0, h * p)),
+                       ("B", slice(h * p, h * p + g * n)),
+                       ("C", slice(h * p + g * n, None))):
+        assert _gap(got[0][..., cols], want[0][..., cols]) < tol, name
+
+
+def test_the_scan_kernels_carry_the_state_and_its_gradient():
+    """The twin of ``test_the_carried_state_matters_at_these_rates``.  A
+    forward that dropped the state between chunks would give the last
+    chunk what it gives that chunk alone; a backward that dropped dS would
+    give the first three chunks no gradient from the last chunk's y."""
+    shape = KERNEL_SHAPES["pairs"]
+    args = _op_inputs(17, shape)
+    kernels, plain = _kernels(shape), _plain(shape)
+    whole = jax.jit(kernels)(*args)
+    alone = jax.jit(kernels)(*[v[:, 384:] if v.ndim > 1 else v
+                               for v in args])
+    assert _gap(alone, whole[:, 384:]) > 0.05
+    assert _gap(whole, jax.jit(plain)(*args)) < 1e-5
+
+    def last(fn):
+        return jax.jit(jax.grad(lambda *a: (
+            fn(*a)[:, 384:].astype(jnp.float32) ** 2).sum(),
+            argnums=(0, 1)))(*args)
+    got, want = last(kernels), last(plain)
+    for name, a, b in zip(("data", "dt"), got, want):
+        early = float(jnp.abs(b[:, :384]).max() / jnp.abs(b).max())
+        assert early > 0.05, name
+        assert _gap(a[:, :384], b[:, :384]) < 5e-4, name
+
+
+def test_the_scan_kernels_chooser_and_guard():
+    from mxnet_tpu.ops.pallas_kernels import ssd_available, ssd_blocks
+    # nemotron-twotower-steps-t4096: a group's 8 heads a step
+    assert ssd_blocks(4096, 64, 64, 8, 128, 128, 2) == 8
+    assert ssd_available(4096, 64, 64, 8, 128, 128, 2)
+    # one group of 64 heads: 8 a step all the same (the loop is unrolled)
+    assert ssd_blocks(4096, 64, 64, 1, 128, 128, 2) == 8
+    # a head a tile, four heads a tile
+    assert ssd_blocks(512, 4, 128, 2, 128, 128, 4) == 2
+    assert ssd_blocks(512, 8, 32, 2, 128, 128, 4) == 4
+    refused = {"T off the chunk": (4000, 64, 64, 8, 128, 128),
+               "a chunk off the lanes": (4096, 64, 64, 8, 128, 64),
+               "P the lanes refuse": (4096, 64, 48, 8, 128, 128),
+               "N off the lanes": (4096, 64, 64, 8, 64, 128),
+               "half a tile of heads a group": (4096, 8, 64, 8, 128, 128),
+               "a chunk VMEM does not hold": (4096, 64, 64, 8, 128, 2048)}
+    for why, shape in refused.items():
+        assert ssd_blocks(*shape, 2) is None, why
+        assert not ssd_available(*shape, 2), why
+
+
+def test_off_the_tpu_the_op_is_the_plain_scan():
+    """The path is chosen from the backend and the shape: here, on the CPU,
+    the cell's own shape holds no kernel."""
+    shape = (1, 512, 64, 64, 8, 128)
+    args = _op_inputs(19, shape, jnp.bfloat16)
+    op = get_op("ssm_scan")
+    fn = lambda *a: op.fn(*a, num_heads=64, head_dim=64, num_groups=8,  # noqa: E731
+                          chunk_size=128)
+    assert "pallas_call" not in str(jax.make_jaxpr(fn)(*args))
+    assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum()))(*args))
+    assert "pallas_call" in str(jax.make_jaxpr(_kernels(shape))(*args))

@@ -36,6 +36,10 @@ GROUPED_RUNS = [(192, 190, 200, 185, 256, 130, 257, 126),
                 (4000, 0, 1, 3071, 0, 512, 0, 300),
                 (0, 0, 0, 0, 0, 0, 0, 0)]
 
+# the state-space mixer of nemotron-twotower-steps-t4096: (B, T, H, P, G, N,
+# chunk), bfloat16
+SSD_SHAPE = (1, 4096, 64, 64, 8, 128, 128)
+
 
 def _rel(a, b):
     a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
@@ -140,6 +144,56 @@ def check_grouped_products():
                   pk.grouped_blocks(block, f, c, 2), *errs), flush=True)
 
 
+def check_ssd_scan():
+    """``ssm_scan`` through the kernels against its plain form, forward and
+    the gradients of its five inputs, at the rates where the state carried
+    from chunk to chunk matters (``test_ssm.py``: dt in 0.001-0.1, A in
+    1-16), which the benchmark's own seed does not reach.  Both sides take
+    the same bfloat16 inputs; the plain form's float32 (L, L) products run
+    as one bfloat16 pass on the chip too."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import ssm, pallas_kernels as pk
+
+    bsz, t, h, p, g, n, chunk = SSD_SHAPE
+    assert pk.ssd_available(t, h, p, g, n, chunk, 2), SSD_SHAPE
+    rng = np.random.RandomState(3)
+    bf16 = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
+    f32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
+    step = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (h,)))
+    args = (bf16(bsz, t, h * p + 2 * g * n), bf16(bsz, t, h) * 0.5,
+            f32(np.log(rng.uniform(1.0, 16.0, (h,)))), f32(rng.randn(h)),
+            f32(np.log(np.expm1(step))))
+    weight = bf16(bsz, t, h * p).astype(jnp.float32)
+    plain = lambda *a: ssm._scan(*a, h=h, p=p, g=g, chunk=chunk)  # noqa: E731
+    kernels = lambda *a: ssm._scan_kernels(*a, h, p, g, chunk)  # noqa: E731
+
+    def both(fn):
+        def loss(*a):
+            y = fn(*a)
+            return (y.astype(jnp.float32) * weight).sum(), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+        return (y,) + grads
+    got, want = both(kernels), both(plain)
+    # the last chunk alone, its entering state left out: how much of y the
+    # carried state is at these rates
+    alone = jax.jit(plain)(*(v[:, t - chunk:] if v.ndim > 1 else v
+                             for v in args))
+    carried = _rel(alone, want[0][:, t - chunk:])
+    assert carried > 0.05, "the carried state is %.1e of y" % carried
+    names = "y data dt a_log d dt_bias".split()
+    errs = [_rel(a, b) for a, b in zip(got, want)]
+    for name, err in zip(names, errs):
+        assert err < (2e-2 if name == "y" else 5e-2), \
+            "ssd_scan %s rel err %.2e at %s" % (name, err, SSD_SHAPE)
+    print("PASS ssd_scan %s bfloat16 heads a step %d carried state %.2f of y"
+          "  rel err %s" % (SSD_SHAPE, pk.ssd_blocks(t, h, p, g, n, chunk, 2),
+                            carried, " ".join("%s %.1e" % (k, e) for k, e in
+                                              zip(names, errs))), flush=True)
+
+
 if __name__ == "__main__":
     import jax
     if jax.default_backend() != "tpu":
@@ -150,4 +204,5 @@ if __name__ == "__main__":
     enable_compile_cache()
     check_flash_attention()
     check_grouped_products()
+    check_ssd_scan()
     print("ALL TPU NUMERICS CHECKS PASSED")
