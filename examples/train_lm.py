@@ -19,6 +19,7 @@ floor and convergence is quickly visible.
 from __future__ import annotations
 
 import argparse
+import ast
 import logging
 import os
 import sys
@@ -39,8 +40,24 @@ def parse_args():
     p.add_argument("--model", default="transformer",
                    choices=("transformer", "hybrid_lm"),
                    help="module under mxnet_tpu.models whose get_symbol "
-                        "builds the net (hybrid_lm: state-space, expert "
-                        "and grouped-query layers by its default pattern)")
+                        "builds the net (hybrid_lm: the parts --pattern "
+                        "names)")
+    p.add_argument("--pattern", default="M*E",
+                   help="hybrid_lm: one letter a part on the residual "
+                        "stream, each on its own pre-norm: M a Mamba-2 "
+                        "mixer, K a Kimi Delta Attention mixer (gated delta "
+                        "rule), * grouped-query attention, L latent "
+                        "attention (MLA, no positions), E an expert layer, "
+                        "D a dense MLP; a layer of a mixer and a "
+                        "feed-forward is two letters, e.g. KDKEKELEKE")
+    p.add_argument("--hybrid-arg", action="append", default=[],
+                   metavar="NAME=VALUE",
+                   help="hybrid_lm: any further size of "
+                        "models/hybrid_lm.get_symbol, e.g. kda_heads=4 "
+                        "kda_head_dim=32 kv_lora_rank=64 qk_nope_head_dim=32 "
+                        "qk_rope_head_dim=16 v_head_dim=32 mlp_hidden=512 "
+                        "mlp_act=silu mlp_gated=1 num_experts=8 "
+                        "experts_per_token=2 (repeatable)")
     p.add_argument("--vocab", type=int, default=256)
     p.add_argument("--seq-len", type=int, default=256)
     p.add_argument("--batch-size", type=int, default=8)
@@ -89,6 +106,14 @@ def main():
                 num_heads=args.num_heads)
     if args.model == "transformer":     # hybrid_lm's depth is its pattern
         size["num_layers"] = args.num_layers
+    else:
+        size["pattern"] = args.pattern
+        for pair in args.hybrid_arg:
+            name, value = pair.split("=", 1)
+            try:
+                size[name] = ast.literal_eval(value)
+            except (ValueError, SyntaxError):       # a name, e.g. mlp_act
+                size[name] = value
     net = getattr(models, args.model).get_symbol(**size)
     opt = mx.optimizer.Adam(learning_rate=args.lr)
     ts = TrainStep(net, opt)

@@ -25,6 +25,7 @@ from . import attention      # noqa: F401  (NEW: dot_product_attention/ring,
                              #  LayerNorm — no reference analogue, §5.7)
 from . import ssm            # noqa: F401  (NEW: causal_conv1d, ssm_scan)
 from . import moe            # noqa: F401  (NEW: moe_router, moe_experts)
+from . import kda            # noqa: F401  (NEW: kda_scan)
 from . import misc           # noqa: F401  (ndarray-fun registry tail,
                              #  KL sparse reg, v1 aliases)
 

@@ -1,8 +1,11 @@
 """Attention operators (NEW capability — the reference has no attention op
 anywhere in src/operator, SURVEY.md §5.7; designed TPU-first from the start).
 
-``dot_product_attention`` is the core primitive: (B, H, T, D) Q/K/V in, Q's
-shape out.  Unequal head counts are grouped-query attention: K and V may come
+``dot_product_attention`` is the core primitive: (B, H, T, D) Q and K and
+(B, H, T, Dv) V in, (B, H, T, Dv) out.  The value heads may have a width of
+their own (a latent-attention layer's 128 beside query/key heads of 192):
+every rung of the ladder takes it, the flash kernels with both widths and no
+padding.  Unequal head counts are grouped-query attention: K and V may come
 with fewer heads, H_kv dividing H; query head ``i`` reads key/value head
 ``i // (H / H_kv)``.  The key/value heads are repeated ahead of the lowering
 ladder, so every rung (ring, flash kernels, XLA) sees equal shapes and the
@@ -26,8 +29,9 @@ from .registry import register, parse_bool, parse_float
 
 
 def _attn_infer(attrs, in_shapes):
-    q = in_shapes[0]
-    return list(in_shapes), [q], None
+    q, v = in_shapes[0], in_shapes[2]
+    out = q if q is None or v is None else tuple(q[:-1]) + (v[-1],)
+    return list(in_shapes), [out], None
 
 
 @register("dot_product_attention", arg_names=("query", "key", "value"),
@@ -37,13 +41,16 @@ def _attn_infer(attrs, in_shapes):
           infer_shape=_attn_infer)
 def _dot_product_attention(query, key, value, causal=False, scale=None,
                            impl="auto"):
-    """Scaled dot-product attention over (B, H, T, D); key and value may
-    have H_kv < H heads (grouped-query: each is read by H / H_kv query
-    heads, repeated here before the ladder).
+    """Scaled dot-product attention over (B, H, T, D); value (B, H, T, Dv)
+    may have a head width of its own and gives the result's; ``scale``
+    defaults to 1 / sqrt(D).  Key and value may have H_kv < H heads
+    (grouped-query: each is read by H / H_kv query heads, repeated here
+    before the ladder).
 
     Lowering ladder (impl='auto'):
     1. sequence mesh active -> ring attention (multi-chip, ppermute ring);
-    2. TPU + flash-friendly shapes + T >= 512 -> Pallas flash kernel
+    2. TPU + flash-friendly shapes (``flash_available``: Dv = D or not)
+       + T >= 512 -> Pallas flash kernel
        (blocked online-softmax, no (T, T) score matrix, so its memory is
        O(T) where XLA's backward keeps the scores; kernel times on the
        v5e: PERF.md 5);

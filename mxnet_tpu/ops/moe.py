@@ -28,7 +28,18 @@ expert's run rounded up to half blocks, half a block for an expert with
 none), not tokens x experts held, and not the rows set aside.  One
 ``custom_vjp`` is round the routed part: its backward keeps the op's
 inputs, forms the sorted rows and the up product once more, and needs the
-down product not at all.
+down product not at all: 7 products a block (forward up and down; backward
+up again, ``d_out down``, the two matrices' gradients, ``d_pre up``).
+
+An expert is ``down(act(up u))`` or, told ``gated`` and given a third
+matrix a held expert, ``down(act(gate u) * up u)`` (SwiGLU with ``silu``).
+The gated form is the same layout, the same two arms and the same counters
+with one more first product everywhere the up product stands: forward gate,
+up and down; backward gate and up again (their float32 results multiplied
+and rounded once, as in the forward), ``d_out down``, the three matrices'
+gradients, and ``d_gate_pre gate + d_up_pre up`` summed in float32: 11
+products a block.  ``moe_rows`` counts the rows one product runs over in
+either form, not the products.
 
 The rows set aside are four times the mean number that lands here: a
 chip's share of a router that nothing has balanced yet (random weights, the
@@ -58,7 +69,8 @@ import jax.numpy as jnp
 from .. import telemetry as _tel
 from . import pallas_kernels
 from .nn import ACTIVATIONS
-from .registry import register, parse_float, parse_int, parse_str
+from .registry import register, parse_bool, parse_float, parse_int, \
+    parse_str
 
 # the rows of one block, and the rows set aside in multiples of the mean
 # number of assignments that land here
@@ -213,15 +225,29 @@ def _worked(fill, live, unit):
     return jnp.where(jnp.arange(fill.shape[0]) < live, units, 0).sum()
 
 
-def _forward(data, flat_w, order, counts, up, down, rows_cap, block, act,
-             top_k):
-    """What the experts of ``up`` and ``down`` add: ((out (N, C) float32,
-    rows that hold an assignment, rows the products ran over), ())."""
+def _hidden(act, gated):
+    """What stands between the first products and the down product: the
+    activation, or for a gated expert ``act(gate u) * (up u)``."""
+    return (lambda a, b: act(a) * b) if gated else act
+
+
+def _forward(data, flat_w, order, counts, mats, rows_cap, block, act, top_k):
+    """What the experts of ``mats`` (up, down, the gate's matrix or None)
+    add: ((out (N, C) float32, rows that hold an assignment, rows the
+    products ran over), ())."""
     f32 = jnp.float32
+    up, down, gate = mats
     rows, valid, tiles, live = _layout(order, counts, rows_cap, block)
     gmm, _, unit = _products(block, data, up)
     token = rows // top_k
-    hid = gmm(data[token], up, *tiles, live, transpose_rhs=True, act=act)
+    if gate is None:
+        hid = gmm(data[token], up, *tiles, live, transpose_rhs=True, act=act)
+    else:           # both halves leave in float32 and are rounded once
+        x = data[token]
+        hid = (gmm(x, gate, *tiles, live, transpose_rhs=True, act=act,
+                   out_dtype=f32)
+               * gmm(x, up, *tiles, live, transpose_rhs=True,
+                     out_dtype=f32)).astype(x.dtype)
     y = gmm(hid, down, *tiles, live, transpose_rhs=True)
     # rows past the last that hold something were not written: not zero
     y = jnp.where(valid[:, None], y.astype(f32) * flat_w[rows][:, None], 0.0)
@@ -229,32 +255,40 @@ def _forward(data, flat_w, order, counts, up, down, rows_cap, block, act,
     return (out, valid.sum(), _worked(tiles[1], live, unit)), ()
 
 
-def _backward(data, flat_w, order, counts, up, down, d_out, rows_cap, block,
-              act, top_k):
+def _backward(data, flat_w, order, counts, mats, d_out, rows_cap, block, act,
+              top_k):
     """((gradient of data (N, C) float32, of the flat weights), (of up, of
-    down)): the sorted rows and the up product formed once more, the down
-    product not at all (a row's weight meets ``<hid, d_out down>``, which
-    is ``<y, d_out>``)."""
+    down, of the gate's matrix or None)): the sorted rows and the first
+    products formed once more, the down product not at all (a row's weight
+    meets ``<hid, d_out down>``, which is ``<y, d_out>``)."""
     f32 = jnp.float32
+    up, down, gate = mats
+    firsts = (up,) if gate is None else (gate, up)
     rows, valid, tiles, live = _layout(order, counts, rows_cap, block)
     gmm, tgmm, _ = _products(block, data, up)
     token = rows // top_k
     keep = valid[:, None]
     weight = flat_w[rows][:, None]
     x, g = data[token], d_out[token]
-    hid, act_vjp = jax.vjp(act, gmm(x, up, *tiles, live, transpose_rhs=True,
-                                    out_dtype=f32))
+    hid, act_vjp = jax.vjp(_hidden(act, gate is not None), *(
+        gmm(x, m, *tiles, live, transpose_rhs=True, out_dtype=f32)
+        for m in firsts))
     d_hid = gmm(g, down, *tiles, live, out_dtype=f32)   # before the weight
     d_weight = jnp.where(valid, (hid * d_hid).sum(axis=1), 0.0)
-    d_pre = jnp.where(keep, act_vjp(d_hid * weight)[0], 0.0).astype(x.dtype)
+    d_pre = [jnp.where(keep, d, 0.0).astype(x.dtype)
+             for d in act_vjp(d_hid * weight)]
     weighted = jnp.where(keep, hid * weight, 0.0).astype(x.dtype)
-    d_up = tgmm(d_pre, x, *tiles, live, up.shape[0], out_dtype=up.dtype)
+    d_firsts = [tgmm(d, x, *tiles, live, up.shape[0], out_dtype=m.dtype)
+                for d, m in zip(d_pre, firsts)]
     d_down = tgmm(g, weighted, *tiles, live, up.shape[0],
                   out_dtype=down.dtype)
-    d_x = jnp.where(keep, gmm(d_pre, up, *tiles, live).astype(f32), 0.0)
+    d_x = jnp.where(keep, functools.reduce(jnp.add, [
+        gmm(d, m, *tiles, live).astype(f32)
+        for d, m in zip(d_pre, firsts)]), 0.0)
     d_data = jnp.zeros(data.shape, f32).at[token].add(d_x)
     d_flat = jnp.zeros(flat_w.shape, f32).at[rows].add(d_weight)
-    return (d_data, d_flat), (d_up, d_down)
+    return (d_data, d_flat), (d_firsts[-1], d_down,
+                              None if gate is None else d_firsts[0])
 
 
 def _expert_by_expert(arm, tokens, block):
@@ -265,14 +299,15 @@ def _expert_by_expert(arm, tokens, block):
     own gradients are stacked."""
     rows_cap = _round_up(tokens, block) + block
 
-    def path(data, flat_w, order, counts, up, down, *rest):
+    def path(data, flat_w, order, counts, mats, *rest):
         starts = jnp.cumsum(counts) - counts
         last = order.shape[0] - 1
 
         def one(e):
             mine = order[jnp.minimum(starts[e] + jnp.arange(rows_cap), last)]
-            return arm(data, flat_w, mine, counts[e][None], up[e][None],
-                       down[e][None], *rest, rows_cap=rows_cap)
+            return arm(data, flat_w, mine, counts[e][None],
+                       jax.tree_util.tree_map(lambda m: m[e][None], mats),
+                       *rest, rows_cap=rows_cap)
 
         def step(sums, e):
             # the barrier keeps a kernel's result out of the fusion that
@@ -312,43 +347,50 @@ def _either(arm, indices, first, num_experts, act, held):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _routed(data, indices, weights, up, down, first, num_experts, act):
+def _routed(data, indices, weights, up, down, first, num_experts, act,
+            gate=None):
     """(this chip's part of the routed result (N, C) in data's dtype,
     float32[4] counters, float32 rows the products ran over).  Its backward
     keeps these inputs and nothing else."""
     return _routed_fwd(data, indices, weights, up, down, first, num_experts,
-                       act)[0]
+                       act, gate)[0]
 
 
-def _routed_fwd(data, indices, weights, up, down, first, num_experts, act):
+def _routed_fwd(data, indices, weights, up, down, first, num_experts, act,
+                gate=None):
     n, k = indices.shape
     call, counts = _either(_forward, indices, first, num_experts, act,
                            up.shape[0])
     (out, computed, worked), _ = call(
-        data, weights.reshape(-1).astype(jnp.float32), up, down)
+        data, weights.reshape(-1).astype(jnp.float32), (up, down, gate))
     landed = counts.sum()
     stats = jnp.stack([landed, counts.max(), n * k - landed,
                        landed - computed]).astype(jnp.float32)
     return (out.astype(data.dtype), stats, worked.astype(jnp.float32)), \
-        (data, indices, weights, up, down)
+        (data, indices, weights, up, down, gate)
 
 
 def _routed_bwd(first, num_experts, act, kept, cotangents):
-    data, indices, weights, up, down = kept
+    data, indices, weights, up, down, gate = kept
     call, _ = _either(_backward, indices, first, num_experts, act,
                       up.shape[0])
     (d_data, d_flat), own = call(
-        data, weights.reshape(-1).astype(jnp.float32), up, down,
+        data, weights.reshape(-1).astype(jnp.float32), (up, down, gate),
         cotangents[0])
     # the matrices' gradients leave the ``cond`` as they are: without the
     # barrier the compiler moves the optimizer's float32 casts of them into
     # its branches, and every expert layer's stay alive at twice the size
-    d_up, d_down = jax.lax.optimization_barrier(own)
+    d_up, d_down, d_gate = jax.lax.optimization_barrier(own)
     return d_data.astype(data.dtype), None, d_flat.reshape(
-        weights.shape).astype(weights.dtype), d_up, d_down
+        weights.shape).astype(weights.dtype), d_up, d_down, d_gate
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def _experts_args(attrs):
+    names = ["data", "indices", "weights", "up_weight", "down_weight"]
+    return names + ["gate_weight"] if attrs.get("gated", False) else names
 
 
 def _experts_infer(attrs, in_shapes):
@@ -358,33 +400,36 @@ def _experts_infer(attrs, in_shapes):
     if data is not None:
         ins[3] = (held, f, data[-1])
         ins[4] = (held, data[-1], f)
+        ins[5:] = [ins[3]] * (len(ins) - 5)
     return ins, [data], None
 
 
 def _experts_types(attrs, in_dtypes):
     import numpy as np
     d = in_dtypes[0] if in_dtypes[0] is not None else np.float32
-    return [d, np.int32, np.float32, d, d], [d], []
+    return [d, np.int32, np.float32] + [d] * (len(in_dtypes) - 3), [d], []
 
 
-@register("moe_experts",
-          arg_names=("data", "indices", "weights", "up_weight",
-                     "down_weight"),
+@register("moe_experts", arg_names=_experts_args,
           attr_types={"num_experts": parse_int, "experts_held": parse_int,
                       "first_expert": parse_int, "num_hidden": parse_int,
-                      "act_type": parse_str},
-          defaults={"first_expert": 0, "act_type": "relu2"},
+                      "act_type": parse_str, "gated": parse_bool},
+          defaults={"first_expert": 0, "act_type": "relu2", "gated": False},
           infer_shape=_experts_infer, infer_type=_experts_types)
 def _moe_experts(data, indices, weights, up_weight, down_weight,
-                 num_experts=None, experts_held=None, first_expert=0,
-                 num_hidden=None, act_type="relu2"):
+                 gate_weight=None, num_experts=None, experts_held=None,
+                 first_expert=0, num_hidden=None, act_type="relu2",
+                 gated=False):
     """The held experts' part of the routed result.  data (N, C); indices
     and weights (N, k) from ``moe_router``, over all ``num_experts``;
     up_weight (held, F, C), down_weight (held, C, F); expert ``e`` is
-    ``down_e(act(up_e u))``, no bias.  Returns (N, C)."""
+    ``down_e(act(up_e u))``, no bias.  ``gated`` adds a sixth input,
+    gate_weight (held, F, C), and expert ``e`` is ``down_e(act(gate_e u) *
+    up_e u)`` (``act_type`` silu: SwiGLU).  Returns (N, C)."""
+    del gated                       # told by the sixth input's presence
     out, stats, worked = _routed(
         data, indices, weights, up_weight, down_weight, int(first_expert),
-        int(num_experts), ACTIVATIONS[act_type])
+        int(num_experts), ACTIVATIONS[act_type], gate_weight)
     _tel.device_counter("moe", jax.lax.stop_gradient(stats))
     _tel.device_counter("moe_rows", jax.lax.stop_gradient(worked))
     return out

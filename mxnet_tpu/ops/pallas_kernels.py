@@ -29,6 +29,11 @@ of the shape that the guard ``flash_available`` asks too, so the guard and
 the kernels cannot disagree; explicit ``block_q`` / ``block_k`` override it
 in all three kernels.
 
+The value heads may have a width of their own (Dv beside the query/key
+heads' D: latent attention's 128 beside 192): v, the result and their
+gradients carry Dv through all three kernels, the scores and dQ / dK carry
+D, nothing is padded, and the blocks are planned at the wider of the two.
+
 Used by ``dot_product_attention`` (ops/attention.py) on TPU for long
 sequences; everything is shape-guarded so XLA's fused attention remains the
 fallback.
@@ -112,18 +117,25 @@ def flash_blocks(t, d, itemsize):
 
 def flash_available(q_shape, k_shape=None, v_shape=None, block_q=None,
                     block_k=None):
-    """Shape guard: self-attention only (q/k/v shapes equal; grouped-query
-    key/value heads are repeated in ``dot_product_attention`` before this is
-    asked, other unequal shapes go to XLA), D sublane-friendly, T divisible into blocks, and each kernel's whole-T
-    residents and score tiles within the VMEM budget at the f32 upper bound
+    """Shape guard: self-attention only (q and k shapes equal, v's equal
+    but for its last axis: value heads may be narrower or wider than the
+    query/key heads, as a latent-attention layer's 128 beside 192, and the
+    kernels then take both widths; grouped-query key/value heads are
+    repeated in ``dot_product_attention`` before this is asked, other
+    unequal shapes go to XLA), both widths sublane-friendly, T divisible
+    into blocks, and each kernel's whole-T residents and score tiles within
+    the VMEM budget at the f32 upper bound and the wider of the two widths
     (``flash_blocks`` at 4 bytes; with explicit blocks, those blocks)."""
     if len(q_shape) != 4:
         return False
-    for other in (k_shape, v_shape):
-        if other is not None and tuple(other) != tuple(q_shape):
-            return False  # cross-attention -> XLA path
+    if k_shape is not None and tuple(k_shape) != tuple(q_shape):
+        return False  # cross-attention -> XLA path
     t, d = q_shape[2], q_shape[3]
-    if d % 8 or d > 256:
+    if v_shape is not None:
+        if tuple(v_shape[:3]) != tuple(q_shape[:3]) or v_shape[3] % 8:
+            return False
+        d = max(d, v_shape[3])
+    if q_shape[3] % 8 or d > 256:
         return False
     if block_q is None and block_k is None:
         return flash_blocks(t, d, 4) is not None
@@ -183,12 +195,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     # refs carry one (bh) slice: q (1, block_q, D), k/v (1, T, D)
     j = pl.program_id(1)
     q = _scaled(q_ref[0], scale)                      # (bq, D), input dtype
-    bq, d = q.shape
+    bq, d = q.shape[0], v_ref.shape[2]                # d: the value's width
     q_pos = _positions(j * block_q, bq, 1)
 
     def fold(masked):
         def body(kb, carry):
-            acc, m, l = carry                         # (D, bq), (1, bq) x 2
+            acc, m, l = carry                         # (Dv, bq), (1, bq) x 2
             start = pl.multiple_of(kb * block_k, block_k)
             k = k_ref[0, pl.ds(start, block_k), :]
             v = v_ref[0, pl.ds(start, block_k), :]
@@ -219,9 +231,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=False):
-    """Blocked attention over (B, H, T, D); same semantics as
-    ``attention_reference``.  ``block_q`` / ``block_k`` None: each kernel's
-    blocks from ``flash_blocks``."""
+    """Blocked attention over q, k (B, H, T, D) and v (B, H, T, Dv), Dv
+    equal to D or not; same semantics as ``attention_reference``, the
+    result (B, H, T, Dv).  ``block_q`` / ``block_k`` None: each kernel's
+    blocks from ``flash_blocks`` at the wider of D and Dv."""
     return _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k,
                            interpret)[0]
 
@@ -232,11 +245,12 @@ _PARALLEL = pltpu.CompilerParams(
 
 def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     b, h, t, d = q.shape
+    dv = v.shape[3]
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q, block_k = _blocks(t, d, q.dtype, block_q, block_k)
+    block_q, block_k = _blocks(t, max(d, dv), q.dtype, block_q, block_k)
     qf = q.reshape(b * h, t, d)
     kf = k.reshape(b * h, t, d)
-    vf = v.reshape(b * h, t, d)
+    vf = v.reshape(b * h, t, dv)
     kernel = functools.partial(_fwd_kernel, scale=sc, causal=causal,
                                block_q=block_q, block_k=block_k, seq_len=t)
     out, lse = pl.pallas_call(
@@ -245,21 +259,21 @@ def _flash_fwd_impl(q, k, v, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, t, dv), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32),
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
         name="mxtpu_flash_fwd",
     )(qf, kf, vf)
-    return out.reshape(b, h, t, d), lse.reshape(b, h, t, 1)
+    return out.reshape(b, h, t, dv), lse.reshape(b, h, t, 1)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
@@ -333,7 +347,8 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
         return body
 
     zeros = jnp.zeros((bk, d), jnp.float32)
-    carry = (zeros, zeros)
+    carry = (zeros, zeros if v.shape[1] == d
+             else jnp.zeros(v.shape, jnp.float32))
     num_qb = seq_len // block_q
     if causal:
         # Q blocks from the first the diagonal reaches, masked; unmasked
@@ -351,9 +366,10 @@ def _dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, dk_ref,
 
 def _flash_dq(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
               interpret):
-    """dQ of (BH, T, D) operands; ``lsef`` / ``deltaf`` f32 rows
-    (BH, T / block_q, 1, block_q), one a grid step."""
+    """dQ of (BH, T, D) operands (v and dO (BH, T, Dv)); ``lsef`` /
+    ``deltaf`` f32 rows (BH, T / block_q, 1, block_q), one a grid step."""
     bh, t, d = qf.shape
+    dv = vf.shape[2]
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=sc, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=t),
@@ -361,8 +377,8 @@ def _flash_dq(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, t, dv), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, 1, block_q), lambda i, j: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, 1, block_q), lambda i, j: (i, j, 0, 0)),
         ],
@@ -379,6 +395,7 @@ def _flash_dkv(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
     """dK, dV of (BH, T, D) operands; ``lsef`` / ``deltaf`` as for
     ``_flash_dq``, all of a head's rows resident."""
     bh, t, d = qf.shape
+    dv = vf.shape[2]
     rows = (t // block_q, 1, block_q)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=sc, causal=causal,
@@ -386,19 +403,19 @@ def _flash_dkv(qf, kf, vf, gf, lsef, deltaf, causal, sc, block_q, block_k,
         grid=(bh, t // block_k),
         in_specs=[
             pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, t, dv), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1,) + rows, lambda i, j: (i, 0, 0, 0)),
             pl.BlockSpec((1,) + rows, lambda i, j: (i, 0, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j: (i, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda i, j: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), vf.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), vf.dtype),
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
@@ -413,17 +430,17 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
     b, h, t, d = q.shape
     sc = scale if scale is not None else 1.0 / math.sqrt(d)
-    block_q, block_k = _blocks(t, d, q.dtype, block_q, block_k)
+    block_q, block_k = _blocks(t, max(d, v.shape[3]), q.dtype, block_q,
+                               block_k)
     # delta = rowsum(dO * O): one fused elementwise+reduce pass in XLA
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(axis=-1)
     # q, k, v, dO flat over heads; lse, delta as one (1, block_q) row a Q block
-    args = [x.reshape(b * h, t, d) for x in (q, k, v, g)] + \
+    args = [x.reshape(b * h, t, x.shape[3]) for x in (q, k, v, g)] + \
         [x.reshape(b * h, t // block_q, 1, block_q) for x in (lse, delta)]
     args += [causal, sc, block_q, block_k, interpret]
     dq = _flash_dq(*args)
     dk, dv = _flash_dkv(*args)
-    return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-            dv.reshape(b, h, t, d))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _flash_bwd_xla(causal, scale, block_q, block_k, interpret, res, g):
@@ -465,8 +482,8 @@ def _flash_bwd_xla(causal, scale, block_q, block_k, interpret, res, g):
         return dq, dk, dv
 
     zeros = jnp.zeros((b, h, t, d), jnp.float32)
-    dq, dk, dv = jax.lax.fori_loop(0, nkb, grad_fold,
-                                   (zeros, zeros, zeros))
+    dq, dk, dv = jax.lax.fori_loop(
+        0, nkb, grad_fold, (zeros, zeros, jnp.zeros(v.shape, jnp.float32)))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

@@ -47,29 +47,39 @@ import jax.numpy as jnp
 
 from . import pallas_kernels
 from .nn import ACTIVATIONS
-from .registry import register, parse_int, parse_str
+from .registry import register, parse_bool, parse_int, parse_str
 
 
 # ------------------------------------------------------- causal convolution
+def _conv_args(attrs):
+    return ["data", "weight"] if attrs.get("no_bias", False) else \
+        ["data", "weight", "bias"]
+
+
 def _conv_infer(attrs, in_shapes):
     data = in_shapes[0]
     ins = list(in_shapes)
     if data is not None:
         ins[1] = (data[-1], int(attrs.get("kernel")))
-        ins[2] = (data[-1],)
+        ins[2:] = [(data[-1],)] * (len(ins) - 2)
     return ins, [data], None
 
 
-@register("causal_conv1d", arg_names=("data", "weight", "bias"),
-          attr_types={"kernel": parse_int, "act_type": parse_str},
-          defaults={"act_type": None}, infer_shape=_conv_infer)
-def _causal_conv1d(data, weight, bias, kernel=None, act_type=None):
+@register("causal_conv1d", arg_names=_conv_args,
+          attr_types={"kernel": parse_int, "act_type": parse_str,
+                      "no_bias": parse_bool},
+          defaults={"act_type": None, "no_bias": False},
+          infer_shape=_conv_infer)
+def _causal_conv1d(data, weight, bias=None, kernel=None, act_type=None,
+                   no_bias=False):
     """Depthwise causal convolution over time: data (B, T, C), weight
     (C, K), ``y[t] = sum_j weight[:, j] x[t - (K - 1) + j] + bias`` with
     zeros before t = 0 (torch ``Conv1d(groups=C, padding=K - 1)`` cut to T),
-    then ``act_type`` (an ``Activation`` type) if given.  K shifted
-    multiply-adds, accumulated in float32; the backward forms them again
-    from the input, which alone is kept."""
+    then ``act_type`` (an ``Activation`` type) if given; ``no_bias`` leaves
+    the bias and its input out.  K shifted multiply-adds, accumulated in
+    float32; the backward forms them again from the input, which alone is
+    kept."""
+    del no_bias                     # told by the third input's absence
     act = ACTIVATIONS[act_type] if act_type else None
 
     @jax.checkpoint
@@ -78,8 +88,9 @@ def _causal_conv1d(data, weight, bias, kernel=None, act_type=None):
         padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(
             jnp.float32)
         w = weight.astype(jnp.float32)
-        y = sum(padded[:, j:j + t, :] * w[:, j] for j in range(k)) \
-            + bias.astype(jnp.float32)
+        y = sum(padded[:, j:j + t, :] * w[:, j] for j in range(k))
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
         return (act(y) if act else y).astype(data.dtype)
     return conv(data, weight, bias)
 

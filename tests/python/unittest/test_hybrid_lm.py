@@ -248,3 +248,145 @@ def test_a_second_chunk_does_not_compile_the_program_again():
             "softmax_label": label[2 * c:2 * c + 2]}, 1, stacked=True)
     (program,) = ts._multi_cache.values()
     assert program._cache_size() == 1
+
+
+# ------------------------------------- two-part layers: K, L, D, gated E
+from benchmark.reference import kda_lm as kda_ref  # noqa: E402
+
+# three layers of a mixer and a feed-forward (KDA + dense, KDA + experts, MLA
+# + experts), T = 40 over rule chunks of 16 (the last one ragged), 4 of 8
+# gated experts held from expert 2 on, value heads narrower than key heads
+KARGS = dict(pattern="KDKELE", vocab_size=64, seq_len=40, num_hidden=32,
+             kda_heads=4, kda_head_dim=8, kda_chunk=16, conv_kernel=4,
+             num_heads=4, kv_lora_rank=16, qk_nope_head_dim=8,
+             qk_rope_head_dim=4, v_head_dim=6, mlp_hidden=48, mlp_act="silu",
+             mlp_gated=True, num_experts=8, experts_held=4, first_expert=2,
+             experts_per_token=2, expert_hidden=16, shared_hidden=16,
+             routed_scale=2.446, eps=1e-5)
+KCFG = {"hidden_size": 32, "vocab_size": 64, "num_hidden_layers": 3,
+        "first_k_dense_replace": 1, "intermediate_size": 48,
+        "linear_attn_config": {"full_attn_layers": [3], "kda_layers": [1, 2],
+                               "head_dim": 8, "num_heads": 4,
+                               "short_conv_kernel_size": 4},
+        "num_attention_heads": 4, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+        "qk_rope_head_dim": 4, "v_head_dim": 6,
+        "published": {"num_experts": 8}, "num_experts": 4,
+        "moe_intermediate_size": 16, "num_shared_experts": 1,
+        "num_experts_per_token": 2, "routed_scaling_factor": 2.446,
+        "rms_norm_eps": 1e-5, "deployment": {"first_expert": 2},
+        "max_position_embeddings": 40}
+
+
+def _kweights(seed):
+    """As ``_weights``: the rule's leaves where the published initialisation
+    has them, so that the state carried between chunks takes part."""
+    w = dict(gen.make_weights(kda_ref.param_shapes(KCFG),
+                              {"matrix_std": 0.2, "beta_bias_std": 0.05},
+                              seed))
+    r = np.random.RandomState(seed)
+    for k in w:
+        if k.endswith("_A_log"):
+            w[k] = jnp.asarray(np.log(r.uniform(1, 16, w[k].shape)),
+                               jnp.float32)
+        if k.endswith("_dt_bias"):
+            dt = np.exp(r.uniform(np.log(1e-3), np.log(0.1), w[k].shape))
+            w[k] = jnp.asarray(dt + np.log(-np.expm1(-dt)), jnp.float32)
+    return w
+
+
+def test_the_new_letters_name_the_references_leaves_and_shapes():
+    assert kda_ref.parts(KCFG) == KARGS["pattern"]
+    net = hybrid_lm.get_symbol(**KARGS)
+    shapes, outs, _ = net.infer_shape(data=(B, T), softmax_label=(B, T))
+    got = {k: v for k, v in zip(net.list_arguments(), shapes)
+           if k not in ("data", "softmax_label")}
+    assert got == {k: tuple(v) for k, v in kda_ref.param_shapes(KCFG).items()}
+    assert outs == [(B * T, 64)]
+    # a letter is a part on a pre-norm of its own
+    assert {k for k in got if k.endswith("_norm_gamma") and k.count("_") == 2
+            and k.startswith("layer")} == {
+        "layer%d_norm_gamma" % i for i in range(len(KARGS["pattern"]))}
+    assert {"layer0_q_conv_weight", "layer0_A_log", "layer0_dt_bias",
+            "layer0_o_norm_gamma", "layer1_mlp_gate_weight",
+            "layer3_experts_gate_weight", "layer3_shared_gate_weight",
+            "layer4_kv_a_proj_weight", "layer4_kv_a_norm_gamma"} <= set(got)
+    assert got["layer0_dt_bias"] == (32,) and got["layer0_A_log"] == (4,)
+    assert got["layer4_kv_a_proj_weight"] == (16 + 4, 32)
+    assert not any(k.endswith("_conv_bias") for k in got)
+
+
+def test_the_older_patterns_build_the_graph_they_built():
+    """The one-part letters M, E and * with their defaults: the same
+    leaves, no gate, the convolution's bias, squared-ReLU experts."""
+    net = hybrid_lm.get_symbol(**ARGS)
+    names = [k for k in net.list_arguments()
+             if k not in ("data", "softmax_label")]
+    assert set(names) == set(ref.param_shapes(CFG))
+    assert not any("_gate_" in k or "_mlp_" in k for k in names)
+    assert "layer0_conv_bias" in names
+    nodes = {n["name"]: n
+             for n in __import__("json").loads(net.tojson())["nodes"]}
+    experts, conv = nodes["layer1_experts"], nodes["layer0_conv"]
+    assert experts["param"]["act_type"] == "relu2"
+    assert experts["param"]["gated"] == "False" \
+        and len(experts["inputs"]) == 5
+    assert conv["param"]["no_bias"] == "False" and len(conv["inputs"]) == 3
+
+
+def test_the_two_part_layers_loss_and_every_gradient_against_the_reference():
+    """One SGD step of rate 1 through TrainStep, float32 on both sides:
+    the chunked rule against the token-by-token recurrence, the latent
+    attention, the gated MLP and experts; 2e-4 of each leaf's largest
+    entry."""
+    net = hybrid_lm.get_symbol(**KARGS)
+    opt = mx.optimizer.create("sgd", learning_rate=1.0,
+                              rescale_grad=1.0 / (B * T))
+    ts = TrainStep(net, opt)
+    w = _kweights(3)
+    data, label = _tokens(3, 1)
+    slots = ts.fopt.init_state({k: np.zeros(1, np.float32) for k in w})
+    state = {k: tuple(jnp.zeros_like(w[k]) for _ in v)
+             for k, v in slots.items()}
+    new, _, _, outs = ts(_copy(w), state, {}, {"data": data[0],
+                                               "softmax_label": label[0]})
+    loss, grads = jax.value_and_grad(kda_ref.mean_loss)(
+        w, data[0], label[0], KCFG, Exact())
+    probs = np.asarray(outs[0])
+    picked = probs[np.arange(B * T), np.asarray(label[0], np.int32).ravel()]
+    np.testing.assert_allclose(-np.log(picked).mean(), float(loss), rtol=1e-5)
+    for k in sorted(w):
+        want = np.asarray(grads[k])
+        got = np.asarray(w[k]) - np.asarray(new[k])
+        np.testing.assert_allclose(
+            got, want, atol=max(2e-4 * np.abs(want).max(), 1e-6), rtol=0,
+            err_msg=k)
+    assert not np.asarray(grads["layer3_router_bias"]).any()
+    for leaf in ("layer0_A_log", "layer0_dt_bias", "layer2_b_proj_weight",
+                 "layer4_kv_a_norm_gamma", "layer3_experts_gate_weight"):
+        assert np.abs(np.asarray(grads[leaf])).max() > 0, leaf
+
+
+def test_the_two_part_layers_through_run_steps_under_bfloat16():
+    """Three steps of Adam in one scan chunk under the bfloat16 policy:
+    finite, the counters of the two expert layers, and the float32
+    islands."""
+    net = hybrid_lm.get_symbol(**KARGS)
+    opt = mx.optimizer.create("adam", learning_rate=1e-3, beta1=0.9,
+                              beta2=0.95, epsilon=1e-8,
+                              rescale_grad=1.0 / (B * T))
+    ts = TrainStep(net, opt, policy=amp.Policy("bfloat16"))
+    w = _kweights(5)
+    data, label = _tokens(5, 3)
+    state = {k: (jnp.zeros_like(v), jnp.zeros_like(v)) for k, v in w.items()}
+    new, state, _, outs = ts.run_steps(
+        _copy(w), state, {}, {"data": data, "softmax_label": label}, 2,
+        stacked=True)
+    assert outs[0].shape == (B * T, 64)
+    assert all(bool(jnp.isfinite(v).all()) for v in new.values())
+    counted, steps = telemetry.device_counters()
+    assert steps == 3 and counted["moe"].shape == (2, 4)
+    assert (counted["moe"][:, 0] + counted["moe"][:, 2]
+            == 3 * B * T * 2).all() and not counted["moe"][:, 3].any()
+    assert ts._low.f32_leaves() == {
+        k for k in w if k.endswith(("_A_log", "_dt_bias", "_router_weight",
+                                    "_router_bias"))}

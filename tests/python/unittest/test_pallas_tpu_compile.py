@@ -85,6 +85,33 @@ def test_head_sizes_off_the_lane_width_compile(one_chip, d):
     _compile(one_chip, (1, 2, 1024, d), jnp.bfloat16)
 
 
+@pytest.mark.parametrize("heads,dtype", [(32, jnp.bfloat16),
+                                         (2, jnp.float32)])
+def test_value_heads_narrower_than_the_key_heads_compile(one_chip, heads,
+                                                         dtype):
+    """kimi-linear-steps-t4096's latent attention, (B, H, T) = (1, 32, 4096)
+    with query/key heads of 192 and value heads of 128, bfloat16: all three
+    kernels take both widths, and the results keep the value's.  (In
+    float32 the same widths compile at 2 heads; at 32 the forward's scoped
+    VMEM reads 16.07 MB of 16: as D = 256 in float32 does from 2 heads on,
+    PERF.md 7.)"""
+    q = jax.ShapeDtypeStruct((1, heads, 4096, 192), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, heads, 4096, 128), dtype, sharding=one_chip)
+    assert flash_available(q.shape, q.shape, v.shape)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True).astype(jnp.float32) ** 2).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("mxtpu_flash_fwd", "mxtpu_flash_dq", "mxtpu_flash_dkv"):
+        assert name in text
+    kind = "bf16" if dtype == jnp.bfloat16 else "f32"
+    assert kind + "[%d,4096,128]" % heads in text \
+        and kind + "[%d,4096,192]" % heads in text
+    assert not re.search(r"\[[0-9,]*4096,4096\]", text)
+
+
 @pytest.mark.parametrize("bq,bk", [(128, 128), (256, 512), (512, 256)])
 def test_explicit_blocks_compile(one_chip, bq, bk):
     _compile(one_chip, (1, 4, 2048, 64), jnp.bfloat16, block_q=bq,
@@ -129,6 +156,43 @@ def test_the_routed_experts_products_compile_at_the_cells_shape(one_chip,
         blocks, blocks, shaped((), jnp.int32)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert ("mxtpu_tgmm" if kw is None else "mxtpu_gmm") in text
+
+
+# kimi-linear-steps-t4096's gated experts: 8 held, hidden 2304, width 1024,
+# the 4,096 + 2,048 rows set aside in blocks of 256.  The products the gate
+# adds to a layer's forward and backward (the others are the shapes above
+# at these widths)
+KIMI_ROWS, KIMI_HIDDEN, KIMI_WIDTH = 6144, 2304, 1024
+GATED = {
+    "gate": (KIMI_HIDDEN, (HELD, KIMI_WIDTH, KIMI_HIDDEN), dict(
+        transpose_rhs=True, act=jax.nn.silu, out_dtype=jnp.float32)),
+    "up": (KIMI_HIDDEN, (HELD, KIMI_WIDTH, KIMI_HIDDEN), dict(
+        transpose_rhs=True, out_dtype=jnp.float32)),
+    "down": (KIMI_WIDTH, (HELD, KIMI_HIDDEN, KIMI_WIDTH), dict(
+        transpose_rhs=True)),
+    "d_rows": (KIMI_WIDTH, (HELD, KIMI_WIDTH, KIMI_HIDDEN), {}),
+    "d_gate": (KIMI_WIDTH, (KIMI_ROWS, KIMI_HIDDEN), None)}
+
+
+@pytest.mark.parametrize("product", sorted(GATED))
+def test_the_gated_experts_products_compile_at_the_cells_shape(one_chip,
+                                                               product):
+    from mxnet_tpu.ops.moe import capacity
+    assert capacity(4096, 8, 256, 8)[:2] == (256, KIMI_ROWS)
+    assert grouped_available(256, KIMI_HIDDEN, KIMI_WIDTH, 2)
+    width, second, kw = GATED[product]
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    if kw is None:
+        fn = lambda a, b, *t: grouped_matmul_t(a, b, *t, HELD)  # noqa: E731
+    else:
+        fn = lambda a, b, *t: grouped_matmul(a, b, *t, **kw)  # noqa: E731
+    blocks = shaped((KIMI_ROWS // 256,), jnp.int32)
+    text = jax.jit(fn).lower(
+        shaped((KIMI_ROWS, width), jnp.bfloat16),
+        shaped(second, jnp.bfloat16), blocks, blocks,
+        shaped((), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 # nemotron-twotower-steps-t4096's state-space mixer, (B, T, H, P, G, N, chunk),

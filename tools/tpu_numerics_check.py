@@ -2,8 +2,11 @@
 (no interpret mode) on the TPU and compared against its XLA formulation at
 bf16-appropriate tolerances — flash attention forward and both backward
 kernels, including the corners of its shape guard (those with f32 operands
-too), and the grouped products of the routed experts at the benchmark cell's
-own shape.  The interpret-mode twins of these checks run on the CPU harness
+too) and a latent-attention layer's value heads of 128 beside query/key heads
+of 192, the grouped products of the routed experts at the benchmark cell's
+own shape, the state-space scan's kernels, and the chunked gated delta rule
+(plain ``jax.numpy``, but at the chip's bfloat16 products) against its
+token-by-token recurrence.  The interpret-mode twins of these checks run on the CPU harness
 (test_pallas.py, test_moe.py).
 
 Run on a machine with a chip:  python tools/tpu_numerics_check.py
@@ -18,15 +21,17 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
-# (B, H, T, D, causal); the last four sit on flash_available's budgets
-# (T*D = 2**20 at each lane width it admits, and docs/long_context.md's shape)
+# (B, H, T, D, causal[, Dv]); four sit on flash_available's budgets (T*D =
+# 2**20 at each lane width it admits, and docs/long_context.md's shape); the
+# last is kimi-linear-steps-t4096's latent attention, value heads of 128
 FLASH_SHAPES = [(2, 4, 512, 64, False),
                 (2, 4, 512, 64, True),
                 (1, 8, 1024, 128, True),
                 (1, 2, 4096, 64, True),
                 (1, 1, 8192, 128, True),
                 (1, 1, 16384, 64, True),
-                (1, 1, 4096, 256, True)]
+                (1, 1, 4096, 256, True),
+                (1, 32, 4096, 192, True, 128)]
 
 # the routed experts of nemotron-twotower-steps-t4096: (experts held, hidden,
 # expert width, rows of a block), and each held expert's rows in three steps:
@@ -39,6 +44,11 @@ GROUPED_RUNS = [(192, 190, 200, 185, 256, 130, 257, 126),
 # the state-space mixer of nemotron-twotower-steps-t4096: (B, T, H, P, G, N,
 # chunk), bfloat16
 SSD_SHAPE = (1, 4096, 64, 64, 8, 128, 128)
+
+
+# the linear-attention mixer of kimi-linear-steps-t4096: (B, T, H, d_k, d_v),
+# bfloat16, chunks of 64
+KDA_SHAPE = (1, 4096, 32, 128, 128)
 
 
 def _rel(a, b):
@@ -56,16 +66,18 @@ def check_flash_attention():
     def loss_f(fn):
         return lambda a, b_, c: (fn(a, b_, c).astype(jnp.float32) ** 2).sum()
 
-    for (b, h_, t, d, causal) in FLASH_SHAPES:
-        assert flash_available((b, h_, t, d)), (b, h_, t, d)
+    for (b, h_, t, d, causal, *dv) in FLASH_SHAPES:
+        dv = dv[0] if dv else d
+        assert flash_available((b, h_, t, d), (b, h_, t, d),
+                               (b, h_, t, dv)), (b, h_, t, d, dv)
         # the guard plans VMEM at the f32 upper bound: its corners are
         # compiled with f32 operands too
         corner = t * d == 2 ** 20
         for dtype in (jnp.bfloat16, jnp.float32) if corner else (
                 jnp.bfloat16,):
             rng = np.random.RandomState(0)
-            q, k, v = (jnp.asarray(rng.randn(b, h_, t, d).astype(np.float32))
-                       .astype(dtype) for _ in range(3))
+            q, k, v = (jnp.asarray(rng.randn(b, h_, t, w).astype(np.float32))
+                       .astype(dtype) for w in (d, d, dv))
             q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
             flash = lambda a, b_, c: flash_attention(a, b_, c, causal)  # noqa: E731
             ref = lambda a, b_, c: attention_reference(  # noqa: E731
@@ -83,8 +95,9 @@ def check_flash_attention():
                     name, errs[-1], (b, h_, t, d, causal))
             print("PASS flash_attention %s %s blocks %s  rel err fwd %.1e "
                   "dq %.1e dk %.1e dv %.1e" % (
-                      (b, h_, t, d, causal), jnp.dtype(dtype).name,
-                      flash_blocks(t, d, jnp.dtype(dtype).itemsize),
+                      (b, h_, t, d, causal) + ((dv,) if dv != d else ()),
+                      jnp.dtype(dtype).name,
+                      flash_blocks(t, max(d, dv), jnp.dtype(dtype).itemsize),
                       *errs), flush=True)
 
 
@@ -194,6 +207,65 @@ def check_ssd_scan():
                                               zip(names, errs))), flush=True)
 
 
+def check_kda_scan():
+    """``kda_scan`` (the chunked form: sub-blocks, a triangular solve, a
+    state a chunk) against the token-by-token recurrence of
+    ``benchmark/reference/kda_lm.py`` in float32 (sums of products, no dot),
+    forward and the gradients of its seven inputs, at the published rates
+    (``test_kda.py``: the decay's step in 0.001-0.1, A in 1-16), where the
+    state carried from chunk to chunk matters and which the benchmark's own
+    seed does not reach.  Both sides take the same bfloat16 inputs."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import kda
+    from benchmark.reference import kda_lm as ref
+
+    bsz, t, h, dk, dv = KDA_SHAPE
+    chunk = 64
+    rng = np.random.RandomState(4)
+    bf16 = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
+    f32 = lambda v: jnp.asarray(v, jnp.float32)  # noqa: E731
+    step = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), (h * dk,)))
+    args = (bf16(bsz, t, h * dk), bf16(bsz, t, h * dk), bf16(bsz, t, h * dv),
+            bf16(bsz, t, h * dk) * 0.5, bf16(bsz, t, h),
+            f32(np.log(rng.uniform(1.0, 16.0, (h,)))),
+            f32(np.log(np.expm1(step))))
+    weight = bf16(bsz, t, h * dv).astype(jnp.float32)
+    chunked = lambda *a: kda._scan(*a, h=h, chunk=chunk)  # noqa: E731
+
+    def recurrence(q, k, v, gate, beta, a_log, dt_bias):
+        heads = lambda x: x.astype(jnp.float32).reshape(  # noqa: E731
+            bsz, t, h, -1)
+        g, b = kda.kda_gates(gate, beta, a_log, dt_bias, h)
+        o = jax.vmap(ref.delta_rule)(
+            ref._l2norm(heads(q)) * dk ** -0.5, ref._l2norm(heads(k)),
+            heads(v), g, b)
+        return o.reshape(bsz, t, h * dv)
+
+    def both(fn):
+        def loss(*a):
+            y = fn(*a)
+            return (y.astype(jnp.float32) * weight).sum(), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(7)), has_aux=True))(*args)
+        return (y,) + grads
+    got, want = both(chunked), both(recurrence)
+    alone = jax.jit(chunked)(*(v[:, t - chunk:] if v.ndim > 1 else v
+                               for v in args))
+    carried = _rel(alone, want[0][:, t - chunk:])
+    assert carried > 0.05, "the carried state is %.1e of o" % carried
+    names = "o q k v gate beta a_log dt_bias".split()
+    errs = [_rel(a, b) for a, b in zip(got, want)]
+    for name, err in zip(names, errs):
+        assert err < (2e-2 if name == "o" else 5e-2), \
+            "kda_scan %s rel err %.2e at %s" % (name, err, KDA_SHAPE)
+    print("PASS kda_scan %s bfloat16 chunks of %d carried state %.2f of o"
+          "  rel err %s" % (KDA_SHAPE, chunk, carried,
+                            " ".join("%s %.1e" % (k, e) for k, e in
+                                     zip(names, errs))), flush=True)
+
+
 if __name__ == "__main__":
     import jax
     if jax.default_backend() != "tpu":
@@ -205,4 +277,5 @@ if __name__ == "__main__":
     check_flash_attention()
     check_grouped_products()
     check_ssd_scan()
+    check_kda_scan()
     print("ALL TPU NUMERICS CHECKS PASSED")
