@@ -17,8 +17,37 @@ Diag(a_t) S_{t-1} + k_t u_t^T``, and inside a chunk that enters with state
 
 so a chunk is matrix products of (L, L) and (L, d) blocks and one solve with
 a unit lower-triangular matrix (the WY form): ``(I + A)^-1`` is applied once
-to ``beta V`` and to ``beta K exp G``, for every chunk at once; what is
-left between chunks is a short sequential pass of four products a chunk.
+to ``beta V`` and to ``beta K exp G``; what is left between chunks is a
+short sequential pass of four products a chunk.  One algorithm, two forms of
+it, chosen from what the op observes (the backend and the shape, never an
+option):
+
+- On a TPU, for the shapes ``pallas_kernels.kda_available`` takes (d_k and
+  d_v of whole 128-lane tiles, chunks of 16, 32, 64 or 128; T is padded to
+  whole chunks): the kernels ``mxtpu_kda_fwd`` / ``_states`` / ``_bwd``.
+  They read q, k, v and the log-decay as column blocks of the op's
+  (B, T, H d) arrays where they lie; a chunk's running log-decay, its
+  (L, L) blocks, the solve and the carried (d_k, d_v) state stay in VMEM; o
+  leaves once, in value's dtype.  The backward is written by hand under one
+  ``jax.custom_vjp`` whose residuals are the op's seven inputs: the states
+  entering each chunk are formed again by a states-only pass into one
+  (T / L, H, d_v, d_k) float32 array (128 MiB at T = 4096 with 32 heads of
+  128, alive during that layer's backward alone) beside each chunk's
+  ``(I + A)^-1`` (32 MiB), then the chunks are taken last to first with the
+  state's gradient carried in VMEM; with ``W = T^-1 R`` the solve's
+  gradient is ``dR = T^-T dW`` and ``dA = -tril(dR W^T, -1)``: products
+  alone.  The l2 norms, the gates ``kda_gates`` and ``sigmoid(beta)`` are
+  made outside, on their (T, H, d) arrays, and autodiff carries the
+  kernels' gradients through them to q, k, the gate, beta, ``A_log`` and
+  ``dt_bias``.  The two callers of the kernels are under ``jax.jit``: a
+  model's mixers share one trace and one lowered copy of each body, which
+  is what a run pays every time it starts (PERF.md 6, PR 35).
+- Everywhere else: ``kda_chunked``, plain ``jax.numpy``, which is also the
+  tests' oracle.  Its backward is autodiff under ``jax.checkpoint``: of the
+  forward only the op's inputs are kept, the blocks inside the chunks are
+  formed again a few heads at a time (so that no (T / L, L, L, d_k) array
+  of all heads exists at once), and the pass between chunks keeps one
+  state a chunk, never one a token.
 
 **Never the exponential of a positive sum.**  ``exp(G_i - G_j)`` is needed
 for i >= j only, where it is at most 1, but ``exp(G_i) exp(-G_j)`` overflows
@@ -30,16 +59,11 @@ below the diagonal against the running sum at its own first row ``r``, as
 below 0 (rows at or after r, columns before it); a sub-block on the diagonal
 directly, element by element, from ``exp(G_i - G_j)`` where i >= j.
 
-Precision: the log-decay ``-exp(A_log) softplus(gate + dt_bias)``, its
-running sums, every ``exp``, ``beta``, the l2 norms, the triangular solve
-and the carried state are float32 whatever the input's dtype; the matrix
-products take operands in the input's dtype and accumulate in float32.
-
-Plain ``jax.numpy``; the backward is autodiff under ``jax.checkpoint``: of
-the forward only the op's inputs are kept, the blocks inside the chunks are
-formed again a few heads at a time (so that no (T / L, L, L, d_k) array of
-all heads exists at once), and the pass between chunks keeps one state a
-chunk, never one a token.
+Precision, in both forms alike: the log-decay ``-exp(A_log) softplus(gate +
+dt_bias)``, its running sums, every ``exp``, ``beta``, the l2 norms, the
+triangular solve and the carried state are float32 whatever the input's
+dtype; the matrix products take operands in the input's dtype, cast where
+the plain form casts them, and accumulate in float32.
 """
 from __future__ import annotations
 
@@ -48,6 +72,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import pallas_kernels
 from .registry import register, parse_int
 
 _SUB = 16               # rows of a sub-block
@@ -176,14 +201,62 @@ def kda_gates(gate, beta, a_log, dt_bias, h):
     return g, jax.nn.sigmoid(beta.astype(f32))
 
 
-def _scan(q, k, v, gate, beta, a_log, dt_bias, h, chunk):
+def _rule_inputs(q, k, gate, beta, a_log, dt_bias, h):
+    """What the rule takes, from the op's inputs: q and k (B, T, H, d_k)
+    after their l2 norms, q scaled, in their dtypes; the float32 log-decay
+    (B, T, H, d_k) and beta (B, T, H)."""
     bsz, t, _ = q.shape
     dk = q.shape[2] // h
     qh = (_l2norm(q.reshape(bsz, t, h, dk)) * dk ** -0.5).astype(q.dtype)
     kh = _l2norm(k.reshape(bsz, t, h, dk)).astype(k.dtype)
-    g, b = kda_gates(gate, beta, a_log, dt_bias, h)
+    return (qh, kh) + kda_gates(gate, beta, a_log, dt_bias, h)
+
+
+def _scan(q, k, v, gate, beta, a_log, dt_bias, h, chunk):
+    bsz, t, _ = q.shape
+    qh, kh, g, b = _rule_inputs(q, k, gate, beta, a_log, dt_bias, h)
     o = kda_chunked(qh, kh, v.reshape(bsz, t, h, -1), g, b, chunk)
     return o.astype(v.dtype).reshape(v.shape)
+
+
+def _kernel_inputs(q, k, v, gate, beta, a_log, dt_bias, h, chunk):
+    """``_rule_inputs`` as the kernels take them, (B, T', H d) with T' whole
+    chunks: the rows added have k = 0, beta = 0 and a log-decay of 0, which
+    leave the state as it is."""
+    bsz, t, _ = q.shape
+    qh, kh, g, b = _rule_inputs(q, k, gate, beta, a_log, dt_bias, h)
+    flat = (qh.reshape(q.shape), kh.reshape(k.shape), v,
+            g.reshape(q.shape), b)
+    return tuple(jnp.pad(x, ((0, 0), (0, -t % chunk), (0, 0))) for x in flat)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _scan_kernels(q, k, v, gate, beta, a_log, dt_bias, h, chunk,
+                  interpret=False):
+    """``_scan`` through the Pallas kernels (``pallas_kernels.kda_scan_*``),
+    the backward written by hand: the residuals are the seven inputs."""
+    o = pallas_kernels.kda_scan_fwd(
+        *_kernel_inputs(q, k, v, gate, beta, a_log, dt_bias, h, chunk), h,
+        chunk, interpret)
+    return o[:, :v.shape[1]]
+
+
+def _scan_kernels_fwd(q, k, v, gate, beta, a_log, dt_bias, h, chunk,
+                      interpret):
+    return _scan_kernels(q, k, v, gate, beta, a_log, dt_bias, h, chunk,
+                         interpret), (q, k, v, gate, beta, a_log, dt_bias)
+
+
+def _scan_kernels_bwd(h, chunk, interpret, res, do):
+    t = do.shape[1]
+    inputs, outside = jax.vjp(
+        functools.partial(_kernel_inputs, h=h, chunk=chunk), *res)
+    do = jnp.pad(do, ((0, 0), (0, -t % chunk), (0, 0)))
+    return outside(pallas_kernels.kda_scan_bwd(*inputs, do, h, chunk,
+                                               interpret))
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
 
 
 def _infer(attrs, in_shapes):
@@ -214,10 +287,17 @@ def _kda_scan(query, key, value, gate, beta, a_log, dt_bias, num_heads=None,
     ``exp(-exp(a_log) softplus(gate + dt_bias))`` for every key channel,
     the step ``sigmoid(beta)``, then the gated delta rule from a zero state.
     Returns o (B, T, H d_v) in value's dtype; of the forward only the inputs
-    are kept.  ``chunk_size`` is a whole number of 16-row sub-blocks."""
+    are kept.  ``chunk_size`` is a whole number of 16-row sub-blocks.  On a
+    TPU, for the shapes ``kda_available`` takes, the Pallas kernels; else
+    the plain form."""
     h, chunk = int(num_heads), int(chunk_size)
     if chunk % _SUB:
         raise ValueError("kda_scan: chunk_size %d is no multiple of %d"
                          % (chunk, _SUB))
+    d_k, d_v = query.shape[2] // h, value.shape[2] // h
+    if jax.default_backend() == "tpu" and pallas_kernels.kda_available(
+            query.shape[1], h, d_k, d_v, chunk, value.dtype.itemsize):
+        return _scan_kernels(query, key, value, gate, beta, a_log, dt_bias,
+                             h, chunk)
     core = jax.checkpoint(functools.partial(_scan, h=h, chunk=chunk))
     return core(query, key, value, gate, beta, a_log, dt_bias)

@@ -47,6 +47,12 @@ rows sorted by expert (ops/moe.py); tiles from ``grouped_blocks``, guard
 and ``_bwd``: the (L, L) blocks and the carried state never leave VMEM;
 heads a grid step from ``ssd_blocks``, guard ``ssd_available``.
 
+``kda_scan_fwd`` / ``kda_scan_bwd``: the chunked gated delta rule of
+``kda_scan`` (ops/kda.py) as three kernels, ``mxtpu_kda_fwd``, ``_states``
+and ``_bwd``: a chunk's (L, L) blocks, its unit-triangular solve and the
+carried state never leave VMEM; heads a grid step from ``kda_blocks``, guard
+``kda_available``.
+
 All of them are tested in Pallas interpret mode on the CPU harness, compiled
 for a described v5e by ``test_pallas_tpu_compile.py`` and run against their
 plain forms on the chip by ``tools/tpu_numerics_check.py``.
@@ -64,7 +70,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["flash_attention", "flash_available", "flash_blocks",
            "grouped_matmul", "grouped_matmul_t", "grouped_available",
            "grouped_blocks", "ssd_scan_fwd", "ssd_scan_bwd", "ssd_available",
-           "ssd_blocks"]
+           "ssd_blocks", "kda_scan_fwd", "kda_scan_bwd", "kda_available",
+           "kda_blocks"]
 
 _NEG_INF = -1e30
 
@@ -1079,3 +1086,565 @@ def ssd_scan_bwd(xbc, dt, acs, d, dy, h, p, g, chunk, interpret=False):
     dacs = dacs + grows.transpose(0, 3, 1, 2).reshape(bsz, t, h)
     return (jnp.concatenate([dx, db, dc], axis=-1), ddt, dacs,
             dd.reshape(bsz, h, p).sum((0, 2)))
+
+
+
+
+# ------------------------------------------------------- gated delta rule
+# The chunked rule of ``ops/kda.py`` (Kimi Delta Attention), forward and
+# backward, with nothing of size (T / L, L, L) or (L, L, d), no triangular
+# solve and no carried state of every token in HBM.  A grid step takes one
+# chunk of L rows of ``heads`` heads, straight out of the op's (B, T, H d)
+# arrays by column blocks, and the chunk axis is the grid's last, sequential
+# one: the state of those heads lives in float32 VMEM scratch from chunk to
+# chunk (its gradient, in the backward, from the last chunk to the first).
+# The state is held transposed, (d_v, d_k): the whole chunk's decay
+# ``exp(G_L)`` is then a row that broadcasts down the sublanes, and so is
+# its gradient.
+#
+# Inside a grid step the heads are the leading axis of every array, and each
+# stage is written once for all of them: elementwise work over
+# (heads, L, d) arrays, the products as batched ``dot_general``s.  One head
+# alone is a chain of dependent steps (running sum, columns, elimination,
+# joins, the products with the state) that leaves the units waiting on each
+# other; the heads' chains share nothing, and a stage of all of them at once
+# lets them overlap (PERF.md 6, PR 34: a third off the forward).  It is also
+# what the host pays for: a body traced and lowered once, not once a head
+# (PERF.md 6, PR 35).
+#
+# The stages: the running log-decay ``G`` (a product with the
+# lower-triangular ones, exact: ``_kda_running``); the blocks ``M`` and the
+# raw ``A`` (before ``beta``) by 16-row sub-blocks as ``ops/kda.py`` states
+# them: a sub-block below the diagonal as a product of rows scaled by
+# ``exp(G_i - G_r)`` and columns scaled by ``exp(G_r - G_j)``, r the
+# sub-block's first row; the diagonal sub-blocks column by column, element
+# by element, ``exp(G_i - G_j)`` formed once for both: the 16 columns are a
+# ``fori_loop`` whose body is traced once and unrolled when it is lowered (a
+# loop left rolled keeps the columns from overlapping: 9.7 ms a layer for
+# 7.3, PERF.md 6, PR 35).  No exponent is above 0.  ``(I + A)^-1`` in float32:
+# the 16 x 16 diagonal blocks by forward elimination on the VPU, all at once
+# and in the same loop that forms their columns, then the sub-blocks joined
+# by forward substitution written as float32 products (a pair of blocks
+# ``[[P, 0], [-Q A P, Q]]``, then pairs of pairs).
+#
+# The backward is two calls.  The states pass runs the chunks forward
+# without q, ``M`` or o and hands back the state entering each chunk and
+# each chunk's ``(I + A)^-1``; the backward pass forms the blocks again from
+# the inputs, keeps the diagonal sub-blocks' ``exp`` in VMEM scratch for its
+# second pass over them, and applies the inverse transposed: with ``W =
+# T^-1 R``, ``dR = T^-T dW`` and ``dA = -tril(dR W^T, -1)``.
+#
+# The kernels take ``g``, the float32 log-decay of every key channel
+# (``kda_gates`` makes it outside), and ``beta`` as columns,
+# (B, H / heads, T, heads); the backward hands both gradients back the same
+# way, the log-decay's summed from each row to its chunk's end.
+
+_KDA_SUB = 16           # rows of a sub-block: ops/kda.py's
+_KDA_HEADS = 8          # at most: what a grid step keeps alive grows with them
+_KDA_CHUNKS = (16, 32, 64, 128)   # sub-blocks joined by halves: 1, 2, 4, 8
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+# ``_NT``, ``_TN`` and ``_NN`` a head: the heads of a grid step lead
+_HNT = (((2,), (2,)), ((0,), (0,)))
+_HTN = (((1,), (1,)), ((0,), (0,)))
+_HNN = (((2,), (1,)), ((0,), (0,)))
+
+
+def _kda_vmem(heads, dk, dv, chunk, itemsize):
+    """What one grid step of the backward, the largest of the three, keeps
+    in VMEM: two buffers of q, k, v, do and their gradients, of the float32
+    log-decay and its gradient, of the entering state and of the chunk's
+    inverse; each head's scratch (state, ``G``, k, the column sums and the
+    16 columns' ``exp`` of the diagonal sub-blocks; the forward's inverse
+    in the making is no larger than two padded columns); the columns of
+    beta padded to 128 lanes; and the float32 temporaries of the body, all
+    heads' alive at once ((L, d): three dozen a head; (L, L): a dozen)."""
+    wide = heads * (2 * dk + dv)
+    blocks = 2 * chunk * (2 * wide * itemsize + 2 * heads * dk * 4) \
+        + 2 * chunk * heads * dv * itemsize \
+        + 2 * heads * (dv * dk + chunk * chunk) * 4
+    scratch = heads * (dv * dk + (3 + _KDA_SUB) * chunk * dk
+                       + 2 * chunk * 128) * 4
+    body = heads * chunk * (36 * max(dk, dv) + 12 * max(chunk, 128)) * 4
+    return blocks + scratch + 4 * chunk * 128 * 4 + body
+
+
+def kda_blocks(t, h, dk, dv, chunk, itemsize):
+    """Heads a grid step of the three kernels of the gated delta rule works,
+    for T steps of H heads with keys of d_k and values of d_v, chunks of
+    ``chunk``, operands of ``itemsize`` bytes: the most (8 at most) that
+    divide H and fit the VMEM budget.  None where the kernels do not apply:
+    a d_k or d_v that is no multiple of the 128 lanes, a chunk that is not
+    16, 32, 64 or 128 rows.  T is any: the caller pads it to whole chunks."""
+    if min(t, h, dk, dv) <= 0 or dk % 128 or dv % 128 \
+            or chunk not in _KDA_CHUNKS:
+        return None
+    return next((n for n in range(min(h, _KDA_HEADS), 0, -1)
+                 if h % n == 0
+                 and _kda_vmem(n, dk, dv, chunk, itemsize) <= _VMEM_BUDGET),
+                None)
+
+
+def kda_available(t, h, dk, dv, chunk, itemsize):
+    """Shape guard of the gated delta rule's kernels: ``kda_blocks`` finds a
+    tiling."""
+    return kda_blocks(t, h, dk, dv, chunk, itemsize) is not None
+
+
+def _exact_dot(a, b, dims):
+    """A float32 product kept float32: the running sums, the solve."""
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _kda_dot(a, b, dims, cd):
+    """A product of the rule: operands in the compute dtype ``cd``, float32
+    accumulation; float32 operands multiply as float32."""
+    return jax.lax.dot_general(
+        a.astype(cd), b.astype(cd), dims,
+        precision=_HIGHEST if cd == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+
+
+def _kda_masks(chunk):
+    """A chunk's (L, L) geometry, made once a grid step and shared by its
+    heads: ``tri`` the lower-triangular ones (running sums down a chunk)
+    and ``tri_t``, the upper, bfloat16; ``eye``, float32; ``incl`` j <= i
+    and ``strict`` j < i; ``own``, (i, j) inside a diagonal sub-block,
+    and ``at``, j counted from that sub-block's first column; ``rows`` and
+    ``cols``, i and j; ``sub`` (L, 1), a row's place in its
+    sub-block, and ``col`` (1, L)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    incl = cols <= rows
+    return {"incl": incl, "strict": cols < rows,
+            "tri": jnp.where(incl, 1.0, 0.0).astype(jnp.bfloat16),
+            "tri_t": jnp.where(cols >= rows, 1.0, 0.0).astype(jnp.bfloat16),
+            "eye": jnp.where(cols == rows, 1.0, 0.0).astype(jnp.float32),
+            "rows": rows, "cols": cols, "at": cols - (rows & -_KDA_SUB),
+            "own": (cols & -_KDA_SUB) == (rows & -_KDA_SUB),
+            "sub": row & (_KDA_SUB - 1),
+            "col": jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)}
+
+
+def _kda_heads(ref, heads):
+    """A grid step's (L, heads * d) block with its heads leading:
+    (heads, L, d)."""
+    d = ref.shape[1] // heads
+    return jnp.stack([ref[:, r * d:(r + 1) * d] for r in range(heads)])
+
+
+def _kda_put(ref, value):
+    """``_kda_heads`` back: (heads, L, d) into a (L, heads * d) block."""
+    d = value.shape[2]
+    for r in range(value.shape[0]):
+        ref[:, r * d:(r + 1) * d] = value[r].astype(ref.dtype)
+
+
+def _kda_by_block(ref, j):
+    """Row j of every sub-block of a (heads, L, width) scratch, each spread
+    over its sub-block's 16 rows: (heads, L, width).  j may be traced."""
+    heads, rows, width = ref.shape
+    return jnp.concatenate(
+        [jnp.broadcast_to(ref[:, pl.ds(top + j, 1), :],
+                          (heads, _KDA_SUB, width))
+         for top in range(0, rows, _KDA_SUB)], axis=1)
+
+
+def _kda_running(tri, g):
+    """The running sum of ``g`` (heads, L, d) float32 down the chunk,
+    float32: ``tri``, the lower-triangular ones, is exact in bfloat16, so g
+    goes through the MXU as its three bfloat16 parts side by side, one
+    product a head, and nothing of its 24 bits is lost."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    heads, _, d = g.shape
+    hi = g.astype(bf16)
+    rest = g - hi.astype(f32)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(f32)).astype(bf16)
+    parts = jax.lax.dot_general(
+        jnp.broadcast_to(tri, (heads,) + tri.shape),
+        jnp.concatenate([hi, mid, low], axis=2), _HNN,
+        preferred_element_type=f32)
+    return parts[:, :, :d] + parts[:, :, d:2 * d] + parts[:, :, 2 * d:]
+
+
+def _kda_chunk(qf, kf, g, beta, masks, cd, g_s, k_s, p_s=None, e_s=None,
+               tinv=None):
+    """A chunk of a grid step's heads, formed from its own rows.  ``qf``
+    (None where ``M`` is not wanted) and ``kf`` (heads, L, d_k) float32,
+    ``g`` (heads, L, d_k) the float32 log-decay, ``beta`` (heads, L, 1);
+    the scratch ``g_s`` and ``k_s`` (heads, L, d_k), ``p_s`` (heads, L, L)
+    where the inverse is to be made and ``e_s`` ((16, heads, L, d_k), or
+    None); ``tinv`` (heads, L, L), ``(I + A)^-1`` where it is known.
+    Leaves the running log-decay in ``g_s``, kf in ``k_s`` and column j's
+    ``exp`` of the diagonal sub-blocks in ``e_s[j]``.  Returns ``gc`` the
+    running log-decay; ``m`` (heads, L, L) float32, zero above the diagonal
+    (None without ``qf``); ``kk``, zero on and above it (the raw ``A``,
+    before beta, where ``tinv`` came; ``beta A`` where it did not);
+    ``tinv``; and ``below``, for the sub-blocks below the diagonal [(first
+    row, rows' scale, columns' scale, the scaled rows (k's, then q's), the
+    scaled columns)], float32."""
+    f32 = jnp.float32
+    heads, chunk, dk = kf.shape
+    nb = chunk // _KDA_SUB
+    gc = _kda_running(masks["tri"], g)
+    g_s[...] = gc
+    k_s[...] = kf
+    # below the diagonal: a product a row of sub-blocks
+    below = []
+    k_rows, m_rows = ([jnp.zeros((heads, _KDA_SUB, chunk), f32)]
+                      for _ in range(2))
+    for b in range(1, nb):
+        top = _KDA_SUB * b
+        ref = gc[:, top:top + 1]
+        er = jnp.exp(gc[:, top:top + _KDA_SUB] - ref)
+        ec = jnp.concatenate([jnp.exp(ref - gc[:, :top]),
+                              jnp.zeros((heads, chunk - top, dk), f32)],
+                             axis=1)
+        rows = [kf[:, top:top + _KDA_SUB] * er]
+        if qf is not None:
+            rows.append(qf[:, top:top + _KDA_SUB] * er)
+        rows = jnp.concatenate(rows, axis=1)
+        cols = kf * ec
+        part = _kda_dot(rows, cols, _HNT, cd)
+        k_rows.append(part[:, :_KDA_SUB])
+        if qf is not None:
+            m_rows.append(part[:, _KDA_SUB:])
+        below.append((top, er, ec, rows, cols))
+    # the diagonal sub-blocks column by column, all of them at once, and in
+    # the same pass the elimination that inverts I + A's diagonal blocks:
+    # row j of each is final, and is taken off the rows below it
+    solve = tinv is None
+    kb = beta * kf if solve else kf
+    if solve:
+        p_s[...] = jnp.broadcast_to(masks["eye"], p_s.shape)
+
+    def column(j, blocks):
+        e = jnp.exp(jnp.minimum(gc - _kda_by_block(g_s, j), 0.0))
+        if e_s is not None:
+            e_s[j] = e
+        t = _kda_by_block(k_s, j) * e
+        cols = [jnp.sum(x * t, axis=2, keepdims=True)
+                for x in ((kb,) if qf is None else (kb, qf))]
+        if solve:
+            p_s[...] -= jnp.where(masks["sub"] > j, cols[0], 0.0) \
+                * _kda_by_block(p_s, j)
+        hit = masks["at"] == j
+        return tuple(jnp.where(hit, col, block)
+                     for col, block in zip(cols, blocks))
+    blocks = jax.lax.fori_loop(
+        0, _KDA_SUB, column,
+        (jnp.zeros((heads, chunk, chunk), f32),) * (1 if qf is None else 2),
+        unroll=True)
+
+    def whole(diagonal, rest, kept):
+        return jnp.where(kept, jnp.where(masks["own"], diagonal, rest), 0.0)
+    # ``kk``: beta A where the solve is to come, the raw A where it is known
+    k_rest = jnp.concatenate(k_rows, axis=1)
+    kk = whole(blocks[0], beta * k_rest if solve else k_rest,
+               masks["strict"])
+    m = None if qf is None else whole(
+        blocks[1], jnp.concatenate(m_rows, axis=1), masks["incl"])
+    if solve:
+        # the sub-blocks joined: pairs, pairs of pairs
+        tinv = p_s[...]
+        size = _KDA_SUB
+        while size < chunk:
+            lower = ((masks["rows"] & size) != 0) & (
+                (masks["cols"] & -size) == (masks["rows"] & -size) - size)
+            tinv = tinv - _exact_dot(tinv, _exact_dot(
+                jnp.where(lower, kk, 0.0), tinv, _HNN), _HNN)
+            size *= 2
+    return gc, m, kk, tinv, below
+
+
+def _kda_solved(tinv, beta, kf, vf, gc):
+    """``(I + A)^-1`` on ``beta V`` and on ``beta K exp G``, one product
+    of both side by side: (wv float32, wk float32, ``exp G``)."""
+    into = jnp.exp(gc)
+    dv = vf.shape[2]
+    w = _exact_dot(tinv, jnp.concatenate(
+        [beta * vf, beta * (kf * into)], axis=2), _HNN)
+    return w[:, :, :dv], w[:, :, dv:], into
+
+
+def _kda_slot(tinv_ref, r, chunk):
+    """Where head ``r``'s (L, L) inverse lies in a grid step's block of
+    them: the heads side by side, as many as fill the block's lanes."""
+    side = tinv_ref.shape[1] // chunk
+    return (slice(r // side * chunk, (r // side + 1) * chunk),
+            slice(r % side * chunk, (r % side + 1) * chunk))
+
+
+def _kda_fwd_kernel(*refs, heads, states):
+    """One chunk of ``heads`` heads.  ``states``: the state entering each
+    chunk and the chunk's ``(I + A)^-1``, and nothing else of the forward
+    (no q, no ``M``, no o)."""
+    if states:
+        k_ref, v_ref, g_ref, col_ref, before_ref, tinv_ref = refs[:6]
+        q_ref = None
+    else:
+        q_ref, k_ref, v_ref, g_ref, col_ref, o_ref = refs[:6]
+    st_ref, g_s, k_s, p_s = refs[6:]
+    f32 = jnp.float32
+    cd = k_ref.dtype
+    chunk = k_ref.shape[0]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        st_ref[...] = jnp.zeros_like(st_ref)
+
+    qf = None if states else _kda_heads(q_ref, heads).astype(f32)
+    kf = _kda_heads(k_ref, heads).astype(f32)
+    vf = _kda_heads(v_ref, heads).astype(f32)
+    beta = _kda_heads(col_ref, heads)
+    gc, m, _, tinv, _ = _kda_chunk(qf, kf, _kda_heads(g_ref, heads), beta,
+                                   _kda_masks(chunk), cd, g_s, k_s, p_s)
+    wv, wk, into = _kda_solved(tinv, beta, kf, vf, gc)
+    st = st_ref[...]
+    stc = st.astype(cd)
+    if states:
+        before_ref[...] = st
+        for r in range(heads):
+            tinv_ref[_kda_slot(tinv_ref, r, chunk)] = tinv[r]
+        seen = _kda_dot(wk, stc, _HNT, cd)
+    else:
+        both = _kda_dot(jnp.concatenate([wk, qf * into], axis=1), stc, _HNT,
+                        cd)
+        seen = both[:, :chunk]
+    uc = (wv - seen).astype(cd)
+    if not states:
+        _kda_put(o_ref, both[:, chunk:] + _kda_dot(m, uc, _HNN, cd))
+    end = gc[:, chunk - 1:]
+    st_ref[...] = jnp.exp(end) * st + _kda_dot(
+        uc, kf * jnp.exp(end - gc), _HTN, cd)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, col_ref, do_ref, before_ref,
+                    tinv_ref, dq_ref, dk_ref, dv_ref, dg_ref, dcol_ref,
+                    ds_ref, g_s, k_s, e_s, c_s, *, heads):
+    """One chunk's gradients, the chunks taken last to first.  ``ds_ref``
+    carries the gradient of the (transposed) state that leaves the chunk."""
+    f32 = jnp.float32
+    cd = k_ref.dtype
+    chunk = k_ref.shape[0]
+    nb = chunk // _KDA_SUB
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    masks = _kda_masks(chunk)
+    qf = _kda_heads(q_ref, heads).astype(f32)
+    kf = _kda_heads(k_ref, heads).astype(f32)
+    vf = _kda_heads(v_ref, heads).astype(f32)
+    beta = _kda_heads(col_ref, heads)
+    do = _kda_heads(do_ref, heads)
+    dv = vf.shape[2]
+    # the forward again, from the inputs, the entering state and the
+    # chunk's inverse
+    gc, m, kk, tinv, below = _kda_chunk(
+        qf, kf, _kda_heads(g_ref, heads), beta, masks, cd, g_s, k_s, None,
+        e_s, jnp.stack([tinv_ref[_kda_slot(tinv_ref, r, chunk)]
+                        for r in range(heads)]))
+    wv, wk, into = _kda_solved(tinv, beta, kf, vf, gc)
+    st, ds = before_ref[...], ds_ref[...]
+    stc, dsc = st.astype(cd), ds.astype(cd)
+    qg = qf * into
+    end = gc[:, chunk - 1:]
+    left = jnp.exp(end - gc)
+    kg = kf * left
+    uc = (wv - _kda_dot(wk, stc, _HNT, cd)).astype(cd)
+    # o = qg S + m u and S' = exp(G_L) S + kg^T u, backwards
+    du = _kda_dot(m, do, _HTN, cd) + _kda_dot(kg, dsc, _HNT, cd)
+    dm = jnp.where(masks["incl"], _kda_dot(do, uc, _HNT, cd), 0.0)
+    dkg = _kda_dot(uc, dsc, _HNN, cd)
+    both = jnp.concatenate([do, du.astype(cd)], axis=1)
+    through = _kda_dot(both, stc, _HNN, cd)
+    dqg, dwk = through[:, :chunk], -through[:, chunk:]
+    at_end = jnp.sum(ds * st, axis=1, keepdims=True) * jnp.exp(end)
+    ds_ref[...] = jnp.exp(end) * ds + _kda_dot(
+        both, jnp.concatenate([qg, -wk], axis=1), _HTN, cd)
+    # the solve, transposed: W = T^-1 R, dR = T^-T dW, dA = -tril(dR W^T)
+    dr = _exact_dot(tinv, jnp.concatenate([du, dwk], axis=2), _HTN)
+    drv, drk = dr[:, :, :dv], dr[:, :, dv:]
+    da = -jnp.where(masks["strict"],
+                    _kda_dot(drv, wv, _HNT, cd)
+                    + _kda_dot(drk, wk, _HNT, cd), 0.0)
+    dbeta = jnp.sum(drv * vf, axis=2, keepdims=True) \
+        + jnp.sum(drk * (kf * into), axis=2, keepdims=True) \
+        + jnp.sum(da * kk, axis=2, keepdims=True)
+    dkk = beta * da
+    _kda_put(dv_ref, beta * drv)
+    dk_into = beta * drk
+    # what the three decayed copies give q, k and the running log-decay
+    dq = dqg * into
+    dkf = dk_into * into + dkg * left
+    dgc = dqg * qg + dk_into * (kf * into) - dkg * kg
+    to_end = jnp.sum(dkg * kg, axis=1, keepdims=True) + at_end
+    # the sub-blocks below the diagonal
+    dq_rows, dk_rows, dg_rows = ([jnp.zeros((heads, _KDA_SUB, kf.shape[2]),
+                                            f32)] for _ in range(3))
+    for top, er, ec, rows, cols in below:
+        grads = jnp.where(
+            masks["col"] < top,
+            jnp.concatenate([dkk[:, top:top + _KDA_SUB],
+                             dm[:, top:top + _KDA_SUB]], axis=1), 0.0)
+        drows = _kda_dot(grads, cols, _HNN, cd)
+        dcols = _kda_dot(grads, rows, _HTN, cd)
+        dk_rows.append(drows[:, :_KDA_SUB] * er)
+        dq_rows.append(drows[:, _KDA_SUB:] * er)
+        seen = drows * rows
+        dg_rows.append(seen[:, :_KDA_SUB] + seen[:, _KDA_SUB:])
+        dkf = dkf + dcols * ec
+        dgc = dgc - dcols * cols
+    dq = dq + jnp.concatenate(dq_rows, axis=1)
+    dkf = dkf + jnp.concatenate(dk_rows, axis=1)
+    dgc = dgc + jnp.concatenate(dg_rows, axis=1)
+
+    # the diagonal sub-blocks, column by column again
+    def column(j, sums):
+        e = e_s[j]
+        hit = masks["at"] == j
+        dm_col = jnp.sum(jnp.where(hit, dm, 0.0), axis=2, keepdims=True)
+        dk_col = jnp.sum(jnp.where(hit, dkk, 0.0), axis=2, keepdims=True)
+        t = _kda_by_block(k_s, j) * e
+        down = (dm_col * qf + dk_col * kf) * e
+        for b in range(nb):
+            c_s[:, pl.ds(_KDA_SUB * b + j, 1), :] = jnp.sum(
+                down[:, _KDA_SUB * b:_KDA_SUB * (b + 1)], axis=1,
+                keepdims=True)
+        return sums[0] + dm_col * t, sums[1] + dk_col * t
+    rq, rk = jax.lax.fori_loop(0, _KDA_SUB, column,
+                               (jnp.zeros(kf.shape, f32),) * 2, unroll=True)
+    up = c_s[...]
+    _kda_put(dq_ref, dq + rq)
+    _kda_put(dk_ref, dkf + rk + up)
+    dgc = dgc + qf * rq + kf * (rk - up)
+    # the log-decay's gradient: of the running sum, from each row on
+    _kda_put(dg_ref, _kda_running(masks["tri_t"], dgc) + to_end)
+    _kda_put(dcol_ref, dbeta)
+
+
+_KDA = _SSD
+
+
+def _kda_layout(k, v, beta, h, chunk):
+    """What the three calls share: the heads a step works, the widths, the
+    grid, beta as columns, and the column blocks of the (B, T, H d) arrays
+    for chunk ``c(i)``: keys', values', the columns'."""
+    bsz, t, _ = k.shape
+    dk, dv = k.shape[2] // h, v.shape[2] // h
+    heads = kda_blocks(t, h, dk, dv, chunk, k.dtype.itemsize)
+    if heads is None or t % chunk:
+        raise ValueError("kda_scan: no tiling for T=%d, H=%d, d_k=%d, "
+                         "d_v=%d, chunk=%d (see kda_available)"
+                         % (t, h, dk, dv, chunk))
+    blocks = h // heads
+    cols = beta.astype(jnp.float32).reshape(bsz, t, blocks, heads).transpose(
+        0, 2, 1, 3)
+    return heads, dk, dv, (bsz, blocks, t // chunk), cols, (
+        lambda c: pl.BlockSpec((None, chunk, heads * dk),
+                               lambda i, j, n: (i, c(n), j)),
+        lambda c: pl.BlockSpec((None, chunk, heads * dv),
+                               lambda i, j, n: (i, c(n), j)),
+        lambda c: pl.BlockSpec((None, None, chunk, heads),
+                               lambda i, j, n: (i, j, c(n), 0)))
+
+
+def _kda_scratch(heads, chunk, dk, dv, kept):
+    """The carried (d_v, d_k) states (or their gradients), ``G`` and k, then
+    for the forward the inverse in the making, and for the backward
+    (``kept``) the diagonal sub-blocks' ``exp`` by column and the column
+    sums."""
+    kinds = [(heads, dv, dk), (heads, chunk, dk), (heads, chunk, dk)] + (
+        [(_KDA_SUB, heads, chunk, dk), (heads, chunk, dk)] if kept
+        else [(heads, chunk, chunk)])
+    return [pltpu.VMEM(kind, jnp.float32) for kind in kinds]
+
+
+def _forward(n):
+    return n
+
+
+# Under ``jax.jit`` so that a model's layers share one trace of each body and
+# one copy of it in the lowered module: ``pallas_call`` traces its body at
+# every call site (PERF.md 6, PR 35).  XLA inlines the calls.
+@functools.partial(jax.jit, static_argnames=("h", "chunk", "interpret"))
+def kda_scan_fwd(q, k, v, g, beta, h, chunk, interpret=False):
+    """o (B, T, H d_v) in v's dtype: the gated delta rule from a zero
+    state.  q, k (B, T, H d_k) as the rule takes them (after their norms, q
+    scaled) and v (B, T, H d_v), in the compute dtype; g (B, T, H d_k)
+    float32, the log of the decay, at or below 0; beta (B, T, H) float32.
+    T is a whole number of chunks."""
+    heads, dk, dv, grid, cols, (keys, vals, col) = _kda_layout(
+        k, v, beta, h, chunk)
+    return pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, heads=heads, states=False),
+        grid=grid,
+        in_specs=[keys(_forward), keys(_forward), vals(_forward),
+                  keys(_forward), col(_forward)],
+        out_specs=vals(_forward),
+        out_shape=jax.ShapeDtypeStruct(v.shape, v.dtype),
+        scratch_shapes=_kda_scratch(heads, chunk, dk, dv, False),
+        compiler_params=_KDA,
+        interpret=interpret,
+        name="mxtpu_kda_fwd",
+    )(q, k, v, g, cols)
+
+
+@functools.partial(jax.jit, static_argnames=("h", "chunk", "interpret"))
+def kda_scan_bwd(q, k, v, g, beta, do, h, chunk, interpret=False):
+    """Gradients of ``kda_scan_fwd`` for o's cotangent ``do``: of q, k and
+    v (in their dtypes), of g (B, T, H d_k) and beta (B, T, H), float32.
+    Two calls: the state entering each chunk, (B, T / chunk, H, d_v, d_k)
+    float32, and each chunk's ``(I + A)^-1``, (L, L) a head, formed again
+    from the inputs; then the chunks last to first."""
+    f32 = jnp.float32
+    heads, dk, dv, grid, cols, (keys, vals, col) = _kda_layout(
+        k, v, beta, h, chunk)
+    bsz, t, _ = k.shape
+    nc = grid[2]
+    back = lambda n: nc - 1 - n                             # noqa: E731
+    state = lambda c: pl.BlockSpec(                         # noqa: E731
+        (None, None, heads, dv, dk), lambda i, j, n: (i, c(n), j, 0, 0))
+    # the chunks' inverses, as many heads side by side as fill 128 lanes
+    side = max(1, 128 // chunk)
+    side = side if heads % side == 0 else 1
+    solved = lambda c: pl.BlockSpec(                        # noqa: E731
+        (None, None, heads // side * chunk, side * chunk),
+        lambda i, j, n: (i, c(n), j, 0))
+    before, tinv = pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, heads=heads, states=True),
+        grid=grid,
+        in_specs=[keys(_forward), vals(_forward), keys(_forward),
+                  col(_forward)],
+        out_specs=[state(_forward), solved(_forward)],
+        out_shape=[jax.ShapeDtypeStruct((bsz, nc, h, dv, dk), f32),
+                   jax.ShapeDtypeStruct(
+                       (bsz, nc, h // side * chunk, side * chunk), f32)],
+        scratch_shapes=_kda_scratch(heads, chunk, dk, dv, False),
+        compiler_params=_KDA,
+        interpret=interpret,
+        name="mxtpu_kda_states",
+    )(k, v, g, cols)
+    dq, dkey, dval, dg, dcols = pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, heads=heads),
+        grid=grid,
+        in_specs=[keys(back), keys(back), vals(back), keys(back), col(back),
+                  vals(back), state(back), solved(back)],
+        out_specs=[keys(back), keys(back), vals(back), keys(back),
+                   col(back)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g.shape, f32),
+                   jax.ShapeDtypeStruct(cols.shape, f32)],
+        scratch_shapes=_kda_scratch(heads, chunk, dk, dv, True),
+        compiler_params=_KDA,
+        interpret=interpret,
+        name="mxtpu_kda_bwd",
+    )(q, k, v, g, cols, do, before, tinv)
+    return dq, dkey, dval, dg, dcols.transpose(0, 2, 1, 3).reshape(bsz, t, h)

@@ -1,5 +1,5 @@
-"""The flash kernels, the routed experts' grouped products and the state-space
-scan's kernels compiled for a
+"""The flash kernels, the routed experts' grouped products, the state-space
+scan's kernels and the gated delta rule's compiled for a
 described (not attached) TPU v5e, at the benchmark cells' shapes and the
 shape guards' corners: what interpret mode
 cannot show — a slice Mosaic cannot tile, a transpose it cannot lower, more
@@ -18,11 +18,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu.ops import ssm
+from mxnet_tpu.ops import kda, ssm
 from mxnet_tpu.ops.pallas_kernels import (flash_attention, flash_available,
                                           flash_blocks, grouped_available,
                                           grouped_matmul, grouped_matmul_t,
-                                          ssd_blocks)
+                                          kda_blocks, ssd_blocks)
 
 
 @pytest.fixture(scope="module")
@@ -241,3 +241,80 @@ def test_the_scan_compiles_at_the_cells_shape(one_chip):
 @pytest.mark.parametrize("shape", SSD_CORNERS)
 def test_the_scans_corners_compile(one_chip, shape, dtype):
     _compile_scan(one_chip, shape, dtype)
+
+
+# kimi-linear-steps-t4096's linear-attention mixer, (B, T, H, d_k, d_v,
+# chunk), then the corners of ``kda_blocks``: 2 heads in chunks of 128 (eight
+# sub-blocks to join); 32 heads with keys of 256 in chunks of 16 (nothing to
+# join); 32 heads with values of 256, two sequences, chunks of 32
+KDA_CELL = (1, 4096, 32, 128, 128, 64)
+KDA_CORNERS = [(1, 256, 2, 128, 128, 128), (1, 64, 32, 256, 128, 16),
+               (2, 128, 32, 128, 256, 32)]
+
+
+def _compile_rule(one_chip, shape, dtype):
+    bsz, t, h, dk, dv, chunk = shape
+    assert kda_blocks(t, h, dk, dv, chunk, jnp.dtype(dtype).itemsize)
+    shaped = lambda dims, kind: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, kind, sharding=one_chip)
+    keys, vals = shaped((bsz, t, h * dk), dtype), shaped((bsz, t, h * dv),
+                                                         dtype)
+
+    def loss(*args):
+        o = kda._scan_kernels(*args, h, chunk)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        keys, keys, vals, keys, shaped((bsz, t, h), dtype),
+        shaped((h,), jnp.float32), shaped((h * dk,), jnp.float32)
+    ).compile().as_text()
+    # forward, the states formed again, backward: nothing run twice
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("mxtpu_kda_fwd", "mxtpu_kda_states", "mxtpu_kda_bwd"):
+        assert name in text
+    # the solve is products inside the kernels, and none of the (L, L)
+    # blocks, which the plain form keeps as float32 arrays of all chunks
+    # and heads, (..., H, T / L, L, L) and the like, is an array of the
+    # program: the one (L, L) array a chunk is the states pass's inverse,
+    # (B, T / L, H L, L) (the states themselves, (B, T / L, H, d_v, d_k),
+    # look like one where d_v = d_k = L)
+    assert "triangular" not in text.lower()
+    blocks = set(re.findall(r"f32\[[0-9,]*\b%d,%d\]" % (chunk, chunk), text))
+    assert blocks <= {
+        "f32[%d,%d,%d,%d]" % (bsz, t // chunk, h * chunk, chunk),
+        "f32[%d,%d,%d,%d,%d]" % (bsz, t // chunk, h, dv, dk)}, blocks
+    return text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_the_delta_rule_compiles_at_the_cells_shape(one_chip, dtype):
+    """With the chooser's eight heads a step; the large temporaries are
+    the states' (T / L, H, d_v, d_k) float32 array and the chunks' inverses,
+    and no (T, d_k, d_v) state or (T / L, L, L, d) block exists."""
+    assert kda_blocks(*KDA_CELL[1:], jnp.dtype(dtype).itemsize) == 8
+    text = _compile_rule(one_chip, KDA_CELL, dtype)
+    assert "f32[1,64,32,128,128]" in text and "f32[1,64,1024,128]" in text
+    assert not re.search(r"f32\[[0-9,]*\b64,[0-9,]*\b16,16,128\]", text)
+    assert "f32[1,4096,32,128,128]" not in text
+
+
+def test_the_delta_rules_forward_alone_keeps_nothing_a_chunk(one_chip):
+    """The forward is one kernel, and no array of the program has a chunk
+    axis: no state a chunk, no (L, L) block a chunk."""
+    bsz, t, h, dk, dv, chunk = KDA_CELL
+    shaped = lambda dims, kind: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, kind, sharding=one_chip)
+    keys = shaped((bsz, t, h * dk), jnp.bfloat16)
+    text = jax.jit(lambda *a: kda._scan_kernels(*a, h, chunk)).lower(
+        keys, keys, keys, keys, shaped((bsz, t, h), jnp.bfloat16),
+        shaped((h,), jnp.float32), shaped((h * dk,), jnp.float32)
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mxtpu_kda_fwd" in text and "triangular" not in text.lower()
+    assert not re.search(r"\[%d,%d,[0-9,]+\]" % (bsz, t // chunk), text)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("shape", KDA_CORNERS)
+def test_the_delta_rules_corners_compile(one_chip, shape, dtype):
+    _compile_rule(one_chip, shape, dtype)

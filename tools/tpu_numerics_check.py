@@ -4,10 +4,10 @@ bf16-appropriate tolerances — flash attention forward and both backward
 kernels, including the corners of its shape guard (those with f32 operands
 too) and a latent-attention layer's value heads of 128 beside query/key heads
 of 192, the grouped products of the routed experts at the benchmark cell's
-own shape, the state-space scan's kernels, and the chunked gated delta rule
-(plain ``jax.numpy``, but at the chip's bfloat16 products) against its
-token-by-token recurrence.  The interpret-mode twins of these checks run on the CPU harness
-(test_pallas.py, test_moe.py).
+own shape, the state-space scan's kernels, and the gated delta rule's
+kernels against its token-by-token recurrence.  The interpret-mode twins of
+these checks run on the CPU harness (test_pallas.py, test_moe.py,
+test_ssm.py, test_kda.py).
 
 Run on a machine with a chip:  python tools/tpu_numerics_check.py
 Prints one PASS line per check; exits non-zero on any mismatch, on a shape
@@ -208,8 +208,9 @@ def check_ssd_scan():
 
 
 def check_kda_scan():
-    """``kda_scan`` (the chunked form: sub-blocks, a triangular solve, a
-    state a chunk) against the token-by-token recurrence of
+    """``kda_scan`` through its kernels (the chunked form: sub-blocks, the
+    unit-triangular solve, a state a chunk, all in VMEM; the backward
+    written by hand) against the token-by-token recurrence of
     ``benchmark/reference/kda_lm.py`` in float32 (sums of products, no dot),
     forward and the gradients of its seven inputs, at the published rates
     (``test_kda.py``: the decay's step in 0.001-0.1, A in 1-16), where the
@@ -217,11 +218,12 @@ def check_kda_scan():
     seed does not reach.  Both sides take the same bfloat16 inputs."""
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops import kda
+    from mxnet_tpu.ops import kda, pallas_kernels as pk
     from benchmark.reference import kda_lm as ref
 
     bsz, t, h, dk, dv = KDA_SHAPE
     chunk = 64
+    assert pk.kda_available(t, h, dk, dv, chunk, 2), KDA_SHAPE
     rng = np.random.RandomState(4)
     bf16 = lambda *shape: jnp.asarray(  # noqa: E731
         rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
@@ -232,7 +234,7 @@ def check_kda_scan():
             f32(np.log(rng.uniform(1.0, 16.0, (h,)))),
             f32(np.log(np.expm1(step))))
     weight = bf16(bsz, t, h * dv).astype(jnp.float32)
-    chunked = lambda *a: kda._scan(*a, h=h, chunk=chunk)  # noqa: E731
+    chunked = lambda *a: kda._scan_kernels(*a, h, chunk)  # noqa: E731
 
     def recurrence(q, k, v, gate, beta, a_log, dt_bias):
         heads = lambda x: x.astype(jnp.float32).reshape(  # noqa: E731
