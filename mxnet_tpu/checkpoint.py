@@ -16,8 +16,11 @@ This module replaces it:
   - ``stage<k>.params``       parameters + aux of pipeline stage ``k``
                               (single-program = everything in stage 0);
   - ``stage<k>-opt.params``   stage ``k``'s optimizer state (replicated mode);
-  - ``stage<k>-zero<j>.params``  row ``j`` of stage ``k``'s ZeRO flat
-                              ``(dp, chunk)`` shards: optimizer state
+  - ``stage<k>-zero<j>.params``  part ``j`` of stage ``k``'s ZeRO
+                              shards, each as one flat row (row ``j`` of
+                              a flat ``(dp, chunk)`` view; of a level-1
+                              leaf kept in its shape, part ``j`` of its
+                              leading axis): optimizer state
                               (``opt:`` entries, level >= 1) and, at
                               ZeRO level 3, the parameters themselves
                               (``argz:`` entries — logical shapes ride
@@ -133,10 +136,11 @@ def snapshot(ts, params, opt_state, aux, *, step=None, epoch=0, nbatch=0,
         (params, opt_state if opt_state is not None else {}, aux))
     stage_of = topo["stage_of"]
     # topo["zero"] is the ZeRO LEVEL (int; historical bools read as 0/1):
-    # level >= 1 shards optimizer state into (dp, chunk) rows, level 3
+    # level >= 1 shards optimizer state into dp parts, level 3
     # additionally stores the parameters themselves as flat rows
     # ("argz:" entries) — their logical shapes ride topo["param_shapes"]
     zlevel = int(topo["zero"])
+    dp = int(topo["dp"])
     pshapes = topo.get("param_shapes") or {}
     groups = {}
 
@@ -161,10 +165,13 @@ def snapshot(ts, params, opt_state, aux, *, step=None, epoch=0, nbatch=0,
             for i, leaf in enumerate(st):
                 leaf = _np.asarray(leaf)
                 if zlevel:
-                    # (dp, chunk) flat shards: row j belongs to dp index j
-                    for j in range(leaf.shape[0]):
+                    # part j of the leaf belongs to dp index j: row j of
+                    # a flat (dp, chunk) view, or the j-th part of the
+                    # leading axis of a leaf kept in its shape (level 1,
+                    # dp divides it) — as one flat row the same bytes
+                    for j, row in enumerate(leaf.reshape(dp, -1)):
                         grp("stage%d-zero%d" % (s, j))[
-                            "opt:%s:%d" % (n, i)] = leaf[j]
+                            "opt:%s:%d" % (n, i)] = row
                 else:
                     grp("stage%d-opt" % s)["opt:%s:%d" % (n, i)] = leaf
     manifest = {

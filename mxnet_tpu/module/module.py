@@ -1072,7 +1072,8 @@ class _FusedFit(object):
         # optimizer-state copies only when someone will hold them (the
         # donation-alias hazard applies to these too)
         if getattr(self._ts, "zero", 0):
-            # ZeRO state lives as flat (dp, chunk) mesh shards — export
+            # ZeRO state lives as mesh shards (flat (dp, chunk) views
+            # but for level 1's leaves kept in their shape) — export
             # the LOGICAL host view so save_optimizer_states (and a
             # later non-ZeRO fit) keeps the reference layout
             st_host = jax.device_get(self._state)

@@ -11,8 +11,12 @@ the sharding level are orthogonal knobs:
 level  parameters              gradients                      optimizer state
 =====  ======================  =============================  ==================
 0      replicated              full tree, all-reduced         replicated
-1      replicated              full tree; flat ``(dp,chunk)``  flat ``(dp,chunk)``
-       .                       views inside the update         dp-sharded
+1      replicated              full tree; constrained to the   the leaf's OWN
+       .                       state's sharding inside the     shape, dp-sharded
+       .                       update (a reduce-scatter        along axis 0; flat
+       .                       along axis 0)                   ``(dp,chunk)`` where
+       .                                                       dp does not divide
+       .                                                       that axis
 2      replicated              ONE flat ``(dp,chunk)`` bucket  flat ``(dp,chunk)``
        .                       (reduce-scatter residency; the  dp-sharded
        .                       full tree never persists), one
@@ -27,11 +31,19 @@ Per-device model footprint at level 3 scales ~``1/(pp * dp)`` when
 composed with pipeline stages — the memory lever that opens models past
 one chip's HBM (docs/distributed.md "ZeRO levels").
 
-The flat ``(dp, chunk)`` layout (zero-padded, device ``i`` owns row
-``i``) is THE wire contract shared by the in-step math, host placement,
-and the sharded checkpoint writer — it exists exactly once, here.
-Elementwise optimizer math commutes with the view, so every level trains
-to exact parity with the replicated step (f64 @1e-9, test-pinned).
+Level 1 cuts a leaf in ``dp`` parts along the leaf's own leading axis
+wherever ``dp`` divides it (:meth:`PlacementPlan.keeps_shape`): the shard
+has the parameter's own layout, so the step pads nothing and reshapes
+nothing (a flat view of a conv filter is a relayout on the TPU, not a
+bitcast).  Every other leaf — a scalar, a leading axis ``dp`` does not
+divide — and everything at levels 2 and 3 (one concatenated bucket needs
+a common form) takes the flat ``(dp, chunk)`` layout: zero-padded, device
+``i`` owns row ``i``.  Where ``dp`` divides the leading axis the two
+forms hold the same elements on the same device, so the sharded
+checkpoint writer's rows are the same bytes either way.  The rule and
+the layouts exist exactly once, here.  Elementwise optimizer math
+commutes with both forms, so every level trains to exact parity with the
+replicated step (f64 @1e-9, test-pinned).
 """
 from __future__ import annotations
 
@@ -117,7 +129,8 @@ def _pspec(*names):
 
 class PlacementPlan(object):
     """One step's parameter-placement plan: ZeRO level + dp width + the
-    flat-shard layout helpers and the sharded update math.
+    rule of each leaf's sharded form, the layout helpers and the sharded
+    update math.
 
     The traced helpers take the target Mesh per call — the whole mesh
     for ``TrainStep``, the owning stage's sub-mesh for
@@ -135,7 +148,8 @@ class PlacementPlan(object):
     # ------------------------------------------------------------- properties
     @property
     def shard_state(self):
-        """Optimizer state lives as flat (dp, chunk) shards (level >= 1)."""
+        """Optimizer state lives dp-sharded (level >= 1): in the form
+        :meth:`keeps_shape` decides at level 1, flat (dp, chunk) above."""
         return self.zero >= 1
 
     @property
@@ -158,6 +172,61 @@ class PlacementPlan(object):
     def from_flat(self, xf, shape):
         return from_flat(xf, shape)
 
+    # ------------------------------------------- the optimizer state's form
+    def keeps_shape(self, shape):
+        """THE rule of how a leaf's optimizer state is cut in ``dp``
+        parts: at level 1 a leaf whose leading axis ``dp`` divides keeps
+        its own shape, sharded along that axis; every other leaf (a
+        scalar, an indivisible axis) and every leaf at levels 2-3 (their
+        bucket concatenates rows) takes the flat (dp, chunk) view."""
+        shape = tuple(shape)
+        return self.zero == 1 and len(shape) >= 1 \
+            and shape[0] % self.dp == 0
+
+    def to_shards(self, x, shape, mesh):
+        """A leaf of ``shape`` -> the state's form of it, dp-sharded
+        (traced).  ``x`` is the logical tensor or the leaf's flat
+        (dp, chunk) rows (a slice of a reduced gradient bucket).  On a
+        reduced gradient the constraint lowers as a reduce-scatter (along
+        axis 0 of a leaf kept in its shape), on a replicated parameter as
+        each device's slice of it; where a leaf keeps its shape, row
+        ``i`` of its rows holds exactly part ``i`` of its leading axis,
+        so that reshape moves nothing between devices."""
+        import jax
+        from jax.sharding import NamedSharding
+        if not self.keeps_shape(shape):
+            x = self.flat_shards(x)       # flat rows pass unchanged
+        elif tuple(x.shape) != tuple(shape):
+            x = self.from_flat(x, shape)
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, _pspec("dp")))
+
+    def from_shards(self, xs, shape, mesh):
+        """The state's form of a leaf -> the logical tensor, replicated
+        (traced; the all-gather of the updated parameter)."""
+        import jax
+        from jax.sharding import NamedSharding
+        if not self.keeps_shape(shape):
+            xs = self.from_flat(xs, shape)
+        return jax.lax.with_sharding_constraint(
+            xs, NamedSharding(mesh, _pspec()))
+
+    def shards_np(self, v):
+        """Host logical tensor -> the host template of its state's form
+        (what ``device_put`` with a ``"dp"`` sharding then cuts by rows):
+        the tensor itself where the leaf keeps its shape, else
+        :func:`flat_np`."""
+        v = _np.asarray(v)
+        return v if self.keeps_shape(v.shape) else flat_np(v, self.dp)
+
+    def state_host(self, fopt, params):
+        """Sharded optimizer state born as host templates in the state's
+        form of each leaf — built from the (padded, where flat) parameter
+        VALUES, so dcasgd's prev-weight state starts AT the weight
+        exactly as in replicated mode (any level >= 1)."""
+        return fopt.init_state({n: self.shards_np(v)
+                                for n, v in params.items()})
+
     # --------------------------------------------------------- shape registry
     def note_host(self, host_arrays):
         """Capture logical shapes from host tensors (placement time) —
@@ -176,10 +245,14 @@ class PlacementPlan(object):
         return self._shapes[name]
 
     def unflatten_host(self, name, arr):
-        """Host flat (dp, chunk) array -> logical tensor (checkpoint /
-        sync-back export)."""
+        """Host array of a leaf's state (or level-3 parameter) -> logical
+        tensor (checkpoint / sync-back export): the identity for a leaf
+        kept in its shape, the unpadded reshape of a flat (dp, chunk)
+        view."""
         shape = self.shape_of(name)
         arr = _np.asarray(arr)
+        if self.keeps_shape(shape):
+            return arr
         return arr.reshape(-1)[:_size_of(shape)].reshape(shape)
 
     # -------------------------------------------------------------- placement
@@ -204,6 +277,28 @@ class PlacementPlan(object):
         return {n: jax.lax.with_sharding_constraint(
             self.from_flat(v, self.shape_of(n)), rep)
             for n, v in params.items()}
+
+    def update_shards(self, fopt, names, params, grads, opt_state, hyper,
+                      t, rng, mesh):
+        """The level-1 sharded optimizer step: every rule in
+        ``_FunctionalOptimizer`` is elementwise in (w, g, state), so it
+        applies unchanged to each device's shard of a leaf, in the form
+        :meth:`keeps_shape` decides.  The sharding constraints make XLA
+        reduce-scatter the gradient in and all-gather the updated
+        parameter out.  ``grads[n]`` is the leaf's gradient, or its flat
+        (dp, chunk) rows of a reduced bucket (the pipeline's overlapped
+        dp comm).  (SGLD's shape-dependent noise draws a different —
+        equally valid — realisation than replicated mode; the
+        deterministic rules match it exactly.)"""
+        new_params, new_state = {}, {}
+        for n in names:
+            w = params[n]
+            gs = self.to_shards(grads[n].astype(w.dtype), w.shape, mesh)
+            nws, new_state[n] = fopt.update(
+                n, self.to_shards(w, w.shape, mesh), gs, opt_state[n],
+                hyper, t, rng=rng)
+            new_params[n] = self.from_shards(nws, w.shape, mesh)
+        return new_params, new_state
 
     def bucket_layout(self, params, names=None):
         """Static (name, chunk_rows) layout of the flat gradient bucket
@@ -285,7 +380,9 @@ class PlacementPlan(object):
         metadata only (no syncs) — the ``zero_param_bytes`` /
         ``zero_grad_bytes`` gauge source and the dryrun ladder's memory
         stamp.  Gradient residency: the bucket's one row per device at
-        level >= 2, the full tree below."""
+        level >= 2, the full tree below.  Optimizer state: a ``dp``-th of
+        each leaf as it lies — no pad for a leaf kept in its shape, the
+        padded row of a flat view."""
         from .. import telemetry as _tel
         nb = _tel.nbytes_of
         param = grad = opt = 0

@@ -56,9 +56,9 @@ def _pspec(*names):
     return PartitionSpec(*names)
 
 
-# flat (dp, chunk) layout: one implementation, in the placement plan
-# module (parallel/placement.py) — these aliases keep the historical
-# train-module names every existing caller uses
+# flat (dp, chunk) layout of the gradient bucket and the level-3
+# parameters: one implementation, in the placement plan module
+# (parallel/placement.py)
 _chunk_rows = _placement.chunk_rows
 _flat_shards = _placement.flat_shards
 _from_flat_shards = _placement.from_flat
@@ -99,13 +99,6 @@ def _host_init(symbol, low, param_names, aux_names, data_shapes,
 
 
 _flat_np = _placement.flat_np
-
-
-def _zero_state_host(fopt, params, dp):
-    """ZeRO optimizer state born as flat (dp, chunk) host templates —
-    padded param values, so dcasgd's prev-weight state starts AT the
-    weight exactly as in replicated mode (any level >= 1)."""
-    return fopt.init_state({n: _flat_np(v, dp) for n, v in params.items()})
 
 
 def _scale_state_to_host(step):
@@ -482,32 +475,14 @@ class TrainStep(object):
                     n, params[n], g, opt_state[n], hyper, t, rng=rng)
             return new_params, new_state
 
-        def update_zero(params, grads, opt_state, hyper, t, rng):
-            """ZeRO-1 update: every optimizer rule in _FunctionalOptimizer
-            is elementwise in (w, g, state), so it applies unchanged to the
-            flat (dp, chunk) shard views; sharding constraints make XLA
-            reduce-scatter the gradient in and all-gather the updated
-            weights out.  (SGLD's shape-dependent noise draws a different
-            — equally valid — realisation than replicated mode; the
-            deterministic rules match it exactly.)"""
-            from jax.sharding import NamedSharding
-            sh_dp = NamedSharding(mesh, _pspec("dp"))
-            rep = NamedSharding(mesh, _pspec())
-            new_params, new_state = {}, {}
-            for n in self.param_names:
-                w = params[n]
-                g = grads[n].astype(w.dtype)
-                gf = jax.lax.with_sharding_constraint(
-                    self._to_shards(g), sh_dp)
-                wf = jax.lax.with_sharding_constraint(
-                    self._to_shards(w), sh_dp)
-                nwf, new_state[n] = self.fopt.update(
-                    n, wf, gf, opt_state[n], hyper, t, rng=rng)
-                nw = self._from_shards(nwf, w.shape)
-                new_params[n] = jax.lax.with_sharding_constraint(nw, rep)
-            return new_params, new_state
-
         plan = self.plan
+
+        def update_zero(params, grads, opt_state, hyper, t, rng):
+            """ZeRO-1 update: the plan's sharded step over each leaf's
+            shard, in the leaf's own shape where dp divides its leading
+            axis (PlacementPlan.update_shards)."""
+            return plan.update_shards(self.fopt, self.param_names, params,
+                                      grads, opt_state, hyper, t, rng, mesh)
 
         def bucket_update(params, grads, opt_state, hyper, t, rng):
             """ZeRO-2/3 update: ``grads`` is the (layout, bucket) pair —
@@ -747,18 +722,10 @@ class TrainStep(object):
             self._step = jax.jit(step, donate_argnums=(0, 1, 2))
 
     # ---------------------------------------------------------- ZeRO views
-    def _chunk(self, size):
-        return _chunk_rows(size, self._dp)
-
-    def _to_shards(self, x):
-        return _flat_shards(x, self._dp)
-
-    def _from_shards(self, xf, shape):
-        return _from_flat_shards(xf, shape)
-
     def unflatten_host(self, name, arr):
-        """Host flat (dp, chunk) array -> the logical tensor (the
-        sync-back/export half of the ZeRO-3 layout)."""
+        """Host array of a sharded leaf (optimizer state; a level-3
+        parameter) -> the logical tensor (the sync-back/export half of
+        the plan's layouts)."""
         return self.plan.unflatten_host(name, arr)
 
     def gather_params(self, params):
@@ -819,7 +786,7 @@ class TrainStep(object):
         """Shard-ownership description for the sharded checkpoint writer
         (mxnet_tpu/checkpoint.py): which stage owns each parameter/aux
         tensor (all stage 0 here — one program), and how the optimizer
-        state is laid out (ZeRO flat ``(dp, chunk)`` shards or
+        state is laid out (ZeRO shards, ``dp`` parts of each leaf, or
         replicated; ``zero`` carries the LEVEL — at level 3 the
         parameters themselves are flat rows and ``param_shapes`` records
         their logical shapes for the writer/reader).  The writer turns
@@ -841,16 +808,17 @@ class TrainStep(object):
         """Place restored HOST pytrees onto this step's topology (the
         restore half of any-topology resume: ``host_state`` leaves arrive
         in the LOGICAL parameter shape and are re-sharded here —
-        ``zero=True`` re-chunks them to this mesh's ``(dp, chunk)`` flat
-        view, whatever topology saved them).  ``device`` pins the no-mesh
-        placement (the fused fit's module device); default is the ambient
-        context or the first LOCAL device — never a peer rank's."""
+        ``zero=True`` cuts them over this mesh's ``dp`` in the plan's
+        form of each leaf, whatever topology saved them).  ``device``
+        pins the no-mesh placement (the fused fit's module device);
+        default is the ambient context or the first LOCAL device — never
+        a peer rank's."""
         import jax
         params = {n: _np.asarray(host_params[n]) for n in self.param_names}
         aux = {n: _np.asarray(host_aux[n]) for n in self.aux_names}
         self.plan.note_host(params)
         if self.zero:
-            state = {n: tuple(_flat_np(s, self._dp)
+            state = {n: tuple(self.plan.shards_np(s)
                               for s in host_state[n])
                      for n in self.param_names}
         else:
@@ -1007,7 +975,7 @@ class TrainStep(object):
         self.plan.note_host(params)
         if self.zero:
             # optimizer state is born sharded over dp
-            opt_state = _zero_state_host(self.fopt, params, self._dp)
+            opt_state = self.plan.state_host(self.fopt, params)
         else:
             opt_state = self.fopt.init_state(params)
         if self.mesh is None:
@@ -1870,8 +1838,8 @@ class PipelineTrainStep(object):
                 for n, v in host_aux.items()}
 
     def unflatten_host(self, name, arr):
-        """Host flat (dp, chunk) array -> the logical tensor (sync-back/
-        export half of the ZeRO-3 layout)."""
+        """Host array of a sharded leaf -> the logical tensor (sync-back/
+        export half of the plan's layouts)."""
         return self.plan.unflatten_host(name, arr)
 
     def zero_bytes(self, params, opt_state=None):
@@ -1921,7 +1889,7 @@ class PipelineTrainStep(object):
         dev_params = self.place_params(params)
         dev_aux = self.place_aux(aux)
         if self.zero:
-            host_state = _zero_state_host(self.fopt, params, self._dp)
+            host_state = self.plan.state_host(self.fopt, params)
             dev_state = {}
             for n, st in host_state.items():
                 sh = NamedSharding(self._sub(self._var_stage[n]),
@@ -1951,7 +1919,7 @@ class PipelineTrainStep(object):
         parameter/aux tensor belongs to its pipeline stage (the stage
         partition map rides in the manifest so restore can re-shard onto
         a different stage count), optimizer state is per-stage —
-        dp-flat-sharded under ``zero=True``.  Requires the stage plan
+        dp-sharded under ``zero=True``.  Requires the stage plan
         (call init()/place_params() first)."""
         if self._stages is None:
             raise MXNetError(
@@ -1976,7 +1944,8 @@ class PipelineTrainStep(object):
                          device=None):
         """Place restored HOST pytrees onto this pipeline's stages
         (``host_state`` leaves arrive in the LOGICAL parameter shape;
-        ``zero=True`` re-chunks them over each stage sub-mesh's dp).
+        ``zero=True`` cuts them over each stage sub-mesh's dp in the
+        plan's form of each leaf).
         ``device`` is accepted for TrainStep API parity and ignored —
         placement here is per stage sub-mesh."""
         import jax
@@ -1990,7 +1959,7 @@ class PipelineTrainStep(object):
             for n, st in host_state.items():
                 sh = NamedSharding(self._sub(self._var_stage[n]),
                                    _pspec("dp"))
-                state[n] = tuple(jax.device_put(_flat_np(s, self._dp), sh)
+                state[n] = tuple(jax.device_put(self.plan.shards_np(s), sh)
                                  for s in st)
         else:
             state = self.place_state(host_state)
@@ -2258,33 +2227,23 @@ class PipelineTrainStep(object):
                     return plan.shard_update(
                         self.fopt, params, grads, bucket_chunks(params),
                         opt_state, hyper, t, rng, sub)
-                gfs = None
-                if bucket:
-                    gfs, off = {}, 0
-                    for n, c in bucket_chunks(params):
-                        gfs[n] = jax.lax.with_sharding_constraint(
-                            grads[:, off:off + c], sh_dp)
-                        off += c
+                if zero:
+                    # level 1: the plan's sharded step over each leaf's
+                    # shard — from the bucket's rows where the backward
+                    # wave already reduce-scattered them
+                    if bucket:
+                        acc, grads, off = grads, {}, 0
+                        for n, c in bucket_chunks(params):
+                            grads[n] = acc[:, off:off + c]
+                            off += c
+                    return plan.update_shards(
+                        self.fopt, names, params, grads, opt_state, hyper,
+                        t, rng, sub)
                 new_p, new_s = {}, {}
                 for n in names:
-                    if zero:
-                        if gfs is not None:
-                            gf = gfs[n].astype(params[n].dtype)
-                        else:
-                            g = grads[n].astype(params[n].dtype)
-                            gf = jax.lax.with_sharding_constraint(
-                                _flat_shards(g, dp), sh_dp)
-                        wf = jax.lax.with_sharding_constraint(
-                            _flat_shards(params[n], dp), sh_dp)
-                        nwf, new_s[n] = self.fopt.update(
-                            n, wf, gf, opt_state[n], hyper, t, rng=rng)
-                        nw = _from_flat_shards(nwf, params[n].shape)
-                        new_p[n] = jax.lax.with_sharding_constraint(nw, rep)
-                    else:
-                        g = grads[n].astype(params[n].dtype)
-                        new_p[n], new_s[n] = self.fopt.update(
-                            n, params[n], g, opt_state[n], hyper, t,
-                            rng=rng)
+                    g = grads[n].astype(params[n].dtype)
+                    new_p[n], new_s[n] = self.fopt.update(
+                        n, params[n], g, opt_state[n], hyper, t, rng=rng)
                 return new_p, new_s
 
             if self._has_scale:
@@ -2812,7 +2771,7 @@ class PipelineTrainStep(object):
         static_nb = [0] * P
         for k in range(V):
             st = self._stages[k]
-            # dp-flat-sharded leaves (ZeRO params at level 3, state at
+            # dp-sharded leaves (ZeRO params at level 3, state at
             # level >= 1) cost each device 1/dp of the array
             pdiv = self._dp if self.zero >= 3 else 1
             sdiv = self._dp if self.zero else 1

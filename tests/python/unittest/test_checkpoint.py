@@ -427,6 +427,63 @@ def test_restore_zero_dp8_to_dp4_and_replicated(tmp_path):
     _close(p3, ref, what="zero->replicated")
 
 
+@pytest.mark.parametrize("dp_new", [2, 3])
+def test_restore_zero1_dp4_to_another_dp(tmp_path, dp_new):
+    """Saved on ``dp`` = 4, where every leaf's state lies in the leaf's
+    own shape (4 divides the leading axes 16, 16 and 8), resumed on
+    ``dp`` = 2 (in shape again, cut in halves) and on ``dp`` = 3 (which
+    divides none of them: the flat ``(dp, chunk)`` view): the checkpoint
+    holds logical tensors, the plan of the resuming step cuts them anew,
+    and training continues to the uninterrupted run's parameters."""
+    rows = 12                               # divisible by 4, 2 and 3
+    rs = np.random.RandomState(0)
+    batch = {"data": rs.uniform(-1, 1, (rows, 32)).astype(np.float32),
+             "softmax_label": rs.randint(0, 8, (rows,)).astype(np.float32)}
+    shapes = ({"data": (rows, 32)}, {"softmax_label": (rows,)})
+
+    def step_on(dp):
+        opt = mx.optimizer.SGD(learning_rate=0.1, momentum=0.9,
+                               rescale_grad=1.0 / rows)
+        return TrainStep(_mlp(), opt, zero=1, mesh=make_mesh(
+            {"dp": dp}, devices=jax.devices()[:dp]))
+
+    ts = step_on(4)
+    p, s, a = ts.init(*shapes, seed=3)
+    rng = jax.random.PRNGKey(7)
+    b = ts.shard_batch(batch)
+    for _ in range(2):
+        p, s, a, _ = ts(p, s, a, b, rng=rng)
+    for n, st in s.items():
+        assert all(leaf.shape == p[n].shape for leaf in st), n
+    path = ckpt.Checkpointer(str(tmp_path / "m"), async_=False).save(
+        ts, p, s, a)
+    man = ckpt.load_manifest(path)
+    assert man["topology"]["zero"] == 1 and man["topology"]["dp"] == 4
+    # one shard file a dp index, each leaf's part in it as one flat row
+    assert len([f for f in man["shards"] if "-zero" in f]) == 4
+    _man, lp, ls, _la = ckpt.load_sharded(path)
+    for n, st in s.items():
+        for got, live in zip(ls[n], st):
+            assert got.shape == lp[n].shape
+            np.testing.assert_array_equal(got, np.asarray(live), err_msg=n)
+    for _ in range(2):
+        p, s, a, _ = ts(p, s, a, b, rng=rng)
+    ref = {n: np.asarray(v) for n, v in p.items()}
+
+    ts2 = step_on(dp_new)
+    p2, s2, a2, _ = ckpt.restore_into(ts2, path)
+    for n, st in s2.items():
+        kept = p2[n].shape[0] % dp_new == 0
+        assert kept is (dp_new == 2), n
+        want = tuple(p2[n].shape) if kept \
+            else (dp_new, -(-p2[n].size // dp_new))
+        assert all(tuple(leaf.shape) == want for leaf in st), n
+    b2 = ts2.shard_batch(batch)
+    for _ in range(2):
+        p2, s2, a2, _ = ts2(p2, s2, a2, b2, rng=rng)
+    _close(p2, ref, what="zero1 dp4->dp%d" % dp_new)
+
+
 def _zero_ts(level, dp=8, pp=0, M=2):
     if pp:
         mesh = make_pp_mesh(pp, dp=dp, devices=jax.devices()[:pp * dp])

@@ -252,14 +252,40 @@ def test_pp_valid_normalization_rejected():
         ts.init(*MLP_SHAPES)
 
 
-def test_pp_zero_parity_and_sharded_state():
-    batch = _mlp_batch()
-    _, p_ref, _, _ = _ref_steps(_mlp(), batch, MLP_SHAPES)
-    ts, p, s, _, _ = _pp_steps(_mlp(), batch, MLP_SHAPES, 2, dp=2, M=2,
-                               zero=True)
-    _assert_trees_close(p, p_ref, what="zero pp")
-    assert all(leaf.shape[0] == 2 for st in s.values() for leaf in st), \
-        "pipeline zero optimizer state is not dp-sharded"
+@pytest.mark.parametrize("net", ["mlp", "mlp5", "conv"])
+def test_pp_zero_parity_and_sharded_state(net):
+    """The pipeline step with ``zero=1`` agrees with the one-stage step,
+    and asks the same placement plan how each leaf's state lies: in the
+    leaf's shape where ``dp`` divides its leading axis (every leaf of
+    ``mlp`` and ``conv``), as the flat ``(dp, chunk)`` view where not
+    (``mlp5``'s head of 5 over a ``dp`` of 2)."""
+    if net == "conv":
+        sym, batch, shapes = _convnet(), _conv_batch(), CONV_SHAPES
+    else:
+        classes = 5 if net == "mlp5" else 8
+        sym, batch, shapes = _mlp(classes), _mlp_batch(classes=classes), \
+            MLP_SHAPES
+    _, p_ref, _, _ = _ref_steps(sym, batch, shapes)
+    # BatchNorm's statistics are a microbatch's: one microbatch for conv
+    ts, p, s, _, _ = _pp_steps(sym, batch, shapes, 2, dp=2,
+                               M=1 if net == "conv" else 2, zero=True)
+    _assert_trees_close(p, p_ref, what="zero pp %s" % net)
+    flat = set()
+    for n, st in s.items():
+        shape = tuple(p[n].shape)
+        kept = shape[0] % 2 == 0
+        assert ts.plan.keeps_shape(shape) is kept
+        if not kept:
+            flat.add(n)
+        for leaf in st:
+            assert tuple(leaf.shape) == (
+                shape if kept else (2, -(-int(np.prod(shape)) // 2))), n
+            assert leaf.sharding.spec == jax.sharding.PartitionSpec("dp")
+            assert {x.data.shape[0] for x in leaf.addressable_shards} \
+                == {leaf.shape[0] // 2}, \
+                "pipeline zero optimizer state is not dp-sharded"
+            assert ts.unflatten_host(n, np.asarray(leaf)).shape == shape
+    assert flat == ({"fc3_weight", "fc3_bias"} if net == "mlp5" else set())
 
 
 # ---------------------------------------------------------------------- AMP

@@ -290,17 +290,33 @@ def test_amp_overflow_skip_under_1f1b():
 
 
 # --------------------------------------------------------------------- ZeRO
+def _assert_dp_sharded(state, dp=2):
+    for n, st in state.items():
+        for leaf in st:
+            assert leaf.sharding.spec == jax.sharding.PartitionSpec("dp"), n
+            assert {x.data.shape[0] for x in leaf.addressable_shards} \
+                == {leaf.shape[0] // dp}, \
+                "zero optimizer state is not dp-sharded: %s" % n
+
+
+@pytest.mark.parametrize("classes", [8, 5])
 @pytest.mark.parametrize("schedule,v", [("1f1b", None), ("interleaved", 2)])
-def test_zero_sharded_update_per_schedule(schedule, v):
+def test_zero_sharded_update_per_schedule(schedule, v, classes):
     # the ZeRO update consumes the flat (dp, chunk) gradient bucket
-    # directly — the stage's dp comm is done when its backward finishes
-    batch = _mlp_batch()
-    _, p_ref, _, _ = _ref_steps(_mlp(), batch, MLP_SHAPES)
-    _, p, s, _, _ = _pp_steps(_mlp(), batch, MLP_SHAPES, 2, dp=2, M=4,
-                              zero=True, schedule=schedule, interleave=v)
+    # directly — the stage's dp comm is done when its backward finishes;
+    # a leaf kept in its shape takes its rows of the bucket as its part
+    # of the leading axis (a head of 5 over a dp of 2 stays flat)
+    batch = _mlp_batch(classes=classes)
+    _, p_ref, _, _ = _ref_steps(_mlp(classes), batch, MLP_SHAPES)
+    ts, p, s, _, _ = _pp_steps(_mlp(classes), batch, MLP_SHAPES, 2, dp=2,
+                               M=4, zero=True, schedule=schedule,
+                               interleave=v)
     _close(p, p_ref, what="zero %s" % schedule)
-    assert all(leaf.shape[0] == 2 for st in s.values() for leaf in st), \
-        "zero optimizer state is not dp-sharded"
+    _assert_dp_sharded(s)
+    for n, st in s.items():
+        want = tuple(p[n].shape) if p[n].shape[0] % 2 == 0 \
+            else (2, -(-p[n].size // 2))
+        assert all(tuple(leaf.shape) == want for leaf in st), n
 
 
 def test_amp_zero_overlap_compose():
@@ -313,7 +329,7 @@ def test_amp_zero_overlap_compose():
                                  policy=pol(), zero=True, schedule="1f1b")
     _close(p, p_ref, what="amp+zero+1f1b")
     assert ts_r.amp_stats() == ts_p.amp_stats() == (1024.0, 0)
-    assert all(leaf.shape[0] == 2 for st in s.values() for leaf in st)
+    _assert_dp_sharded(s)
 
 
 # ---------------------------------------------------------------- live bytes
