@@ -251,6 +251,11 @@ def _scan_kernels_bwd(h, chunk, interpret, res, do):
     t = do.shape[1]
     inputs, outside = jax.vjp(
         functools.partial(_kernel_inputs, h=h, chunk=chunk), *res)
+    # the kernels' inputs wait for o's cotangent: else XLA may form every
+    # layer's states (160 MiB each) as soon as the forward ends and keep them
+    # all until their layer's backward.  After the norms and gates, which XLA
+    # then takes from the forward and does not form again (PERF.md 6, PR 38)
+    inputs, do = jax.lax.optimization_barrier((inputs, do))
     do = jnp.pad(do, ((0, 0), (0, -t % chunk), (0, 0)))
     return outside(pallas_kernels.kda_scan_bwd(*inputs, do, h, chunk,
                                                interpret))

@@ -53,6 +53,10 @@ and ``_bwd``: a chunk's (L, L) blocks, its unit-triangular solve and the
 carried state never leave VMEM; heads a grid step from ``kda_blocks``, guard
 ``kda_available``.
 
+``causal_conv_bwd``: the backward of the short causal convolution
+``causal_conv1d`` (ops/ssm.py), one pass over the rows, ``mxtpu_conv_bwd``;
+blocks from ``conv_blocks``, which returns None where it does not apply.
+
 All of them are tested in Pallas interpret mode on the CPU harness, compiled
 for a described v5e by ``test_pallas_tpu_compile.py`` and run against their
 plain forms on the chip by ``tools/tpu_numerics_check.py``.
@@ -71,7 +75,7 @@ __all__ = ["flash_attention", "flash_available", "flash_blocks",
            "grouped_matmul", "grouped_matmul_t", "grouped_available",
            "grouped_blocks", "ssd_scan_fwd", "ssd_scan_bwd", "ssd_available",
            "ssd_blocks", "kda_scan_fwd", "kda_scan_bwd", "kda_available",
-           "kda_blocks"]
+           "kda_blocks", "causal_conv_bwd", "conv_blocks"]
 
 _NEG_INF = -1e30
 
@@ -1648,3 +1652,150 @@ def kda_scan_bwd(q, k, v, g, beta, do, h, chunk, interpret=False):
         name="mxtpu_kda_bwd",
     )(q, k, v, g, cols, do, before, tinv)
     return dq, dkey, dval, dg, dcols.transpose(0, 2, 1, 3).reshape(bsz, t, h)
+
+
+# ------------------------------------------------- the causal convolution
+# The backward of ``causal_conv1d`` (ops/ssm.py), one pass over the rows:
+# ``y = act(sum_j w_j x[t - K + 1 + j] + b)``, so with ``g = dy act'(y_pre)``
+# ``dx[t] = sum_j w_j g[t + K - 1 - j]``, ``dw_j = sum_t x[t] g[t + K - 1 -
+# j]`` and ``db = sum_t g[t]``: one shifted g serves dx and dw alike.  A grid
+# step takes a block of rows and columns of x and dy with a halo of
+# ``_CONV_HALO`` rows: x's before and after the block, dy's after it (each a
+# block of its own whose index is clamped at the ends and whose rows there
+# are taken as zeros).  x and g are float32 in VMEM scratch; the body works
+# 16 rows at a time, a tap being the window of 32 rows from the strip's
+# first rolled to it, so that a strip's values stay in registers (the whole
+# block at once spilled them: 0.77 ms for a layer's forward and backward
+# at (4096, 6144), 0.46 by strips; PERF.md 6, PR 38).  g never leaves VMEM;
+# dx leaves once in x's dtype; ``dw`` and ``db`` are summed in float32 over
+# the row blocks in one (K + 1, columns) output block that stays in VMEM
+# along the sequential row axis, one a sequence.
+
+_CONV_HALO = 16          # rows: a bfloat16 tile's; K - 1 taps at most
+# tried on the v5e at (4096, 6144) and (4096, 4096), bfloat16: every pair of
+# (512-4096 rows, 128-512 columns) within 10% by wall time (PERF.md 6, PR 38)
+_CONV_ROWS = (1024, 512, 256, 128, 64, 32, 16)
+_CONV_COLS = (256, 128)
+
+
+def _conv_vmem(rows, cols, itemsize):
+    """What a grid step keeps in VMEM: two buffers of the x, dy and dx
+    blocks and of the three halos, and the float32 scratch of x and g; the
+    body works a strip of 16 rows at a time, in registers."""
+    halo = _CONV_HALO
+    return 2 * (3 * rows + 3 * halo) * cols * itemsize \
+        + (2 * rows + 3 * halo) * cols * 4
+
+
+def conv_blocks(t, c, k, itemsize):
+    """(rows, columns) of a grid step of ``causal_conv_bwd`` for a sequence
+    of T steps and C channels, K taps, operands of ``itemsize`` bytes: the
+    most rows, then the most columns, that divide T and C and fit the VMEM
+    budget.  None where the kernel does not apply: T no multiple of the
+    halo's 16 rows, C no multiple of the 128 lanes, more taps than the halo
+    holds."""
+    if min(t, c, k) <= 0 or t % _CONV_HALO or c % 128 \
+            or k - 1 > _CONV_HALO:
+        return None
+    return next(((r, n) for r in _CONV_ROWS for n in _CONV_COLS
+                 if t % r == 0 and c % n == 0
+                 and _conv_vmem(r, n, itemsize) <= _VMEM_BUDGET), None)
+
+
+def _conv_bwd_kernel(xp_ref, x_ref, xn_ref, dy_ref, dyn_ref, w_ref, b_ref,
+                     dx_ref, dw_ref, xs_ref, gs_ref, *, k, act):
+    f32 = jnp.float32
+    i, last = pl.program_id(2), pl.num_programs(2) - 1
+    rows, cols = x_ref.shape
+    halo = strip = _CONV_HALO
+    xs_ref[:halo] = jnp.where(i > 0, xp_ref[...].astype(f32), 0.0)
+    xs_ref[halo:halo + rows] = x_ref[...].astype(f32)
+    xs_ref[halo + rows:] = jnp.where(i < last, xn_ref[...].astype(f32), 0.0)
+    gs_ref[:rows] = dy_ref[...].astype(f32)
+    gs_ref[rows:] = jnp.where(i < last, dyn_ref[...].astype(f32), 0.0)
+    w = [w_ref[pl.ds(j, 1), :] for j in range(k)]
+
+    def window(ref, s):         # two strips from strip s, as rows
+        return ref[pl.ds(pl.multiple_of(s * strip, strip), 2 * strip), :]
+
+    def shifted(win, at):       # the strip's rows from row ``at`` of win
+        return pltpu.roll(win, 2 * strip - at, 0)[:strip] if at else \
+            win[:strip]
+
+    # strip by strip, so that a strip's values stay in registers: g over
+    # the block's rows and the halo's, from y before the activation ...
+    if act is not None:
+        def g_strip(s, carry):
+            win = window(xs_ref, s)
+            y = b_ref[...] + sum(w[j] * shifted(win, halo - k + 1 + j)
+                                 for j in range(k))
+            at = pl.ds(pl.multiple_of(s * strip, strip), strip)
+            gs_ref[at, :] = jax.vjp(act, y)[1](gs_ref[at, :])[0]
+            return carry
+        jax.lax.fori_loop(0, rows // strip + 1, g_strip, 0)
+
+    # ... then dx and the sums of dw and db, each tap's g shared by both
+    def dx_strip(s, sums):
+        win = window(gs_ref, s)
+        at = pl.ds(pl.multiple_of(s * strip, strip), strip)
+        x = xs_ref[pl.ds(pl.multiple_of(s * strip + halo, strip), strip), :]
+        taps = [shifted(win, k - 1 - j) for j in range(k)]
+        dx_ref[at, :] = sum(w[j] * taps[j] for j in range(k)).astype(
+            dx_ref.dtype)
+        return tuple(acc + (part[:8] + part[8:]) for acc, part in zip(
+            sums, [x * tap for tap in taps] + [taps[k - 1]]))
+    sums = jax.lax.fori_loop(0, rows // strip, dx_strip, tuple(
+        jnp.zeros((8, cols), f32) for _ in range(k + 1)))
+
+    @pl.when(i == 0)
+    def _():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
+    for j, acc in enumerate(sums):
+        dw_ref[pl.ds(j, 1), :] += jnp.sum(acc, axis=0, keepdims=True)
+
+
+# Under ``jax.jit``: the layers of one shape share one trace and one lowered
+# body (PERF.md 6, PR 35).
+@functools.partial(jax.jit, static_argnames=("act", "interpret"))
+def causal_conv_bwd(data, weight, bias, dy, act=None, interpret=False):
+    """Gradients of ``causal_conv1d`` (data (B, T, C), weight (C, K), bias
+    (C,) or None, then the elementwise ``act`` if given) for y's cotangent
+    ``dy``: of data, weight and bias, each in its own dtype, from float32
+    sums.  The kernel ``mxtpu_conv_bwd``; T and C as ``conv_blocks``
+    takes them."""
+    f32 = jnp.float32
+    bsz, t, c = data.shape
+    k = weight.shape[1]
+    blocks = conv_blocks(t, c, k, data.dtype.itemsize)
+    if blocks is None:
+        raise ValueError("causal_conv1d: no tiling for T=%d, C=%d, K=%d "
+                         "(see conv_blocks)" % (t, c, k))
+    rows, cols = blocks
+    halo, per = _CONV_HALO, rows // _CONV_HALO
+    before = lambda b, j, i: (b, jnp.maximum(i * per - 1, 0), j)  # noqa: E731
+    after = lambda b, j, i: (  # noqa: E731
+        b, jnp.minimum((i + 1) * per, t // halo - 1), j)
+    block = pl.BlockSpec((None, rows, cols), lambda b, j, i: (b, i, j))
+    edge = lambda at: pl.BlockSpec((None, halo, cols), at)  # noqa: E731
+    row = lambda n: pl.BlockSpec(  # noqa: E731
+        (n, cols), lambda b, j, i: (0, j))
+    b_row = jnp.zeros((1, c), f32) if bias is None else \
+        bias.astype(f32).reshape(1, c)
+    dx, dwb = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, k=k, act=act),
+        grid=(bsz, c // cols, t // rows),
+        in_specs=[edge(before), block, edge(after), block, edge(after),
+                  row(k), row(1)],
+        out_specs=[block, pl.BlockSpec((None, k + 1, cols),
+                                       lambda b, j, i: (b, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct(data.shape, data.dtype),
+                   jax.ShapeDtypeStruct((bsz, k + 1, c), f32)],
+        scratch_shapes=[pltpu.VMEM((rows + 2 * halo, cols), f32),
+                        pltpu.VMEM((rows + halo, cols), f32)],
+        compiler_params=_SSD,
+        interpret=interpret,
+        name="mxtpu_conv_bwd",
+    )(data, data, data, dy, dy, weight.astype(f32).T, b_row)
+    dwb = dwb.sum(0)
+    return dx, dwb[:k].T.astype(weight.dtype), (
+        None if bias is None else dwb[k].astype(bias.dtype))

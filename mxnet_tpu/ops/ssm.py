@@ -65,6 +65,60 @@ def _conv_infer(attrs, in_shapes):
     return ins, [data], None
 
 
+def _conv_pre(data, weight, bias):
+    """The K shifted multiply-adds and the bias, float32: y before its
+    activation."""
+    k, t = weight.shape[1], data.shape[1]
+    padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    w = weight.astype(jnp.float32)
+    y = sum(padded[:, j:j + t, :] * w[:, j] for j in range(k))
+    return y if bias is None else y + bias.astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def causal_conv(data, weight, bias, act):
+    """``_causal_conv1d`` with ``act`` an elementwise function or None."""
+    y = _conv_pre(data, weight, bias)
+    return (act(y) if act else y).astype(data.dtype)
+
+
+def _conv_fwd(data, weight, bias, act):
+    return causal_conv(data, weight, bias, act), (data, weight, bias)
+
+
+def conv_bwd_plain(data, weight, bias, dy, act=None):
+    """The backward in ``jax.numpy``, off the TPU and for the shapes
+    ``conv_blocks`` refuses; the kernel's oracle.  ``g = dy
+    act'(y_pre)`` with y_pre formed again from the input, ``dx[t] = sum_j
+    w_j g[t + K - 1 - j]``, ``dw_j = sum_t x[t - K + 1 + j] g[t]``, ``db =
+    sum_t g[t]``, all float32, each returned in its primal's dtype."""
+    f32 = jnp.float32
+    k, t = weight.shape[1], data.shape[1]
+    g = dy.astype(f32)
+    if act:
+        g = jax.vjp(act, _conv_pre(data, weight, bias))[1](g)[0]
+    w = weight.astype(f32)
+    ahead = jnp.pad(g, ((0, 0), (0, k - 1), (0, 0)))
+    dx = sum(ahead[:, k - 1 - j:k - 1 - j + t] * w[:, j] for j in range(k))
+    padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(f32)
+    dw = jnp.stack([(padded[:, j:j + t] * g).sum((0, 1)) for j in range(k)],
+                   axis=1)
+    return dx.astype(data.dtype), dw.astype(weight.dtype), (
+        None if bias is None else g.sum((0, 1)).astype(bias.dtype))
+
+
+def _conv_bwd(act, res, dy):
+    data, weight, bias = res
+    _, t, c = data.shape
+    if jax.default_backend() == "tpu" and pallas_kernels.conv_blocks(
+            t, c, weight.shape[1], data.dtype.itemsize) is not None:
+        return pallas_kernels.causal_conv_bwd(data, weight, bias, dy, act)
+    return conv_bwd_plain(data, weight, bias, dy, act)
+
+
+causal_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
 @register("causal_conv1d", arg_names=_conv_args,
           attr_types={"kernel": parse_int, "act_type": parse_str,
                       "no_bias": parse_bool},
@@ -77,22 +131,14 @@ def _causal_conv1d(data, weight, bias=None, kernel=None, act_type=None,
     zeros before t = 0 (torch ``Conv1d(groups=C, padding=K - 1)`` cut to T),
     then ``act_type`` (an ``Activation`` type) if given; ``no_bias`` leaves
     the bias and its input out.  K shifted multiply-adds, accumulated in
-    float32; the backward forms them again from the input, which alone is
-    kept."""
+    float32.  The backward is written by hand (``jax.custom_vjp``) and its
+    residuals are the inputs alone: y before the activation is formed
+    again.  On a TPU, for the shapes ``conv_blocks`` tiles, it is one
+    pass of the kernel ``mxtpu_conv_bwd`` over the rows, ``dy act'`` kept
+    in VMEM; else ``conv_bwd_plain``."""
     del no_bias                     # told by the third input's absence
-    act = ACTIVATIONS[act_type] if act_type else None
-
-    @jax.checkpoint
-    def conv(data, weight, bias):
-        k, t = weight.shape[1], data.shape[1]
-        padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(
-            jnp.float32)
-        w = weight.astype(jnp.float32)
-        y = sum(padded[:, j:j + t, :] * w[:, j] for j in range(k))
-        if bias is not None:
-            y = y + bias.astype(jnp.float32)
-        return (act(y) if act else y).astype(data.dtype)
-    return conv(data, weight, bias)
+    return causal_conv(data, weight, bias,
+                       ACTIVATIONS[act_type] if act_type else None)
 
 
 # ------------------------------------------------------------------ the scan

@@ -388,6 +388,31 @@ def test_the_op_through_the_kernels_gives_all_seven_gradients(dtype, tol):
         assert _gap(a, b) < tol, (name, _gap(a, b))
 
 
+def test_the_backward_waits_for_the_cotangent_before_its_states():
+    """The kernels' five inputs pass one optimization barrier with o's
+    cotangent before the first kernel of the backward, so XLA cannot form a
+    layer's states (160 MiB at kimi-linear-steps-t4096's shape) before that
+    layer's backward: left free, it formed all four layers' at the forward's
+    end and kept them, 1.2 GB more scratch (PERF.md 6-7, PR 38;
+    ``tools/step_memory.py``)."""
+    args = _kernel_op_inputs(16, 64, jnp.float32)
+    o, vjp = jax.vjp(lambda *a: kda._scan_kernels(*a, KH, 64, True), *args)
+    closed = jax.make_jaxpr(vjp)(o)
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+    order = [e for e in eqns(closed.jaxpr) if e.primitive.name in (
+        "optimization_barrier", "pallas_call")]
+    assert [e.primitive.name for e in order] == [
+        "optimization_barrier", "pallas_call", "pallas_call"]
+    barrier = order[0]
+    assert len(barrier.invars) == 6
+    assert closed.jaxpr.invars[0] in barrier.invars
+
+
 @pytest.mark.parametrize("shape,heads", [
     ((4096, 32, 128, 128, 64, 2), 8),      # kimi-linear-steps-t4096
     ((4096, 32, 128, 128, 64, 4), 8),
@@ -518,3 +543,30 @@ def test_the_other_kernels_bodies_a_layer_read_as_they_did():
         shaped((h,), f32))
     assert _bodies(text) == {"mxtpu_ssd_fwd": 2, "mxtpu_ssd_states": 2,
                              "mxtpu_ssd_bwd": 2}
+
+
+def test_the_conv_kernels_body_is_lowered_once_a_shape(monkeypatch):
+    """The gradient of the KDA mixer's three convolutions (no bias) and of
+    the Mamba mixer's (a bias, 6,144 channels) on a TPU: the caller of
+    ``mxtpu_conv_bwd`` is under ``jax.jit``, so the three of one shape share
+    one body and the module holds two behind four calls.  The backend is
+    steered here, in the test, as the op takes the kernel on a TPU alone.
+    This tree reads 39 KB of text and 44 KB of jaxpr."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shaped = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
+    silu = jax.nn.silu
+
+    def convs(q, k, v, w, xbc, wm, bm):
+        outs = [ssm.causal_conv(a, w, None, silu) for a in (q, k, v)]
+        outs.append(ssm.causal_conv(xbc, wm, bm, silu))
+        return sum((o.astype(jnp.float32) ** 2).sum() for o in outs)
+    x = shaped((1, 4096, 4096), bf16)
+    text, jaxpr = _lowered_for_tpu(
+        jax.grad(convs, argnums=tuple(range(7))), x, x, x,
+        shaped((4096, 4), bf16), shaped((1, 4096, 6144), bf16),
+        shaped((6144, 4), bf16), shaped((6144,), bf16))
+    assert _bodies(text) == {"mxtpu_conv_bwd": 2}
+    assert len(re.findall(r"call @causal_conv_bwd", text)) == 4
+    assert len(text) < 80 << 10, len(text)
+    assert len(jaxpr) < 90 << 10, len(jaxpr)

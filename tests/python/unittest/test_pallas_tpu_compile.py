@@ -318,3 +318,35 @@ def test_the_delta_rules_forward_alone_keeps_nothing_a_chunk(one_chip):
 @pytest.mark.parametrize("shape", KDA_CORNERS)
 def test_the_delta_rules_corners_compile(one_chip, shape, dtype):
     _compile_rule(one_chip, shape, dtype)
+
+
+# the short causal convolutions of both hybrid cells, (B, T, C, a bias), then
+# a corner: float32, 3 taps, row blocks of 16
+CONV_CELLS = [(1, 4096, 6144, True), (1, 4096, 4096, False)]
+
+
+@pytest.mark.parametrize("shape,dtype,k", [(s, jnp.bfloat16, 4)
+                                           for s in CONV_CELLS]
+                         + [((2, 48, 384, True), jnp.float32, 3)])
+def test_the_convolutions_backward_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, shape, dtype, k):
+    """``causal_conv1d``'s gradient is one kernel, ``mxtpu_conv_bwd``, and
+    the program keeps no float32 (B, T, C) array: what it holds beyond its
+    inputs and outputs is under one such array's bytes.  The op takes the
+    kernel on a TPU: the backend is steered here, in the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bsz, t, c, with_bias = shape
+    shaped = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def loss(x, w, b):
+        y = ssm.causal_conv(x, w, b, jax.nn.silu)
+        return (y.astype(jnp.float32) ** 2).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2) if with_bias
+                                else (0, 1))).lower(
+        shaped(bsz, t, c), shaped(c, k), shaped(c) if with_bias else None
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mxtpu_conv_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < bsz * t * c * 4

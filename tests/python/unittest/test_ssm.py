@@ -16,7 +16,7 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..",
 sys.path.insert(0, ROOT)
 
 import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu.ops import ssm  # noqa: E402
+from mxnet_tpu.ops import pallas_kernels as pk, ssm  # noqa: E402
 from mxnet_tpu.ops.registry import get_op  # noqa: E402
 from benchmark.reference import hybrid_lm as ref  # noqa: E402
 
@@ -141,6 +141,132 @@ def test_causal_conv1d_infers_its_leaves():
     assert dict(zip(s.list_arguments(), shapes)) == {
         "data": (2, 9, 6), "c_weight": (6, 4), "c_bias": (6,)}
     assert outs == [(2, 9, 6)]
+
+
+def _shifted_sum(data, weight, bias, act_type):
+    """The oracle: the plain shifted sum as the op stood before its backward
+    was written by hand, float32 inside, differentiated by autodiff."""
+    k, t = weight.shape[1], data.shape[1]
+    padded = jnp.pad(data, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    y = sum(padded[:, j:j + t] * weight[:, j].astype(jnp.float32)
+            for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return (jax.nn.silu(y) if act_type else y).astype(data.dtype)
+
+
+def _conv_inputs(seed, bsz, t, c, k, with_bias, dtype):
+    r = np.random.RandomState(seed)
+    x, w, dy = (jnp.asarray(r.randn(*s), jnp.float32).astype(dtype)
+                for s in ((bsz, t, c), (c, k), (bsz, t, c)))
+    b = jnp.asarray(r.randn(c), jnp.float32).astype(dtype) \
+        if with_bias else None
+    return x, w, b, dy
+
+
+def _conv_grads(fn, x, w, b, dy):
+    return jax.vjp(fn, x, w, b)[1](dy)
+
+
+def _assert_conv_grads(got, want, dtype):
+    """dx, dweight and dbias in their primals' dtype and shape; float32 to
+    1e-5 of the largest entry, bfloat16 within one unit in the last place
+    of it (bfloat16 keeps 8 bits)."""
+    for name, a, ref_ in zip(("dx", "dweight", "dbias"), got, want):
+        if ref_ is None:
+            assert a is None
+            continue
+        assert a.dtype == ref_.dtype == dtype and a.shape == ref_.shape
+        top = float(jnp.abs(ref_.astype(jnp.float32)).max())
+        tol = 1e-5 * top if dtype == jnp.float32 else \
+            2.0 ** (np.floor(np.log2(top)) - 7) if top else 0.0
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(ref_, np.float32), rtol=0,
+                                   atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("t", [1, 37])         # T < K; T no multiple of 16
+@pytest.mark.parametrize("act_type", [None, "silu"])
+@pytest.mark.parametrize("with_bias", [True, False])
+@pytest.mark.parametrize("k", [2, 3, 4])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_causal_conv1ds_backward_is_autodiff_of_the_shifted_sum(
+        dtype, k, with_bias, act_type, t):
+    """dx, dweight and dbias of the op against autodiff of the plain form:
+    float32 to 1e-5 of the largest entry; bfloat16 to the plain form's own
+    bfloat16 result within one ulp of its largest entry.  And the gradient
+    is causal: dy after s moves no dx before s - K + 1."""
+    x, w, b, dy = _conv_inputs(k + t, 2, t, 6, k, with_bias, dtype)
+    op = lambda x, w, b: get_op("causal_conv1d").fn(  # noqa: E731
+        x, w, b, kernel=k, act_type=act_type)
+    got = _conv_grads(op, x, w, b, dy)
+    _assert_conv_grads(got, _conv_grads(
+        lambda *a: _shifted_sum(*a, act_type), x, w, b, dy), dtype)
+    s = t - 1
+    moved = _conv_grads(op, x, w, b, dy.at[:, s:].add(1.0))[0]
+    np.testing.assert_array_equal(np.asarray(moved[:, :max(s - k + 1, 0)]),
+                                  np.asarray(got[0][:, :max(s - k + 1, 0)]))
+    assert not np.array_equal(np.asarray(moved), np.asarray(got[0]))
+
+
+def test_causal_conv1ds_backward_is_its_own_and_keeps_the_inputs_alone(
+        monkeypatch):
+    """Off the TPU the op's gradient is ``conv_bwd_plain`` under one
+    ``custom_vjp``: no ``checkpoint`` / ``remat`` in the program, and the
+    forward hands the backward the op's inputs and nothing else."""
+    x, w, b, dy = _conv_inputs(0, 2, 9, 6, 4, True, jnp.float32)
+    op = lambda x, w, b: get_op("causal_conv1d").fn(  # noqa: E731
+        x, w, b, kernel=4, act_type="silu")
+    loss = lambda *a: (op(*a) * dy).sum()  # noqa: E731
+    assert "custom_vjp_call" in str(jax.make_jaxpr(op)(x, w, b))
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, w, b))
+    assert "checkpoint" not in text and "remat" not in text
+    _, res = ssm._conv_fwd(x, w, b, jax.nn.silu)
+    assert len(res) == 3 and all(r is v for r, v in zip(res, (x, w, b)))
+    calls = []
+    plain = ssm.conv_bwd_plain
+    monkeypatch.setattr(ssm, "conv_bwd_plain",
+                        lambda *a: calls.append(1) or plain(*a))
+    jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
+    assert calls == [1]
+
+
+# (B, T, C, K, bias, activation, dtype): three row blocks of 16 and their
+# halos; three row blocks of 32 by three column blocks of 128, no bias; 17
+# taps, the most the halo holds
+CONV_KERNEL_SHAPES = [(2, 48, 256, 4, True, None, jnp.float32),
+                      (2, 48, 256, 4, True, "silu", jnp.float32),
+                      (1, 96, 384, 3, False, "silu", jnp.bfloat16),
+                      (1, 48, 128, 17, True, "silu", jnp.float32)]
+
+
+@pytest.mark.parametrize("case", CONV_KERNEL_SHAPES)
+def test_the_conv_kernel_is_the_plain_backward(case):
+    """``mxtpu_conv_bwd`` in interpret mode against ``conv_bwd_plain``:
+    float32 to 1e-5 of the largest entry, bfloat16 within one ulp of it
+    (both sum in float32, in another order)."""
+    bsz, t, c, k, with_bias, act_type, dtype = case
+    x, w, b, dy = _conv_inputs(c, bsz, t, c, k, with_bias, dtype)
+    act = jax.nn.silu if act_type else None
+    _assert_conv_grads(pk.causal_conv_bwd(x, w, b, dy, act, interpret=True),
+                       ssm.conv_bwd_plain(x, w, b, dy, act), dtype)
+
+
+@pytest.mark.parametrize("shape,blocks", [
+    ((4096, 6144, 4, 2), (1024, 256)),     # nemotron-twotower's Mamba conv
+    ((4096, 4096, 4, 2), (1024, 256)),     # kimi-linear's q, k and v
+    ((48, 256, 4, 4), (16, 256)),
+    ((96, 384, 3, 2), (32, 128)),
+    ((4096, 384, 4, 2), (1024, 128)),
+    ((16, 128, 17, 4), (16, 128)),         # K - 1 fills the halo
+    ((16, 128, 18, 4), None),
+    ((40, 256, 4, 2), None),               # T no multiple of 16
+    ((64, 200, 4, 2), None),               # C no multiple of the lanes
+    ((0, 128, 4, 2), None)])
+def test_the_conv_kernels_chooser_and_guard(shape, blocks):
+    assert pk.conv_blocks(*shape) == blocks
+    if blocks:
+        assert pk._conv_vmem(*blocks, shape[3]) <= pk._VMEM_BUDGET
 
 
 @pytest.mark.parametrize("groups", [1, 4])

@@ -4,8 +4,9 @@ bf16-appropriate tolerances — flash attention forward and both backward
 kernels, including the corners of its shape guard (those with f32 operands
 too) and a latent-attention layer's value heads of 128 beside query/key heads
 of 192, the grouped products of the routed experts at the benchmark cell's
-own shape, the state-space scan's kernels, and the gated delta rule's
-kernels against its token-by-token recurrence.  The interpret-mode twins of
+own shape, the state-space scan's kernels, the gated delta rule's kernels
+against its token-by-token recurrence, and the short causal convolution's
+backward kernel against float32 autodiff.  The interpret-mode twins of
 these checks run on the CPU harness (test_pallas.py, test_moe.py,
 test_ssm.py, test_kda.py).
 
@@ -49,6 +50,10 @@ SSD_SHAPE = (1, 4096, 64, 64, 8, 128, 128)
 # the linear-attention mixer of kimi-linear-steps-t4096: (B, T, H, d_k, d_v),
 # bfloat16, chunks of 64
 KDA_SHAPE = (1, 4096, 32, 128, 128)
+
+# the short causal convolutions of both hybrid cells, bfloat16, 4 taps, SiLU:
+# (B, T, C, with a bias) the Mamba mixer's over xBC, the KDA mixer's q, k, v
+CONV_SHAPES = [(1, 4096, 6144, True), (1, 4096, 4096, False)]
 
 
 def _rel(a, b):
@@ -268,6 +273,48 @@ def check_kda_scan():
                                      zip(names, errs))), flush=True)
 
 
+def check_causal_conv():
+    """``causal_conv1d``'s backward (the kernel ``mxtpu_conv_bwd`` on the
+    chip) against autodiff of the plain shifted sum in float32 on the same
+    bfloat16 inputs, y's and the three gradients' largest error relative to
+    the largest entry."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import ssm, pallas_kernels as pk
+    from mxnet_tpu.ops.nn import ACTIVATIONS
+
+    silu = ACTIVATIONS["silu"]
+    rng = np.random.RandomState(5)
+    for bsz, t, c, with_bias in CONV_SHAPES:
+        assert pk.conv_blocks(t, c, 4, 2) is not None, (t, c)
+        bf16 = lambda *shape: jnp.asarray(  # noqa: E731
+            rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
+        args = (bf16(bsz, t, c), bf16(c, 4) * 0.5,
+                bf16(c) if with_bias else None)
+        dy = bf16(bsz, t, c)
+
+        def plain(x, w, b):
+            y = ssm._conv_pre(x, w, b)
+            return silu(y)
+
+        def grads(fn, cast):
+            y, vjp = jax.vjp(fn, *(None if a is None else a.astype(cast)
+                                   for a in args))
+            return (y,) + vjp(dy.astype(cast))
+        got = jax.jit(lambda: grads(
+            lambda *a: ssm.causal_conv(*a, silu), jnp.bfloat16))()
+        want = jax.jit(lambda: grads(plain, jnp.float32))()
+        errs = [_rel(a, b) for a, b in zip(got, want) if b is not None]
+        names = "y data weight bias".split()[:len(errs)]
+        for name, err in zip(names, errs):
+            assert err < 2e-2, "causal_conv %s rel err %.2e at %s" % (
+                name, err, (bsz, t, c))
+        print("PASS causal_conv %s bias %s bfloat16 blocks %s  rel err %s"
+              % ((bsz, t, c), with_bias, pk.conv_blocks(t, c, 4, 2),
+                 " ".join("%s %.1e" % (k, e) for k, e in zip(names, errs))),
+              flush=True)
+
+
 if __name__ == "__main__":
     import jax
     if jax.default_backend() != "tpu":
@@ -280,4 +327,5 @@ if __name__ == "__main__":
     check_grouped_products()
     check_ssd_scan()
     check_kda_scan()
+    check_causal_conv()
     print("ALL TPU NUMERICS CHECKS PASSED")
