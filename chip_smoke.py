@@ -368,21 +368,6 @@ def run(platform, **overrides):
         shutil.rmtree(workdir, ignore_errors=True)
 
 
-def watch_compiles():
-    """Count compile requests against the persistent cache and its hits;
-    the difference is what this run really compiled."""
-    from jax import monitoring
-    counts = {"requests": 0, "hits": 0}
-
-    def listener(event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            counts["requests"] += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            counts["hits"] += 1
-    monitoring.register_event_listener(listener)
-    return counts
-
-
 def main():
     t0 = time.time()
     device = describe_device()
@@ -393,18 +378,23 @@ def main():
               "tests/python/unittest/test_chip_smoke.py)."
               % device["platform"], file=sys.stderr)
         return 1
-    from mxnet_tpu import cost
+    from mxnet_tpu import cost, sanitize
     from mxnet_tpu.base import enable_compile_cache
     cache_dir = enable_compile_cache()
-    compiles = watch_compiles()
+    t_phases = time.perf_counter()
     log("compile cache at %s (JAX_COMPILATION_CACHE_DIR %s)", cache_dir,
         "set" if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "unset")
     log("roofline peaks for %r: %.0f TFLOP/s, %.0f GB/s", device["kind"],
         *[p / s for p, s in zip(cost.resolve_peaks(), (1e12, 1e9))])
     run("tpu")
+    # the set-up account's compile requests against the persistent cache
+    # and its hits: their difference is what this run really compiled
+    compiles = sanitize.setup_account(since=t_phases)
     log("compiled %d program(s); %d of %d compile requests were persistent-"
-        "cache hits; wall %.1fs", compiles["requests"] - compiles["hits"],
-        compiles["hits"], compiles["requests"], time.time() - t0)
+        "cache hits; trace %.1fs, lowering %.1fs, compile %.1fs; wall %.1fs",
+        compiles["misses"], compiles["hits"], compiles["requests"],
+        compiles["trace"], compiles["lower"], compiles["compile"],
+        time.time() - t0)
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

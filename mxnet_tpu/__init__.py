@@ -4,7 +4,9 @@ MXNet 0.9.4 (NNVM era), redesigned for JAX/XLA/Pallas rather than ported.
 See SURVEY.md for the reference layer map this package mirrors and README.md for
 the architecture.
 """
-from .base import MXNetError
+from time import perf_counter as _perf_counter
+_t_import = _perf_counter()
+from .base import MXNetError  # noqa: E402
 from .context import Context, cpu, gpu, tpu, cpu_pinned, current_context
 from . import base
 from . import telemetry
@@ -56,3 +58,8 @@ from . import rnn
 from . import test_utils
 
 __version__ = "0.1.0"
+
+# the set-up account's import interval; jax is imported by now, so the
+# account's jax.monitoring feed is installed here too
+sanitize.install_setup_feed(_t_import, _perf_counter())
+del _perf_counter, _t_import

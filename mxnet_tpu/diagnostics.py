@@ -25,7 +25,8 @@ strict no-op otherwise (the same zero-overhead contract as telemetry):
 * **compile & memory visibility** — ``sample_device_memory`` turns JAX
   live-array statistics (and, where the backend provides them, device
   ``memory_stats``) into per-epoch telemetry gauges; the ``xla_compile``
-  span lives in ``executor._get_jit`` (first-call trace+compile cost).
+  span (a program's trace + lowering + compile) comes from the set-up
+  feed in ``sanitize``.
 
 * **crash snapshot** — any exception escaping ``Module.fit`` writes the
   same bundle (stacks, counters, recent events, the exception itself)

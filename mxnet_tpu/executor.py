@@ -850,12 +850,6 @@ class Executor(object):
             # dispatch reuses; grad kinds first fire under jax.vjp with
             # tracers, where program_capture degrades to a silent skip
             fn = self._hbm_first_call(fn, kind)
-        if _tel._enabled:
-            # jax.jit is lazy: the miss's trace+compile cost lands on the
-            # FIRST invocation, not here — time that call as an
-            # `xla_compile` span so first-step compile shows up in the
-            # step breakdown instead of hiding inside `forward`
-            fn = self._timed_first_call(cache_key, fn, kind)
         self._jit_cache[cache_key] = fn
         # named key fields make mxsan's RECOMPILE diff readable (built
         # from the SAME locals as cache_key, so key and report can never
@@ -865,49 +859,17 @@ class Executor(object):
                               "mirror": mirror_key, "trace_env": env_key})
         return fn
 
-    def _timed_first_call(self, cache_key, fn, kind):
-        """Wrap a fresh jit so its first call records an ``xla_compile``
-        span tagged with the jit kind, then replace the cache entry with
-        the raw jit — steady-state dispatch pays nothing.  For grad kinds
-        the first call happens under jax.vjp, so the span covers trace +
-        primal compile; the pullback's own compile lands in the first
-        ``backward`` span."""
-        import time as _time
-        from . import telemetry as _tel
-
-        def first_call(*args):
-            wall = _time.time()
-            t0 = _time.perf_counter()
-            out = fn(*args)
-            dur = _time.perf_counter() - t0
-            _tel.record_span("xla_compile", wall, dur, cat="compile",
-                             kind=kind)
-            # the first invocation's wall time IS this program's compile
-            # (steady-state dispatch is microseconds) — fold it into the
-            # executor cache's cumulative compile_seconds counter
-            self._san_cache.compile_note(dur)
-            self._jit_cache[cache_key] = fn
-            return out
-        return first_call
-
     def _hbm_first_call(self, fn, kind):
         """Wrap a fresh jit so its first invocation records the compiled
         program's memory analysis and/or cost analysis into mxsan's
         ledgers (best-effort: tracer arguments or lowering errors degrade
         to a skip), then step out of the way."""
-        from . import telemetry as _tel
         state = {"done": False}
 
         def hbm_first_call(*args):
             if not state["done"]:
                 state["done"] = True
-                # compile-seconds: with telemetry on, _timed_first_call
-                # wraps THIS wrapper and its first-call timing already
-                # covers the capture's compile — crediting the cache here
-                # too would double-count
-                _san.program_capture(
-                    "executor.%s" % kind, fn, args,
-                    cache=None if _tel._enabled else self._san_cache)
+                _san.program_capture("executor.%s" % kind, fn, args)
             return fn(*args)
         return hbm_first_call
 

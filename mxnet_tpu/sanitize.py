@@ -81,6 +81,14 @@ Three checkers:
   tail into a diagnostics bundle — a hung fleet leaves a post-mortem
   naming which rank stopped at which seq.
 
+One part is always on: the **set-up account** (:func:`setup_account`), a
+``jax.monitoring`` feed installed once by the package's import, which
+times each program's trace, lowering and compile and counts the
+persistent cache's answers.  jax fires it only when it traces, lowers or
+compiles, so a cached dispatch pays nothing; it feeds the per-cache
+``compile_seconds`` and, while the telemetry registry records, the
+``xla_compile`` / ``compile.seconds`` spans.
+
 ``stats()`` / ``violations()`` expose counters and the recent violation
 messages; under telemetry every cache miss also refreshes the
 ``jit_cache_size`` gauge from the registry (the sum of live entries
@@ -111,7 +119,8 @@ __all__ = ["SanitizerError", "SanitizerWarning", "arm", "disarm", "armed",
            "wire_bytes", "hbm_arm", "hbm_disarm", "hbm_ledger",
            "hbm_note", "hbm_capture", "hbm_wrap", "cost_arm",
            "cost_disarm", "cost_ledger", "cost_note", "program_capture",
-           "program_wrap", "compile_seconds"]
+           "program_wrap", "compile_seconds", "setup_account",
+           "SetupAccount", "install_setup_feed", "program_name"]
 
 CHECKERS = ("recompile", "sync", "donate", "collective")
 
@@ -157,10 +166,12 @@ _CACHES = []              # list[_CacheHandle]
 _DONATED = {}             # id(leaf) -> (label, where, step, ref)
 _RAW_COMPILES = {}        # (jit fun name, shapes signature) -> count
 # inner-function names registered caches jit (declared via
-# register_cache(jit_names=...)): their compiles are those caches' OWN
-# misses — the raw-jit watcher must not double-count them (many
-# executors re-binding the same shapes legitimately recompile 'fwd')
-_REGISTERED_JIT_NAMES = set()
+# register_cache(jit_names=...)), each to the newest handle declaring it:
+# their compiles are those caches' OWN misses — the raw-jit watcher must
+# not double-count them (many executors re-binding the same shapes
+# legitimately recompile 'fwd'), and the set-up feed charges their trace,
+# lowering and compile seconds to that handle
+_REGISTERED_JIT_NAMES = {}
 _stats = {"recompile_violations": 0, "sync_violations": 0,
           "donate_violations": 0, "collective_violations": 0,
           "sync_allowed": 0, "cache_misses": 0, "raw_compiles": 0,
@@ -230,9 +241,10 @@ class _CacheHandle(object):
         self.name = name
         self.kind = kind or name
         self.warmup = warmup
+        self.jit_names = tuple(jit_names)
         if jit_names:
             with _lock:
-                _REGISTERED_JIT_NAMES.update(jit_names)
+                _REGISTERED_JIT_NAMES.update(dict.fromkeys(jit_names, self))
         self._sizer = sizer
         self._owner_ref = None
         if owner is not None:
@@ -244,7 +256,7 @@ class _CacheHandle(object):
         self._misses = 0
         self._miss_anchor = 0       # miss count when the checker was armed
         self._warned = 0
-        self._compile_s = 0.0       # cumulative XLA compile wall seconds
+        self._compile_s = 0.0       # cumulative trace + lower + compile s
 
     # -- registry plumbing
     def alive(self):
@@ -310,8 +322,9 @@ class _CacheHandle(object):
             "class)" % ("; ".join(parts) or "<none — duplicate key, "
                         "entries are being evicted/rebuilt>")
 
-    # -- compile-time accounting (call with the wall seconds one XLA
-    #    compile took; cumulative per cache, mirrored to /metrics)
+    # -- compile-time accounting (the set-up feed calls it with each
+    #    trace, lowering or compile interval of this cache's programs;
+    #    cumulative per cache, mirrored to /metrics)
     def compile_note(self, seconds):
         seconds = float(seconds)
         with _lock:
@@ -342,7 +355,9 @@ def register_cache(name, kind=None, owner=None, sizer=None, warmup=None,
     overrides every budget).  ``jit_names`` declares the inner function
     names this cache jits (``("fwd", "f")`` for the executor): their
     compiles are this cache's own misses, so the raw-jit log watcher
-    skips them.  Registration is always active and costs a list append —
+    skips them, and the set-up feed charges their trace, lowering and
+    compile seconds to this handle (the newest handle that declares a
+    name owns it).  Registration is always active and costs a list append —
     the checkers consult it only when armed."""
     h = _CacheHandle(name, kind, owner, sizer, warmup, jit_names=jit_names)
     with _lock:
@@ -925,17 +940,22 @@ def cost_note(name, analysis, compile_s=None):
 
 
 def program_capture(name, fn, args=(), kwargs=None, cache=None):
-    """The unified capture-at-compile hook: one timed
+    """The unified capture-at-compile hook: one
     ``fn.lower(*args).compile()``, then whatever ledgers are armed —
     ``memory_analysis()`` when ``_hbm_on``, ``cost_analysis()`` when
-    ``_cost_on`` — plus compile-seconds accounting against ``cache`` (a
-    register_cache handle) and a ``compile.seconds`` telemetry span.
+    ``_cost_on``.  It keeps no clock: the set-up feed times the capture's
+    trace, lowering and compile, charges them to ``cache`` (a
+    register_cache handle) where no handle declares the program's name,
+    writes them as a ``compile.seconds`` telemetry span named ``name``,
+    and the cost row's ``compile_seconds`` is their union.
 
     Arming pays each compile once: jax caches the lowering per argument
     signature and keeps the compiled executable on it, so the dispatch of
     ``fn`` with these same arguments reuses what was compiled here
-    (test_cost.py counts the backend compiles; on the chip,
-    ``chip_smoke.py`` does).
+    (test_cost.py and test_setup_account.py count the backend compiles).
+    A program with a Pallas kernel lowered here carries this call stack
+    in its kernel's payload, which the persistent cache's key keeps: its
+    first capture misses entries that plain dispatches wrote.
 
     Attribution must never add a failure mode to the program it measures,
     so a capture that cannot run returns None — but not silently: tracer
@@ -950,8 +970,9 @@ def program_capture(name, fn, args=(), kwargs=None, cache=None):
     if any(isinstance(leaf, jax.core.Tracer)
            for leaf in jax.tree_util.tree_leaves((args, kwargs))):
         return None
-    wall = time.time()
-    t0 = time.perf_counter()
+    st = _state()
+    outer, st.capture = getattr(st, "capture", None), \
+        {"name": str(name), "cache": cache, "spans": []}
     try:
         compiled = fn.lower(*args, **(kwargs or {})).compile()
     except Exception as e:
@@ -960,15 +981,8 @@ def program_capture(name, fn, args=(), kwargs=None, cache=None):
             "mxsan: no HBM/cost row for program '%s' — lowering it for "
             "attribution failed: %s: %s", name, type(e).__name__, e)
         return None
-    dur = time.perf_counter() - t0
-    if cache is not None:
-        try:
-            cache.compile_note(dur)
-        except Exception:
-            pass
-    if _tel._enabled:
-        _tel.record_span("compile.seconds", wall, dur, cat="compile",
-                         program=str(name))
+    finally:
+        spans, st.capture = st.capture["spans"], outer
     out = {"hbm": None, "cost": None}
     if _hbm_on:
         try:
@@ -980,7 +994,7 @@ def program_capture(name, fn, args=(), kwargs=None, cache=None):
     if _cost_on:
         try:
             out["cost"] = cost_note(name, compiled.cost_analysis(),
-                                    compile_s=dur)
+                                    compile_s=_union_seconds(spans))
         except Exception:
             pass
     return out
@@ -1008,16 +1022,239 @@ def program_wrap(name, fn, cache=None):
 
 
 def compile_seconds():
-    """Cumulative XLA compile wall seconds per registered cache (plus a
-    ``total``), fed by ``_CacheHandle.compile_note`` — the seconds the
-    ROADMAP persistent-compilation-cache item would save.  Caches that
-    never compiled are omitted; empty dict when nothing was measured."""
+    """Cumulative trace + lowering + compile seconds per registered cache
+    (plus a ``total``), fed by the set-up feed through
+    ``_CacheHandle.compile_note`` in every run — the seconds a warm
+    persistent compilation cache shortens to a load.  Caches that never
+    compiled are omitted; empty dict when nothing was measured."""
     with _lock:
         out = {h.name: round(h._compile_s, 6)
                for h in _CACHES if h._compile_s > 0.0}
         if out:
             out["total"] = round(sum(out.values()), 6)
         return out
+
+
+# ------------------------------------------------------------ set-up account
+# One feed, always on, times what a process spends before its first step:
+# ``jax.monitoring``'s trace, lowering (Mosaic's included) and compile
+# spans (``backend_compile_duration`` encloses the persistent cache's
+# lookup, so a load is timed there too) and the persistent cache's request
+# / hit / write events, plus the package's own import.  jax fires these
+# only when it traces, lowers or compiles: a cached dispatch records
+# nothing.  Every interval feeds the owning cache's compile_note, and,
+# while the telemetry registry records, one span per program: ``xla_compile``
+# for a program compiled by its first dispatch, ``compile.seconds`` for one
+# compiled by program_capture.
+
+_FEED_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                "/jax/core/compile/backend_compile_duration": "compile"}
+_FEED_COUNTS = {"/jax/compilation_cache/compile_requests_use_cache":
+                "requests",
+                "/jax/compilation_cache/cache_hits": "hits",
+                "/jax/compilation_cache/cache_misses": "written"}
+SETUP_PHASES = ("import", "trace", "lower", "compile")
+_feed_installed = False
+_feed_failed = False        # the feed's first failure is logged, once
+
+
+class SetupAccount(object):
+    """What the feed saw, in memory and bounded: intervals ``(phase,
+    program, start, end)`` and counted events ``(time, kind)``, both on the
+    ``time.perf_counter`` clock, and the package's import."""
+
+    KEEP = 65536
+
+    def __init__(self):
+        self.intervals = deque(maxlen=self.KEEP)
+        self.events = deque(maxlen=self.KEEP)
+        self.imported = None
+        self.lock = threading.Lock()    # jax may compile on any thread
+
+    def read(self, since=None, until=None, top=5):
+        """Seconds of each phase in ``[since, until]`` (open ends: all),
+        each instant counted once, for the innermost interval that covers
+        it: an inner jit traced inside its caller's trace is the caller's
+        trace, a program compiled while another is traced is compile.  So
+        the phases never overlap and their sum is at most the cut's
+        length.  Also the persistent cache's ``requests``, ``hits``,
+        ``misses`` (requests it did not answer) and ``written`` (entries
+        jax wrote), and per phase the ``top`` programs by seconds."""
+        lo = float("-inf") if since is None else since
+        hi = float("inf") if until is None else until
+        with self.lock:
+            spans, events = list(self.intervals), list(self.events)
+        if self.imported is not None:
+            spans.append(("import", "mxnet_tpu") + tuple(self.imported))
+        spans = [(p, n, max(a, lo), min(b, hi)) for p, n, a, b in spans
+                 if min(b, hi) > max(a, lo)]
+        by_program = _innermost_seconds(spans)
+        out = dict.fromkeys(SETUP_PHASES, 0.0)
+        programs = {p: [] for p in SETUP_PHASES}
+        for (phase, name), sec in by_program.items():
+            out[phase] += sec
+            programs[phase].append([name, sec])
+        counts = dict.fromkeys(_FEED_COUNTS.values(), 0)
+        for t, kind in events:
+            if lo <= t <= hi:
+                counts[kind] += 1
+        out.update(counts)
+        out["misses"] = counts["requests"] - counts["hits"]
+        out["programs"] = {
+            p: sorted(rows, key=lambda r: -r[1])[:top]
+            for p, rows in programs.items()}
+        return out
+
+
+_setup = SetupAccount()
+
+
+def _innermost_seconds(spans):
+    """``{(phase, program): seconds}``: each instant covered by ``spans``
+    goes to the covering span that started last (at one start, the
+    shorter), the innermost of nested spans."""
+    marks = sorted([(a, 1, -b, i) for i, (_, _, a, b) in enumerate(spans)]
+                   + [(b, 0, 0, i) for i, (_, _, _, b) in enumerate(spans)])
+    out = {}
+    active, prev = [], None
+    for t, opens, _, i in marks:
+        if active and t > prev:
+            key = spans[active[-1]][:2]
+            out[key] = out.get(key, 0.0) + (t - prev)
+        if opens:
+            active.append(i)
+        else:
+            active.remove(i)
+        prev = t
+    return out
+
+
+def _union_seconds(spans):
+    """Length of the union of ``(start, end)`` intervals."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(spans):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+def program_name(fun_name):
+    """The function's own name in a feed event: jax names a trace by the
+    function and a lowering or compile by its module, ``jit(<name>)``,
+    ``jvp(<name>)`` and so on."""
+    name = str(fun_name)
+    while name.endswith(")") and "(" in name:
+        head, _, inner = name.partition("(")
+        if not head.replace("_", "").isalnum():
+            break
+        name = inner[:-1]
+    return name
+
+
+def _owner(name):
+    """The live registered cache that declares ``name`` among its
+    ``jit_names`` (the newest, when several do), or None."""
+    h = _REGISTERED_JIT_NAMES.get(name)
+    if h is None or h.alive():
+        return h
+    with _lock:
+        live = [c for c in _CACHES if c.alive() and name in c.jit_names]
+        if live:
+            _REGISTERED_JIT_NAMES[name] = live[-1]
+        else:
+            _REGISTERED_JIT_NAMES.pop(name, None)
+    return live[-1] if live else None
+
+
+def _on_feed_span(event, start_time, end_time, fun_name="?", **_):
+    phase = _FEED_PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        end = time.perf_counter()
+        dur = end_time - start_time
+        start = end - dur
+        name = program_name(fun_name)
+        with _setup.lock:
+            _setup.intervals.append((phase, name, start, end))
+        st = _state()
+        cap = getattr(st, "capture", None)
+        owner = _owner(name) or (cap["cache"] if cap else None)
+        if owner is not None:
+            owner.compile_note(dur)
+        if cap is not None:
+            cap["spans"].append((start, end))
+        if _tel._enabled:
+            _feed_telemetry(st, cap, phase, name, start, end)
+    except Exception:       # noqa: BLE001 — the feed never fails a compile
+        global _feed_failed
+        if not _feed_failed:
+            _feed_failed = True
+            import logging
+            logging.getLogger(__name__).warning(
+                "mxsan: the set-up feed failed on %s of %r; its account "
+                "may miss intervals", phase, fun_name, exc_info=True)
+
+
+def _feed_telemetry(st, cap, phase, name, start, end):
+    """One registry span a program, written at its compile's end: the
+    union of the trace, lowering and compile intervals this thread saw
+    under the program's name since its last compile."""
+    pending = getattr(st, "feed_pending", None)
+    if pending is None or len(pending) > 256:
+        pending = st.feed_pending = {}
+    seen = pending.setdefault(name, [])
+    seen.append((start, end))
+    if phase != "compile":
+        return
+    del pending[name]
+    first = min(a for a, _ in seen)
+    tags = {"kind": name, "persistent_hit": bool(
+        getattr(st, "feed_hit", False))}
+    st.feed_hit = False
+    if cap is not None:
+        tags["program"] = cap["name"]
+    _tel.record_span("compile.seconds" if cap is not None else "xla_compile",
+                     time.time() - (time.perf_counter() - first),
+                     _union_seconds(seen), cat="compile", **tags)
+
+
+def _on_feed_event(event, **_):
+    kind = _FEED_COUNTS.get(event)
+    if kind is None:
+        return
+    with _setup.lock:
+        _setup.events.append((time.perf_counter(), kind))
+    if kind == "hits" and _tel._enabled:
+        _state().feed_hit = True
+
+
+def install_setup_feed(import_start, import_end):
+    """Install the feed's two ``jax.monitoring`` listeners, once per
+    process, and note the package's import interval.  Called by the last
+    line of ``mxnet_tpu/__init__.py``, where jax is already imported."""
+    global _feed_installed
+    if _setup.imported is None:
+        _setup.imported = (import_start, import_end)
+    with _lock:
+        if _feed_installed:
+            return
+        _feed_installed = True
+    from jax import monitoring
+    monitoring.register_event_time_span_listener(_on_feed_span)
+    monitoring.register_event_listener(_on_feed_event)
+
+
+def setup_account(since=None, until=None, top=5):
+    """The set-up account up to ``until`` (a ``time.perf_counter`` stamp,
+    such as a window's first dispatch), from ``since``: seconds of
+    ``import``, ``trace``, ``lower`` and ``compile`` (disjoint, see
+    :meth:`SetupAccount.read`), the persistent cache's ``requests``,
+    ``hits``, ``misses`` and ``written``, and the largest ``programs`` of
+    each phase."""
+    return _setup.read(since, until, top)
 
 
 def note_collective(kind, name=None, sig=None, axes=None, device=True):

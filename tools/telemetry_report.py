@@ -311,7 +311,8 @@ _INCIDENTS = ["fit_crashes", "watchdog_stalls"]
 
 
 def collect_compile_spans(events):
-    """xla_compile spans (executor._get_jit first-call trace+compile)."""
+    """Compile spans (``xla_compile``, ``compile.seconds``): a program's
+    trace + lowering + compile, written by sanitize's set-up feed."""
     return [ev for ev in events if ev.get("type") == "span"
             and ev.get("cat") == "compile"]
 
