@@ -140,7 +140,19 @@ def _rms_norm(data, gamma, gate=None, eps=1e-5, num_groups=1, gated=False):
     that many equal groups of the axis.  ``gated`` adds a third input and
     norms ``x * silu(gate)``, gate first (the gated norm of a state-space
     mixer).  Statistics in float32 whatever the input's dtype; of the
-    forward only the inputs are kept."""
+    forward only the inputs are kept.  Gated, on a TPU and for the shapes
+    ``gnorm_available`` takes, the kernels of ``ssm.gated_group_norm``;
+    else, and ungated always, the ``jax.numpy`` form below."""
+    if gated and jax.default_backend() == "tpu" \
+            and gate.dtype == data.dtype:
+        from . import pallas_kernels, ssm
+        c = data.shape[-1]
+        if pallas_kernels.gnorm_available(data.size // max(c, 1), c,
+                                          int(num_groups),
+                                          data.dtype.itemsize):
+            return ssm.gated_group_norm(data, gamma, gate, eps,
+                                        int(num_groups))
+
     @jax.checkpoint
     def norm(data, gamma, gate):
         x = data.astype(jnp.float32)

@@ -293,3 +293,29 @@ def _ssm_scan(data, dt, a_log, d, dt_bias, num_heads=None, head_dim=None,
     core = jax.checkpoint(functools.partial(_scan, h=h, p=p, g=g,
                                             chunk=chunk))
     return core(data, dt, a_log, d, dt_bias)
+
+
+# ------------------------------------------------------- the gated group norm
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def gated_group_norm(data, gamma, gate, eps, groups):
+    """``RMSNorm(data, gamma, gate, gated=True, num_groups=groups)`` as the
+    kernels ``mxtpu_gnorm_fwd`` / ``_bwd``, one pass over the rows each way;
+    the residuals are the op's inputs alone.  ``RMSNorm`` takes it on a TPU
+    for the shapes ``gnorm_available`` takes; its own ``jax.numpy`` form is
+    the oracle."""
+    return pallas_kernels.gnorm_fwd(data, gate, gamma, groups, eps)
+
+
+def _gnorm_fwd(data, gamma, gate, eps, groups):
+    return gated_group_norm(data, gamma, gate, eps, groups), (data, gamma,
+                                                              gate)
+
+
+def _gnorm_bwd(eps, groups, res, dout):
+    data, gamma, gate = res
+    dx, dz, dgamma = pallas_kernels.gnorm_bwd(data, gate, gamma, dout,
+                                              groups, eps)
+    return dx, dgamma, dz
+
+
+gated_group_norm.defvjp(_gnorm_fwd, _gnorm_bwd)

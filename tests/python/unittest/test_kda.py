@@ -570,3 +570,29 @@ def test_the_conv_kernels_body_is_lowered_once_a_shape(monkeypatch):
     assert len(re.findall(r"call @causal_conv_bwd", text)) == 4
     assert len(text) < 80 << 10, len(text)
     assert len(jaxpr) < 90 << 10, len(jaxpr)
+
+
+@pytest.mark.parametrize("layers", [1, 4])
+def test_the_gated_norms_kernels_bodies_are_lowered_once_for_all_layers(
+        layers):
+    """The gradient of nemotron-twotower-steps-t4096's gated group norm, one
+    layer and the four Mamba layers of its ``MEMEM*EME`` stack, lowered for a
+    TPU: the callers of ``mxtpu_gnorm_fwd`` and ``_bwd`` are under
+    ``jax.jit``, so the module holds each body once behind a call a layer,
+    and the text hardly grows with the layers (this tree reads 15 KB and
+    16 KB of text, 18 KB and 31 KB of jaxpr)."""
+    shaped = jax.ShapeDtypeStruct
+    bf16 = jnp.bfloat16
+    x = shaped((1, 4096, 4096), bf16)
+
+    def norms(y, w, z):
+        for _ in range(layers):
+            y = ssm.gated_group_norm(y, w, z, 1e-5, 8)
+        return (y.astype(jnp.float32) ** 2).sum()
+    text, jaxpr = _lowered_for_tpu(jax.grad(norms, argnums=(0, 1, 2)), x,
+                                   shaped((4096,), bf16), x)
+    assert _bodies(text) == {"mxtpu_gnorm_fwd": 1, "mxtpu_gnorm_bwd": 1}
+    assert len(re.findall(r"call @gnorm_fwd", text)) == layers
+    assert len(re.findall(r"call @gnorm_bwd", text)) == layers
+    assert len(text) < 40 << 10, len(text)
+    assert len(jaxpr) < 40 << 10, len(jaxpr)

@@ -1,5 +1,6 @@
 """The flash kernels, the routed experts' grouped products, the state-space
-scan's kernels and the gated delta rule's compiled for a
+scan's kernels, the gated delta rule's, the convolution's backward and the
+gated group norm's compiled for a
 described (not attached) TPU v5e, at the benchmark cells' shapes and the
 shape guards' corners: what interpret mode
 cannot show — a slice Mosaic cannot tile, a transpose it cannot lower, more
@@ -349,4 +350,31 @@ def test_the_convolutions_backward_compiles_at_the_cells_shapes(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert "mxtpu_conv_bwd" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < bsz * t * c * 4
+
+
+# the gated group norm of nemotron-twotower-steps-t4096's Mamba mixers, (B, T,
+# C, G), then corners: one group of 128 lanes; one group of 4,096 in float32,
+# where the VMEM budget cuts the rows
+@pytest.mark.parametrize("shape,dtype", [((1, 4096, 4096, 8), jnp.bfloat16),
+                                         ((2, 48, 128, 1), jnp.float32),
+                                         ((1, 4096, 4096, 1), jnp.float32)])
+def test_the_gated_group_norm_compiles_at_the_cells_shape(one_chip, shape,
+                                                          dtype):
+    """The gated norm's value and gradients are two kernels,
+    ``mxtpu_gnorm_fwd`` and ``_bwd``, and the program keeps no float32
+    (B, T, C) array: what it holds beyond its inputs and outputs is under one
+    such array's bytes."""
+    bsz, t, c, g = shape
+    shaped = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def loss(x, w, z):
+        y = ssm.gated_group_norm(x, w, z, 1e-5, g)
+        return (y.astype(jnp.float32) ** 2).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shaped(bsz, t, c), shaped(c), shaped(bsz, t, c)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "mxtpu_gnorm_fwd" in text and "mxtpu_gnorm_bwd" in text
     assert compiled.memory_analysis().temp_size_in_bytes < bsz * t * c * 4

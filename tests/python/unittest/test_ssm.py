@@ -3,7 +3,9 @@
 chunked scan over several chunks with the published initialisation's ranges,
 where the state carried between chunks matters; the causal convolution; the
 norms."""
+import functools
 import os
+import re
 import sys
 
 import jax
@@ -168,11 +170,12 @@ def _conv_grads(fn, x, w, b, dy):
     return jax.vjp(fn, x, w, b)[1](dy)
 
 
-def _assert_conv_grads(got, want, dtype):
-    """dx, dweight and dbias in their primals' dtype and shape; float32 to
-    1e-5 of the largest entry, bfloat16 within one unit in the last place
-    of it (bfloat16 keeps 8 bits)."""
-    for name, a, ref_ in zip(("dx", "dweight", "dbias"), got, want):
+def _assert_conv_grads(got, want, dtype, names=("dx", "dweight", "dbias")):
+    """dx, dweight and dbias (or what ``names`` says) in their primals'
+    dtype and shape; float32 to 1e-5 of the largest entry, bfloat16 within
+    one unit in the last place of it (bfloat16 keeps 8 bits)."""
+    assert len(got) == len(want) == len(names)
+    for name, a, ref_ in zip(names, got, want):
         if ref_ is None:
             assert a is None
             continue
@@ -495,3 +498,104 @@ def test_off_the_tpu_the_op_is_the_plain_scan():
     assert "pallas_call" not in str(jax.make_jaxpr(jax.grad(
         lambda *a: fn(*a).astype(jnp.float32).sum()))(*args))
     assert "pallas_call" in str(jax.make_jaxpr(_kernels(shape))(*args))
+
+
+# ------------------------------------------------------- the gated group norm
+# (B, T, groups, a group's width, dtype, the gate's scale): widths of 128 and
+# 512 lanes, one group and eight, float32 and bfloat16, and a gate at the
+# published scale of the in-projection's, |z| up to 100, where silu'
+# saturates; the last two the guard refuses (groups of 96 lanes; 40 rows, no
+# multiple of the 16-row strip) and the op stays the plain form
+GNORM_CASES = [(2, 24, 1, 128, jnp.float32, 1.0),
+               (1, 64, 8, 128, jnp.bfloat16, 1.0),
+               (1, 32, 8, 512, jnp.float32, 1.0),
+               (2, 16, 1, 512, jnp.bfloat16, 1.0),
+               (1, 48, 8, 512, jnp.bfloat16, 30.0),
+               (1, 32, 2, 96, jnp.float32, 1.0),
+               (1, 40, 8, 128, jnp.bfloat16, 1.0)]
+
+
+@pytest.mark.parametrize("case", GNORM_CASES)
+def test_the_gated_norms_kernels_are_the_plain_form(case, monkeypatch):
+    """The gated ``RMSNorm`` over groups with the backend steered to a TPU
+    (here, in the test) and the kernels ``mxtpu_gnorm_fwd`` / ``_bwd`` in
+    interpret mode, against the op's plain form off the TPU: the result and
+    the gradients of data, gamma and gate, float32 to 1e-5 of the largest
+    entry, bfloat16 within one ulp of it.  A shape the guard refuses takes
+    the plain form on the TPU too: the same numbers to the bit."""
+    bsz, t, g, width, dtype, scale = case
+    c = g * width
+    r = np.random.RandomState(c + t)
+    x, z, dy = (jnp.asarray(r.randn(bsz, t, c) * s, jnp.float32).astype(dtype)
+                for s in (1.0, scale, 1.0))
+    w = jnp.asarray(r.randn(c), jnp.float32).astype(dtype)
+
+    def run():                 # a new function: jax keeps a trace by it
+        op = lambda x, w, z: get_op("RMSNorm").fn(  # noqa: E731
+            x, w, z, num_groups=g, gated=True)
+        y, vjp = jax.vjp(op, x, w, z)
+        return (y,) + vjp(dy), str(jax.make_jaxpr(op)(x, w, z))
+    want, jaxpr = run()
+    assert "pallas_call" not in jaxpr
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name in ("gnorm_fwd", "gnorm_bwd"):
+        monkeypatch.setattr(pk, name, functools.partial(getattr(pk, name),
+                                                        interpret=True))
+    got, jaxpr = run()
+    taken = width % 128 == 0 and (bsz * t) % 16 == 0
+    assert pk.gnorm_available(bsz * t, c, g, x.dtype.itemsize) == taken
+    assert ("pallas_call" in jaxpr) == taken
+    if taken:
+        _assert_conv_grads(got, want, dtype, ("y", "dx", "dgamma", "dgate"))
+    else:
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("shape,rows", [
+    ((4096, 4096, 8, 2), 512),             # nemotron-twotower's Mamba norm
+    ((4096, 4096, 8, 4), 512),
+    ((4096, 4096, 1, 4), 256),             # one group of 4,096 in float32
+    ((48, 128, 1, 4), 16),
+    ((96, 1024, 8, 2), 32),
+    ((40, 1024, 8, 2), None),              # T no multiple of 16
+    ((64, 768, 8, 2), None),               # groups of 96 lanes
+    ((64, 1000, 8, 2), None),              # C no multiple of G
+    ((0, 1024, 8, 2), None)])
+def test_the_gated_norms_chooser_and_guard(shape, rows):
+    assert pk.gnorm_blocks(*shape) == rows
+    assert pk.gnorm_available(*shape) == (rows is not None)
+    if rows:
+        t, c, g, itemsize = shape
+        assert pk._gnorm_vmem(rows, c // g, itemsize) <= pk._VMEM_BUDGET
+
+
+def test_only_the_gated_norms_ask_the_guard(monkeypatch):
+    """The step of an ``MEMEM*EME`` model traced with the backend steered to
+    a TPU: each Mamba mixer's gated norm asks ``gnorm_available`` once, the
+    ten ungated norms (the pre-norms, the final norm) never; the four layers
+    call each kernel's one traced body (the callers are under ``jax.jit``)."""
+    from mxnet_tpu import amp
+    from mxnet_tpu.models import hybrid_lm
+    from mxnet_tpu.train import TrainStep
+    calls = []
+    guard = pk.gnorm_available
+    monkeypatch.setattr(pk, "gnorm_available",
+                        lambda *a: calls.append(a) or guard(*a))
+    net = hybrid_lm.get_symbol(vocab_size=64, seq_len=32, pattern="MEMEM*EME",
+                               num_hidden=32, ssm_heads=4, ssm_head_dim=64,
+                               ssm_groups=2)
+    ts = TrainStep(net, mx.optimizer.Adam(learning_rate=1e-4),
+                   policy=amp.Policy("bfloat16"))
+    p, s, a = ts.init({"data": (2, 32)}, {"softmax_label": (2, 32)})
+    b = ts.shard_batch({"data": np.zeros((2, 32), np.float32),
+                        "softmax_label": np.zeros((2, 32), np.float32)})
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jaxpr = str(ts._step.trace(p, s, a, ts._scale_state_dev(), b,
+                               jax.random.PRNGKey(0), ts.fopt.hyper(0),
+                               np.int32(1)).jaxpr)
+    assert calls == [(64, 256, 2, 2)] * 4        # B T rows, inner, groups
+    assert re.findall(r"name=(gnorm_\w+)", jaxpr) == ["gnorm_fwd"] * 4 + [
+        "gnorm_bwd"] * 4
+    assert jaxpr.count("pallas_call") == 2

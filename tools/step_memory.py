@@ -5,7 +5,11 @@ no chip is attached and nothing runs.  Prints one JSON line of
 ``memory_analysis()``; with ``--dump DIR`` XLA's buffer assignment is
 written there too (``*buffer-assignment.txt``), and ``--live N`` reads from
 it the scratch allocation's extent, the largest sum of its buffers live at
-one position of the schedule, and the N largest of those buffers.
+one position of the schedule, and the N largest of those buffers.  With
+``--sha`` nothing is compiled: the line holds the sha256 and the length of
+the program's StableHLO, which two trees run from one path share where the
+program did not change (a Pallas kernel's payload carries the source
+locations of its call stack).
 
   JAX_PLATFORMS=cpu python3 tools/step_memory.py kimi-linear-steps-t4096 \
       --dump /tmp/kimi --live 12
@@ -18,6 +22,7 @@ compile at the cell's full size takes about a minute and a half here."""
 import argparse
 import collections
 import glob
+import hashlib
 import json
 import os
 import re
@@ -41,9 +46,9 @@ class _Capture(dict):
         raise _Built
 
 
-def chunk_program(cell_name, dump=None):
-    """The cell's chunk program lowered and compiled for one described v5e
-    chip."""
+def chunk_program(cell_name, dump=None, compiled=True):
+    """The cell's chunk program lowered and compiled (or, without
+    ``compiled``, lowered alone) for one described v5e chip."""
     if dump:
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    " --xla_dump_to=%s" % dump).strip()
@@ -103,7 +108,8 @@ def chunk_program(cell_name, dump=None):
     real = jax.default_backend
     jax.default_backend = lambda: "tpu"
     try:
-        return cap.fn.lower(*args).compile()
+        lowered = cap.fn.lower(*args)
+        return lowered.compile() if compiled else lowered
     finally:
         jax.default_backend = real
 
@@ -168,9 +174,18 @@ def main(argv=None):
     ap.add_argument("--live", type=int, metavar="N",
                     help="the N largest buffers live at the scratch's "
                     "fullest (needs --dump)")
+    ap.add_argument("--sha", action="store_true",
+                    help="the StableHLO's sha256 and length; nothing is "
+                    "compiled")
     a = ap.parse_args(argv)
     if a.live and not a.dump:
         ap.error("--live reads the dump: give --dump")
+    if a.sha:
+        text = chunk_program(a.workload, compiled=False).as_text()
+        print(json.dumps({"workload": a.workload, "stablehlo_bytes": len(
+            text), "stablehlo_sha256": hashlib.sha256(
+                text.encode()).hexdigest()}))
+        return
     mem = chunk_program(a.workload, a.dump).memory_analysis()
     row = {k: getattr(mem, k + "_size_in_bytes") for k in (
         "argument", "output", "alias", "temp", "generated_code")}

@@ -6,7 +6,8 @@ too) and a latent-attention layer's value heads of 128 beside query/key heads
 of 192, the grouped products of the routed experts at the benchmark cell's
 own shape, the state-space scan's kernels, the gated delta rule's kernels
 against its token-by-token recurrence, and the short causal convolution's
-backward kernel against float32 autodiff.  The interpret-mode twins of
+backward kernel and the gated group norm's two kernels against float32
+autodiff.  The interpret-mode twins of
 these checks run on the CPU harness (test_pallas.py, test_moe.py,
 test_ssm.py, test_kda.py).
 
@@ -54,6 +55,11 @@ KDA_SHAPE = (1, 4096, 32, 128, 128)
 # the short causal convolutions of both hybrid cells, bfloat16, 4 taps, SiLU:
 # (B, T, C, with a bias) the Mamba mixer's over xBC, the KDA mixer's q, k, v
 CONV_SHAPES = [(1, 4096, 6144, True), (1, 4096, 4096, False)]
+
+# the gated group norm of nemotron-twotower-steps-t4096's Mamba mixers: (B, T,
+# C, G), and the scale of the gate: the cell's, and one where silu' saturates
+GNORM_SHAPE = (1, 4096, 4096, 8)
+GNORM_GATES = (1.0, 30.0)
 
 
 def _rel(a, b):
@@ -315,6 +321,53 @@ def check_causal_conv():
               flush=True)
 
 
+def check_gated_norm():
+    """The gated ``RMSNorm`` over groups (the kernels ``mxtpu_gnorm_fwd`` and
+    ``_bwd`` on the chip) against autodiff of the plain form in float32 on
+    the same bfloat16 inputs: the result's and the three gradients' largest
+    error relative to the largest entry."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops.registry import get_op
+
+    bsz, t, c, g = GNORM_SHAPE
+    assert pk.gnorm_available(bsz * t, c, g, 2), GNORM_SHAPE
+    rng = np.random.RandomState(6)
+    bf16 = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.randn(*shape).astype(np.float32)).astype(jnp.bfloat16)
+
+    def plain(x, w, z):
+        u = x * jax.nn.silu(z)
+        grouped = u.reshape(u.shape[:-1] + (g, c // g))
+        r = jax.lax.rsqrt(jnp.mean(grouped * grouped, -1, keepdims=True)
+                          + 1e-5)
+        return (grouped * r).reshape(u.shape) * w
+
+    def op(x, w, z):
+        return get_op("RMSNorm").fn(x, w, z, eps=1e-5, num_groups=g,
+                                    gated=True)
+    for scale in GNORM_GATES:
+        args = (bf16(bsz, t, c), bf16(c), bf16(bsz, t, c) * scale)
+        dy = bf16(bsz, t, c)
+        assert "mxtpu_gnorm_fwd" in str(jax.make_jaxpr(op)(*args))
+
+        def grads(fn, cast):
+            y, vjp = jax.vjp(fn, *(a.astype(cast) for a in args))
+            return (y,) + vjp(dy.astype(cast))
+        got = jax.jit(lambda: grads(op, jnp.bfloat16))()
+        want = jax.jit(lambda: grads(plain, jnp.float32))()
+        names = "y data gamma gate".split()
+        errs = [_rel(a, b) for a, b in zip(got, want)]
+        for name, err in zip(names, errs):
+            assert err < 2e-2, "gated_norm %s rel err %.2e, gate x %g" % (
+                name, err, scale)
+        print("PASS gated_norm %s bfloat16 gate x %g rows %s  rel err %s"
+              % (GNORM_SHAPE, scale, pk.gnorm_blocks(bsz * t, c, g, 2),
+                 " ".join("%s %.1e" % (k, e) for k, e in zip(names, errs))),
+              flush=True)
+
+
 if __name__ == "__main__":
     import jax
     if jax.default_backend() != "tpu":
@@ -328,4 +381,5 @@ if __name__ == "__main__":
     check_ssd_scan()
     check_kda_scan()
     check_causal_conv()
+    check_gated_norm()
     print("ALL TPU NUMERICS CHECKS PASSED")
