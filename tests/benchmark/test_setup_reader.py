@@ -2,13 +2,12 @@
 ``setup.lower_s``, ``setup.compile_s``, ``setup.cache_misses`` and
 ``setup.rest_s``, read by ``readers/setup.py`` from the program's own
 account (``mxnet_tpu.sanitize.setup_account``) between the process's start
-and the window's first dispatch.  ``BENCHMARK.json`` does not list them
-yet: ``test_optimizer_update_metric.py`` holds its last per-layer entry by
-place, and new entries go at the end.  ``ENTRIES`` are the entries to
-append once it no longer does.  Held here: their files, that every cell's
-line carries them once they are appended, the reading of a made account,
-nothing without an account, and on the harness's own run at the tiny CPU
-size that the phases and the rest are ``setup_s``."""
+and the window's first dispatch.  ``ENTRIES`` are their entries as
+``BENCHMARK.json`` holds them: appended once, in this order, right after
+``optimizer.update_ms``, with no ``workloads`` list.  Held here: the
+entries and their files, that every cell's line carries them, the reading
+of a made account, nothing without an account, and on the harness's own
+run at the tiny CPU size that the phases and the rest are ``setup_s``."""
 import json
 import os
 import sys
@@ -37,10 +36,15 @@ ENTRIES = [{"name": m, "unit": "count" if m == "setup.cache_misses" else "s",
             "layer": "set-up", "moves": "setup_s"} for m in METRICS]
 
 
-def _with_entries(cell):
-    """``cell`` with ``ENTRIES`` appended to its per-layer metrics."""
-    cell.bench = dict(cell.bench, per_layer=cell.bench["per_layer"] + ENTRIES)
-    return cell
+def test_the_entries_are_appended_once_in_order():
+    """Each name once; the six together and in order, directly after the
+    entry that was last before them, so that no entry before them moved.
+    Held by name, not by place from the end: later entries go after them."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert all(names.count(m) == 1 for m in METRICS)
+    first = names.index(METRICS[0])
+    assert names[first - 1] == "optimizer.update_ms"
+    assert BENCH["per_layer"][first:first + len(ENTRIES)] == ENTRIES
 
 
 @pytest.mark.parametrize("metric", METRICS)
@@ -59,9 +63,9 @@ def test_every_cell_line_carries_them_once_appended(cell, monkeypatch):
     """Every cell reports ``setup_s``, so with no ``workloads`` list every
     cell's traced line carries the six, each in its unit (read here on
     their own: the others need a trace)."""
-    c = _with_entries(cells.Cell(cell))
+    c = cells.Cell(cell)
     assert "setup_s" in [m["name"] for m in c.end_to_end()]
-    assert c.per_layer()[-len(ENTRIES):] == ENTRIES
+    assert [m for m in c.per_layer() if m["layer"] == "set-up"] == ENTRIES
     c.bench = dict(c.bench, per_layer=ENTRIES)
     _made_account(monkeypatch)
     got = run.per_layer_metrics(c, _made_window())
